@@ -50,6 +50,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.utils.compile_cache import enable_compile_cache
+
 ROWS: list[tuple[str, float, str]] = []
 
 BENCH_REPEATS = max(int(os.environ.get("BENCH_REPEATS", "3")), 1)
@@ -1211,6 +1213,7 @@ def serve_energy():
     """
     from repro.models.registry import get_arch
     from repro.roofline.autotune import KnobConfig, WorkloadSpec, autotune
+    from repro.roofline.hw import device_peaks
     from repro.serve import ContinuousScheduler, ServeConfig, ServeEngine
     from repro.serve.trace import trace_energy
     from repro.sharding.mesh import MeshPlan
@@ -1287,7 +1290,8 @@ def serve_energy():
              KnobConfig(segment_len=32)]
     wspec = WorkloadSpec(tuple(sw_lens), tuple(sw_news),
                          n_slots=sw_slots, max_len=sw_max_len)
-    res = autotune(arch.cfg, wspec, candidates=cands)
+    res = autotune(arch.cfg, wspec, device_peaks(jax.devices()[0]),
+                   candidates=cands)
     predicted = {p.knobs: p for p in res.ranked}
     eng_sw = ServeEngine(arch, params, plan,
                          ServeConfig(max_len=sw_max_len, temperature=0.0))
@@ -1409,6 +1413,7 @@ def main() -> None:
     ap.add_argument("--only", default="",
                     help="comma-separated bench names (default: all)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.only:
         want = set(args.only.split(","))
         unknown = want - {n for n, *_ in benches}

@@ -14,5 +14,7 @@ sonic_matmul         — C1+C2 fused serving matmul, plus the decode-shaped
                        below DECODE_M_THRESHOLD (the generation hot path).
 
 Each kernel ships kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd
-public wrapper; interpret=True on CPU), ref.py (pure-jnp oracle).
+public wrapper), ref.py (pure-jnp oracle).  ``dispatch.run_kernel`` compiles
+a kernel with Mosaic when its caller is lowered for the TPU and interprets
+it on any other platform.
 """

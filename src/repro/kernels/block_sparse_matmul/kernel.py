@@ -43,7 +43,7 @@ def block_sparse_matmul_pallas(
     indices: jax.Array,  # (Nb, R) int32
     *,
     bm: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Returns y (M, N) fp32."""
     m, k = x.shape
@@ -72,7 +72,7 @@ def block_sparse_matmul_pallas(
     )(indices, x, vflat)
 
 
-def _int8_kernel(idx_ref, x_ref, v_ref, s_ref, o_ref):
+def _int8_kernel(idx_ref, x_ref, v_ref, s_ref, o_ref, *, r_steps: int):
     j = pl.program_id(1)
     r = pl.program_id(2)
 
@@ -82,7 +82,7 @@ def _int8_kernel(idx_ref, x_ref, v_ref, s_ref, o_ref):
 
     # dequant-inside-kernel: the int8 block is scaled against its per-block
     # fp32 scale at the MXU's edge — weights stay int8 in HBM and VMEM
-    w = v_ref[0].astype(jnp.float32) * s_ref[j, r]
+    w = v_ref[0].astype(jnp.float32) * s_ref[j * r_steps + r]
     o_ref[...] += jnp.dot(
         x_ref[...].astype(jnp.float32), w, preferred_element_type=jnp.float32
     )
@@ -95,13 +95,13 @@ def block_sparse_matmul_int8_pallas(
     indices: jax.Array,  # (Nb, R) int32
     *,
     bm: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Int8-weight variant (ISSUE 10): same sparse gather as the fp kernel,
     but kept blocks travel HBM→VMEM as int8 (4× fewer weight bytes than fp32)
-    and dequantize in-kernel against ``scales``.  The whole (Nb, R) scale
-    array rides along every grid step like the sonic codebook — it is tiny
-    (one fp32 per kept block) and VMEM-resident.  Returns y (M, N) fp32."""
+    and dequantize in-kernel against ``scales``.  The scales are tiny (one
+    fp32 per kept block) and sit whole in SMEM, flattened to (Nb·R,), so the
+    kernel reads its block's scale as a scalar.  Returns y (M, N) fp32."""
     m, k = x.shape
     nb, r, bk, bn = values.shape
     assert k == 0 or k % bk == 0, (k, bk)
@@ -115,13 +115,13 @@ def block_sparse_matmul_int8_pallas(
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, rr, idx: (i, idx[j, rr])),
             pl.BlockSpec((1, bk, bn), lambda i, j, rr, idx: (j * r + rr, 0, 0)),
-            pl.BlockSpec(scales.shape, lambda i, j, rr, idx: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, rr, idx: (i, j)),
     )
     return pl.pallas_call(
-        _int8_kernel,
+        functools.partial(_int8_kernel, r_steps=r),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, nb * bn), jnp.float32),
         interpret=interpret,
-    )(indices, x, vflat, scales)
+    )(indices, x, vflat, scales.reshape(nb * r))

@@ -11,8 +11,7 @@ from repro.kernels.block_sparse_matmul.kernel import (
     block_sparse_matmul_int8_pallas,
     block_sparse_matmul_pallas,
 )
-
-_ON_TPU = jax.default_backend() == "tpu"
+from repro.kernels.dispatch import run_kernel
 
 
 @functools.partial(jax.jit, static_argnames=("bm",))
@@ -32,9 +31,8 @@ def block_sparse_matmul(
     pad_m = (-m) % bm_eff
     if pad_m:
         x2 = jnp.pad(x2, ((0, pad_m), (0, 0)))
-    y = block_sparse_matmul_pallas(
-        x2, w.values, w.indices, bm=bm_eff, interpret=not _ON_TPU
-    )
+    y = run_kernel(block_sparse_matmul_pallas, x2, w.values, w.indices,
+                   bm=bm_eff)
     if pad_m:
         y = y[:m]
     n = w.values.shape[0] * w.block_shape[1]
@@ -59,9 +57,8 @@ def block_sparse_matmul_int8(
     pad_m = (-m) % bm_eff
     if pad_m:
         x2 = jnp.pad(x2, ((0, pad_m), (0, 0)))
-    y = block_sparse_matmul_int8_pallas(
-        x2, w.values, w.scales, w.indices, bm=bm_eff, interpret=not _ON_TPU
-    )
+    y = run_kernel(block_sparse_matmul_int8_pallas, x2, w.values, w.scales,
+                   w.indices, bm=bm_eff)
     if pad_m:
         y = y[:m]
     n = w.values.shape[0] * w.block_shape[1]

@@ -1,8 +1,9 @@
 """Public jit'd wrapper for the clustered-matmul kernel.
 
 Accepts any (..., K) activation against (K, N) int8 indices + (C,) codebook
-(the ``ClusteredWeight`` storage from ``repro.core.clustering``).  On CPU the
-Pallas kernel runs in interpret mode; on TPU set interpret=False.
+(the ``ClusteredWeight`` storage from ``repro.core.clustering``).  The
+Pallas kernel is compiled on the TPU and interpreted elsewhere
+(``repro.kernels.dispatch``).
 """
 from __future__ import annotations
 
@@ -12,8 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.clustered_matmul.kernel import clustered_matmul_pallas
-
-_ON_TPU = jax.default_backend() == "tpu"
+from repro.kernels.dispatch import run_kernel
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk"))
@@ -36,14 +36,14 @@ def clustered_matmul(
     pad_m = (-m) % bm_eff
     if pad_m:
         x2 = jnp.pad(x2, ((0, pad_m), (0, 0)))
-    y = clustered_matmul_pallas(
+    y = run_kernel(
+        clustered_matmul_pallas,
         x2,
         indices,
         codebook.astype(jnp.float32),
         bm=bm_eff,
         bn=bn,
         bk=bk,
-        interpret=not _ON_TPU,
     )
     if pad_m:
         y = y[:m]

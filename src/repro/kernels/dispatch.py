@@ -1,0 +1,24 @@
+"""Where a Pallas kernel runs: compiled by Mosaic on the TPU, interpreted
+everywhere else.
+
+The choice is made when the calling program is lowered, for the platform it
+is lowered for (``jax.lax.platform_dependent``).  Importing a kernel module
+decides nothing and queries no backend, and compiling for a described TPU
+from a CPU host takes the TPU branch, so such a compile sees the real
+kernel.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+
+
+def run_kernel(kernel, *args, **kwargs):
+    """``kernel(*args, **kwargs, interpret=...)`` with ``interpret`` False
+    when lowered for the TPU and True on any other platform."""
+    return jax.lax.platform_dependent(
+        *args,
+        tpu=functools.partial(kernel, **kwargs, interpret=False),
+        default=functools.partial(kernel, **kwargs, interpret=True),
+    )

@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.clustered_matmul.kernel import codebook_dequant, codebook_row
+
 
 def _matvec_kernel(idx_ref, x_ref, v_ref, cb_ref, o_ref):
     r = pl.program_id(1)
@@ -28,7 +30,7 @@ def _matvec_kernel(idx_ref, x_ref, v_ref, cb_ref, o_ref):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    w = cb_ref[...][v_ref[0].astype(jnp.int32)]  # dequant (bk, bn) fp32
+    w = codebook_dequant(cb_ref, v_ref[0].astype(jnp.int32))
     o_ref[...] += jnp.dot(
         x_ref[...].astype(jnp.float32), w, preferred_element_type=jnp.float32
     )
@@ -40,21 +42,22 @@ def sonic_matvec_pallas(
     codebook: jax.Array,  # (C,) fp32
     indices: jax.Array,  # (Nb, R) int32
     *,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Decode-shaped fused matvec: grid over (Nb, R) only — no M-tiling.
 
     The matmul kernel below pads decode activations (M = B·1, typically ≤ 8)
     up to a bm-row tile, spending MXU cycles and x-traffic on zero rows.
     Here the whole activation sliver rides along every grid step as a
-    (M, bk) block and only the *kept* K-blocks are gathered via the same
-    scalar-prefetch index map as ``sparse_matvec`` — per-token HBM weight
+    (M, bk) block and only the *kept* K-blocks are gathered via a
+    scalar-prefetch index map — per-token HBM weight
     bytes stay at the (1 − s)/2 the SONIC format promises.
     """
     m, k = x.shape
     nb, r, bk, bn = idx_values.shape
     assert k % bk == 0, (k, bk)
     vflat = idx_values.reshape(nb * r, bk, bn)
+    cb = codebook_row(codebook)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -62,7 +65,7 @@ def sonic_matvec_pallas(
         in_specs=[
             pl.BlockSpec((m, bk), lambda j, rr, idx: (0, idx[j, rr])),
             pl.BlockSpec((1, bk, bn), lambda j, rr, idx: (j * r + rr, 0, 0)),
-            pl.BlockSpec(codebook.shape, lambda j, rr, idx: (0,)),
+            pl.BlockSpec(cb.shape, lambda j, rr, idx: (0, 0)),
         ],
         out_specs=pl.BlockSpec((m, bn), lambda j, rr, idx: (0, j)),
     )
@@ -71,10 +74,10 @@ def sonic_matvec_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, nb * bn), jnp.float32),
         interpret=interpret,
-    )(indices, x, vflat, codebook)
+    )(indices, x, vflat, cb)
 
 
-def _matvec_int8_kernel(idx_ref, x_ref, v_ref, s_ref, o_ref):
+def _matvec_int8_kernel(idx_ref, x_ref, v_ref, s_ref, o_ref, *, r_steps: int):
     j = pl.program_id(0)
     r = pl.program_id(1)
 
@@ -84,7 +87,7 @@ def _matvec_int8_kernel(idx_ref, x_ref, v_ref, s_ref, o_ref):
 
     # dequant-inside-kernel against the per-block scale (ISSUE 10): the
     # kept block arrives as raw int8 and is scaled at the MXU's edge
-    w = v_ref[0].astype(jnp.float32) * s_ref[j, r]
+    w = v_ref[0].astype(jnp.float32) * s_ref[j * r_steps + r]
     o_ref[...] += jnp.dot(
         x_ref[...].astype(jnp.float32), w, preferred_element_type=jnp.float32
     )
@@ -96,13 +99,13 @@ def sonic_matvec_int8_pallas(
     scales: jax.Array,  # (Nb, R) fp32 per-block dequant scales
     indices: jax.Array,  # (Nb, R) int32
     *,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Decode-shaped int8-weight matvec: same no-M-padding grid over (Nb, R)
     as ``sonic_matvec_pallas``, but kept blocks stream as raw int8 against a
     per-block fp32 scale instead of cluster ids against a codebook — the
-    scale array (one fp32 per kept block) rides along every step like the
-    codebook does."""
+    scales (one fp32 per kept block) sit whole in SMEM, flattened to
+    (Nb·R,), and each step reads its block's scale as a scalar."""
     m, k = x.shape
     nb, r, bk, bn = values.shape
     assert k % bk == 0, (k, bk)
@@ -114,16 +117,16 @@ def sonic_matvec_int8_pallas(
         in_specs=[
             pl.BlockSpec((m, bk), lambda j, rr, idx: (0, idx[j, rr])),
             pl.BlockSpec((1, bk, bn), lambda j, rr, idx: (j * r + rr, 0, 0)),
-            pl.BlockSpec(scales.shape, lambda j, rr, idx: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((m, bn), lambda j, rr, idx: (0, j)),
     )
     return pl.pallas_call(
-        _matvec_int8_kernel,
+        functools.partial(_matvec_int8_kernel, r_steps=r),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, nb * bn), jnp.float32),
         interpret=interpret,
-    )(indices, x, vflat, scales)
+    )(indices, x, vflat, scales.reshape(nb * r))
 
 
 def _kernel(idx_ref, x_ref, v_ref, cb_ref, o_ref):
@@ -133,7 +136,7 @@ def _kernel(idx_ref, x_ref, v_ref, cb_ref, o_ref):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    w = cb_ref[...][v_ref[0].astype(jnp.int32)]  # dequant (bk, bn) fp32
+    w = codebook_dequant(cb_ref, v_ref[0].astype(jnp.int32))
     o_ref[...] += jnp.dot(
         x_ref[...].astype(jnp.float32), w, preferred_element_type=jnp.float32
     )
@@ -146,13 +149,14 @@ def sonic_matmul_pallas(
     indices: jax.Array,  # (Nb, R) int32
     *,
     bm: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     m, k = x.shape
     nb, r, bk, bn = idx_values.shape
     bm = min(bm, m)
     assert m % bm == 0 and k % bk == 0, (m, bm, k, bk)
     vflat = idx_values.reshape(nb * r, bk, bn)
+    cb = codebook_row(codebook)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -160,7 +164,7 @@ def sonic_matmul_pallas(
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, rr, idx: (i, idx[j, rr])),
             pl.BlockSpec((1, bk, bn), lambda i, j, rr, idx: (j * r + rr, 0, 0)),
-            pl.BlockSpec(codebook.shape, lambda i, j, rr, idx: (0,)),
+            pl.BlockSpec(cb.shape, lambda i, j, rr, idx: (0, 0)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, rr, idx: (i, j)),
     )
@@ -169,4 +173,4 @@ def sonic_matmul_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, nb * bn), jnp.float32),
         interpret=interpret,
-    )(indices, x, vflat, codebook)
+    )(indices, x, vflat, cb)

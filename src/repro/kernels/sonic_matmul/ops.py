@@ -9,13 +9,15 @@ import jax.numpy as jnp
 
 from repro.core.clustering import ClusteringConfig, cluster_weights
 from repro.core.sonic_layers import BlockSparseWeightInt8, make_block_sparse
+from repro.kernels.block_sparse_matmul.kernel import (
+    block_sparse_matmul_int8_pallas,
+)
+from repro.kernels.dispatch import run_kernel
 from repro.kernels.sonic_matmul.kernel import (
     sonic_matmul_pallas,
     sonic_matvec_int8_pallas,
     sonic_matvec_pallas,
 )
-
-_ON_TPU = jax.default_backend() == "tpu"
 
 # Flattened row counts below this dispatch to the decode-shaped matvec kernel
 # (grid over (Nb, R) only) instead of padding up to an M-tile.  8 = the fp32
@@ -91,17 +93,15 @@ def sonic_matmul(x: jax.Array, w: SonicWeight, *, bm: int = 256) -> jax.Array:
     x2 = x.reshape(-1, k)
     m = x2.shape[0]
     if m < DECODE_M_THRESHOLD:
-        y = sonic_matvec_pallas(
-            x2, w.idx_values, w.codebook, w.indices, interpret=not _ON_TPU
-        )
+        y = run_kernel(sonic_matvec_pallas, x2, w.idx_values, w.codebook,
+                       w.indices)
         return y.reshape(*lead, w.dense_shape[1]).astype(x.dtype)
     bm_eff = min(bm, max(8, m))
     pad_m = (-m) % bm_eff
     if pad_m:
         x2 = jnp.pad(x2, ((0, pad_m), (0, 0)))
-    y = sonic_matmul_pallas(
-        x2, w.idx_values, w.codebook, w.indices, bm=bm_eff, interpret=not _ON_TPU
-    )
+    y = run_kernel(sonic_matmul_pallas, x2, w.idx_values, w.codebook,
+                   w.indices, bm=bm_eff)
     if pad_m:
         y = y[:m]
     return y.reshape(*lead, w.dense_shape[1]).astype(x.dtype)
@@ -113,9 +113,8 @@ def sonic_matvec(x: jax.Array, w: SonicWeight) -> jax.Array:
     through the no-padding matvec kernel regardless of B."""
     squeeze = x.ndim == 1
     x2 = x[None] if squeeze else x
-    y = sonic_matvec_pallas(
-        x2, w.idx_values, w.codebook, w.indices, interpret=not _ON_TPU
-    ).astype(x.dtype)
+    y = run_kernel(sonic_matvec_pallas, x2, w.idx_values, w.codebook,
+                   w.indices).astype(x.dtype)
     return y[0] if squeeze else y
 
 
@@ -135,21 +134,15 @@ def sonic_matmul_int8(
     m = x2.shape[0]
     n = w.dense_shape[1]
     if m < DECODE_M_THRESHOLD:
-        y = sonic_matvec_int8_pallas(
-            x2, w.values, w.scales, w.indices, interpret=not _ON_TPU
-        )
+        y = run_kernel(sonic_matvec_int8_pallas, x2, w.values, w.scales,
+                       w.indices)
         return y.reshape(*lead, n).astype(x.dtype)
-    from repro.kernels.block_sparse_matmul.kernel import (
-        block_sparse_matmul_int8_pallas,
-    )
-
     bm_eff = min(bm, max(8, m))
     pad_m = (-m) % bm_eff
     if pad_m:
         x2 = jnp.pad(x2, ((0, pad_m), (0, 0)))
-    y = block_sparse_matmul_int8_pallas(
-        x2, w.values, w.scales, w.indices, bm=bm_eff, interpret=not _ON_TPU
-    )
+    y = run_kernel(block_sparse_matmul_int8_pallas, x2, w.values, w.scales,
+                   w.indices, bm=bm_eff)
     if pad_m:
         y = y[:m]
     return y.reshape(*lead, n).astype(x.dtype)
@@ -161,7 +154,6 @@ def sonic_matvec_int8(x: jax.Array, w: BlockSparseWeightInt8) -> jax.Array:
     always through the no-padding int8 matvec kernel regardless of B."""
     squeeze = x.ndim == 1
     x2 = x[None] if squeeze else x
-    y = sonic_matvec_int8_pallas(
-        x2, w.values, w.scales, w.indices, interpret=not _ON_TPU
-    ).astype(x.dtype)
+    y = run_kernel(sonic_matvec_int8_pallas, x2, w.values, w.scales,
+                   w.indices).astype(x.dtype)
     return y[0] if squeeze else y

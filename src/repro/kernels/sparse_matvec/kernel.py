@@ -4,17 +4,24 @@ y[B, N] = Σ_c x_nz[:, c] · Wt[idx[c], :]
 
 This is the zero-compression product of Fig. 1(b): the activation vector is
 dense after compression (x_nz), and only the weight rows the surviving
-activations touch are read.  Wt is stored input-major (K, N) so each gathered
-row is a contiguous HBM stripe; the BlockSpec index map reads ``idx`` via
-scalar prefetch, so — like the photonic VDU that never fires a VCSEL for a
-zero — untouched weight rows are never DMA'd.
+activations touch are read.  Wt is stored input-major as a row table
+(K, 1, N) (``row_table``) and stays in HBM (``memory_space=pl.ANY``);
+``idx`` arrives by scalar prefetch, and the kernel DMAs each kept row's
+bn-column stripe into a VMEM scratch — like the photonic VDU that never
+fires a VCSEL for a zero, untouched weight rows are never read.
 
-Grid = (N/bn, knz/bc): each step gathers a (bc, bn) row-bundle.  Row bundles
-require ``idx`` to be *bundle-contiguous*: ops.py rounds the kept set up to
-multiples of bc and sorts, so a bundle's rows live in one (bc-aligned) block.
-To keep the gather exact for arbitrary index sets, bc = 1 by default (one row
-per step, (1, bn) stripes); larger bc is available when the caller guarantees
-block-aligned sparsity.
+Why a row table: a (K, N) array sits in HBM in (8, 128) tiles, so the
+smallest row slice a DMA may take is 8 rows.  With a unit second-minor
+dimension the TPU lays each row out as its own (1, 128)-tiled stripe, with
+no padding, and one kept row is one DMA.  This holds for 32-bit weights;
+16-bit rows pack two to a sublane and cannot be copied one at a time.
+
+Grid = (N/bn, ⌈knz/tk⌉), kept rows innermost: step (j, c) copies rows
+idx[c·tk : (c+1)·tk] of column tile j into a (tk, 1, bn) scratch, one row
+DMA each, and accumulates x_nz[:, c·tk : (c+1)·tk] @ rows into the resident
+(B, bn) output tile.  Weight bytes read are knz · N · itemsize, whatever the
+index set.  When knz > tk it is padded to whole chunks with zero
+activations against row 0, which add nothing.
 """
 from __future__ import annotations
 
@@ -26,43 +33,80 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(idx_ref, x_ref, w_ref, o_ref, *, nc: int):
-    c = pl.program_id(1)
+def row_table(wt: jax.Array) -> jax.Array:
+    """(K, N) weight → the (K, 1, N) row table the kernel gathers from.
+
+    Convert once, where the weight is stored: on the TPU the two layouts
+    differ, so converting on every call copies the whole weight."""
+    k, n = wt.shape
+    return wt.reshape(k, 1, n)
+
+
+def _kernel(idx_ref, x_ref, w_hbm, o_ref, rows, sem, *, tk: int, bn: int):
+    j, c = pl.program_id(0), pl.program_id(1)
+
+    def copy(r):
+        return pltpu.make_async_copy(
+            w_hbm.at[pl.ds(idx_ref[c * tk + r], 1), :, pl.ds(j * bn, bn)],
+            rows.at[pl.ds(r, 1)],
+            sem,
+        )
+
+    @pl.loop(0, tk)
+    def _start(r):
+        copy(r).start()
 
     @pl.when(c == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    # (B, 1) × (1, bn) outer-product accumulate (VPU path; B is the sublane dim)
-    o_ref[...] += x_ref[...].astype(jnp.float32) * w_ref[...].astype(jnp.float32)
+    @pl.loop(0, tk)
+    def _wait(r):
+        copy(r).wait()
+
+    o_ref[...] += jnp.dot(
+        x_ref[...].astype(jnp.float32),
+        rows[:, 0, :].astype(jnp.float32),
+        preferred_element_type=jnp.float32,
+    )
 
 
 def sparse_matvec_pallas(
     x_nz: jax.Array,  # (B, knz)
     idx: jax.Array,  # (knz,) int32
-    wt: jax.Array,  # (K, N)
+    rows: jax.Array,  # (K, 1, N) from row_table
     *,
     bn: int = 512,
-    interpret: bool = True,
+    tk: int = 256,
+    interpret: bool,
 ) -> jax.Array:
     """Returns y (B, N) fp32."""
     b, knz = x_nz.shape
-    k, n = wt.shape
+    _, _, n = rows.shape
     bn = min(bn, n)
     assert n % bn == 0, (n, bn)
+    tk = min(tk, knz)
+    pad = (-knz) % tk
+    if pad:
+        x_nz = jnp.pad(x_nz, ((0, 0), (0, pad)))
+        idx = jnp.pad(idx, (0, pad))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(n // bn, knz),
+        grid=(n // bn, (knz + pad) // tk),
         in_specs=[
-            pl.BlockSpec((b, 1), lambda j, c, idx: (0, c)),
-            pl.BlockSpec((1, bn), lambda j, c, idx: (idx[c], j)),
+            pl.BlockSpec((b, tk), lambda j, c, idx: (0, c)),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((b, bn), lambda j, c, idx: (0, j)),
+        scratch_shapes=[
+            pltpu.VMEM((tk, 1, bn), rows.dtype),
+            pltpu.SemaphoreType.DMA(()),
+        ],
     )
     return pl.pallas_call(
-        functools.partial(_kernel, nc=knz),
+        functools.partial(_kernel, tk=tk, bn=bn),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n), jnp.float32),
         interpret=interpret,
-    )(idx, x_nz, wt)
+    )(idx, x_nz, rows)

@@ -7,34 +7,36 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.sparse_matvec.kernel import sparse_matvec_pallas
-
-_ON_TPU = jax.default_backend() == "tpu"
+from repro.kernels.dispatch import run_kernel
+from repro.kernels.sparse_matvec.kernel import row_table, sparse_matvec_pallas
 
 
 @functools.partial(jax.jit, static_argnames=("bn",))
 def sparse_matvec(
     x_nz: jax.Array,  # (..., knz): (knz,), (B, knz), or decode (B, 1, knz)
     idx: jax.Array,  # (knz,) int32
-    wt: jax.Array,  # (K, N)
+    wt: jax.Array,  # (K, N), or its (K, 1, N) row_table
     *,
     bn: int = 512,
 ) -> jax.Array:
     """Leading dims are flattened into the kernel's row axis — decode-shaped
-    (B, 1, knz) activations run unpadded, one kernel row per sequence."""
+    (B, 1, knz) activations run unpadded, one kernel row per sequence.
+    Pass the weight as its ``row_table`` where it is kept: a (K, N) weight
+    is converted on every call."""
     squeeze = x_nz.ndim == 1
     lead = x_nz.shape[:-1]
     x2 = x_nz.reshape(-1, x_nz.shape[-1]) if x_nz.ndim != 2 else x_nz
-    y = sparse_matvec_pallas(x2, idx.astype(jnp.int32), wt, bn=bn,
-                             interpret=not _ON_TPU)
+    rows = row_table(wt) if wt.ndim == 2 else wt
+    y = run_kernel(sparse_matvec_pallas, x2, idx.astype(jnp.int32), rows,
+                   bn=bn)
     y = y.astype(x_nz.dtype)
-    return y[0] if squeeze else y.reshape(*lead, wt.shape[1])
+    return y[0] if squeeze else y.reshape(*lead, wt.shape[-1])
 
 
 @functools.partial(jax.jit, static_argnames=("k", "bn"))
 def topk_sparse_matmul(
     x: jax.Array,  # (..., K) activations (possibly sparse)
-    wt: jax.Array,  # (K, N)
+    wt: jax.Array,  # (K, N), or its (K, 1, N) row_table
     k: int,
     *,
     bn: int = 512,
@@ -47,4 +49,4 @@ def topk_sparse_matmul(
     _, idx = jax.lax.top_k(scores, min(k, x2.shape[1]))
     idx = jnp.sort(idx)  # ascending → quasi-sequential HBM stripes
     x_nz = jnp.take(x2, idx, axis=1)
-    return sparse_matvec(x_nz, idx, wt, bn=bn).reshape(*lead, wt.shape[1])
+    return sparse_matvec(x_nz, idx, wt, bn=bn).reshape(*lead, wt.shape[-1])

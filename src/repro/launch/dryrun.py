@@ -37,6 +37,7 @@ from repro.launch.steps import build_step_bundle
 from repro.models.registry import get_arch
 from repro.roofline.analysis import analyze_compiled, roofline_terms
 from repro.roofline.analytic import analytic_cost
+from repro.roofline.hw import TPU_V5E
 from repro.sharding.mesh import make_plan
 from repro.utils.logging import get_logger
 
@@ -94,6 +95,7 @@ def run_cell(
             hbm_bytes=cost.hbm_bytes,
             collective_bytes_per_dev=stats.collective_bytes_per_dev,
             n_chips=n_chips,
+            hw=TPU_V5E,  # the production mesh models v5e pods, not this host
         )
         rec.update(
             status="ok",

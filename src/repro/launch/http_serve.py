@@ -36,6 +36,7 @@ from repro.models.registry import get_arch
 from repro.serve import (ContinuousScheduler, FrontDoor, HttpConfig,
                          ServeConfig, ServeEngine, TenantPolicy, TenantSpec)
 from repro.sharding.mesh import MeshPlan
+from repro.utils.compile_cache import enable_compile_cache
 from repro.utils.logging import get_logger
 
 log = get_logger("launch.http_serve")
@@ -175,6 +176,7 @@ def main() -> None:
                     help="self-test: drive N seeded in-process clients, "
                          "print a summary, drain, exit (0 = serve forever)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     arch = get_arch(args.arch, reduced=args.reduced)
     if arch.cfg.encoder_only:

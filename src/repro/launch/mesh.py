@@ -11,13 +11,9 @@ import jax
 
 
 def _mesh(shape, axes):
-    # jax ≥ 0.4.38 takes axis_types; older releases (the baked-in 0.4.37
-    # toolchain) have neither AxisType nor the kwarg — Auto is the default.
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -44,8 +44,10 @@ import numpy as np
 
 from repro.configs.base import ALL_ARCH_IDS
 from repro.models.registry import get_arch
+from repro.roofline.hw import device_peaks
 from repro.serve import ContinuousScheduler, ServeConfig, ServeEngine, SubmitRequest
 from repro.sharding.mesh import MeshPlan
+from repro.utils.compile_cache import enable_compile_cache
 from repro.utils.logging import get_logger
 
 log = get_logger("launch.serve")
@@ -340,6 +342,7 @@ def main() -> None:
                          "from the analytic autotuner before serving "
                          "(poisson only; overrides those flags)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     arch = get_arch(args.arch, reduced=args.reduced)
     if arch.cfg.encoder_only:
@@ -388,6 +391,8 @@ def main() -> None:
                          "--weight-quant int8")
     if not 0.0 <= args.weight_quant_sparsity < 1.0:
         raise SystemExit("--weight-quant-sparsity must be in [0, 1)")
+    # the autotuner prices against the chip this process serves on
+    hw = device_peaks(jax.devices()[0])
     # quantization changes the bytes the roofline moves per element
     cache_bpe = 1.03 if args.cache_quant_int8 else 2.0
     weight_bpe = (1.01 * (1.0 - args.weight_quant_sparsity)
@@ -404,7 +409,8 @@ def main() -> None:
                          n_slots=args.slots,
                          max_len=args.prompt_len + args.new_tokens + 1
                          + args.spec_k)
-        res = autotune(arch.cfg, w, paged=(args.kv_layout == "paged"),
+        res = autotune(arch.cfg, w, hw,
+                       paged=(args.kv_layout == "paged"),
                        spec_ks=(0, args.spec_k) if args.spec_k else (0,),
                        cache_bytes_per_elem=cache_bpe,
                        weight_bytes_per_elem=weight_bpe)
@@ -482,7 +488,7 @@ def main() -> None:
                                  tuple(int(x) for x in n_news),
                                  n_slots=args.slots,
                                  max_len=max_len)
-                res2 = autotune(arch.cfg, w,
+                res2 = autotune(arch.cfg, w, hw,
                                 paged=(args.kv_layout == "paged"),
                                 spec_ks=(0, sched.spec.k),
                                 spec_accept_len=acc,

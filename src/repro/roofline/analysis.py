@@ -29,7 +29,7 @@ from typing import Any
 
 import numpy as np
 
-from repro.roofline.hw import HWTarget, TPU_V5E
+from repro.roofline.hw import HWTarget
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "s64": 8, "u64": 8,
@@ -264,7 +264,7 @@ def roofline_terms(
     hbm_bytes: float,
     collective_bytes_per_dev: float,
     n_chips: int,
-    hw: HWTarget = TPU_V5E,
+    hw: HWTarget,
 ) -> RooflineTerms:
     compute = exec_flops / (n_chips * hw.peak_flops_bf16)
     memory = hbm_bytes / (n_chips * hw.hbm_bw)

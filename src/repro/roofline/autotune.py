@@ -37,7 +37,7 @@ from repro.roofline.analytic import (
     spec_verify_cost,
     step_time,
 )
-from repro.roofline.hw import TPU_V5E, HWTarget
+from repro.roofline.hw import HWTarget
 
 
 @dataclasses.dataclass(frozen=True)
@@ -94,7 +94,7 @@ def predict(
     knobs: KnobConfig,
     workload: WorkloadSpec,
     cfg: ModelConfig,
-    hw: HWTarget = TPU_V5E,
+    hw: HWTarget,
     oh: HostOverheads | None = None,
     spec_accept_len: float | None = None,
     paged: bool = False,
@@ -261,8 +261,8 @@ class AutotuneResult:
 def autotune(
     cfg: ModelConfig,
     workload: WorkloadSpec,
+    hw: HWTarget,
     candidates: list[KnobConfig] | None = None,
-    hw: HWTarget = TPU_V5E,
     oh: HostOverheads | None = None,
     spec_accept_len: float | None = None,
     paged: bool = False,
@@ -304,9 +304,9 @@ class DrainPredictor:
         knobs: KnobConfig,
         n_slots: int,
         max_len: int,
+        hw: HWTarget,
         paged: bool = False,
         alpha: float = 0.2,
-        hw: HWTarget = TPU_V5E,
     ):
         assert 0.0 < alpha <= 1.0, alpha
         self.cfg = cfg

@@ -1370,6 +1370,7 @@ class ContinuousScheduler:
         against measured per-request walls and predicts ``Retry-After``
         from ``queue_composition()`` instead of a scalar EWMA."""
         from repro.roofline.autotune import DrainPredictor, KnobConfig
+        from repro.roofline.hw import device_peaks
 
         knobs = KnobConfig(
             segment_len=self.segment_len,
@@ -1378,9 +1379,12 @@ class ContinuousScheduler:
             spec_k=self.spec_k,
             block_len=self.block_len if self.paged else 0,
         )
+        # priced on the serving device's peaks; observe() scales that to
+        # wall seconds
         return DrainPredictor(
             self.engine.arch.cfg, knobs, n_slots=self.n_slots,
-            max_len=self.engine.sc.max_len, paged=self.paged,
+            max_len=self.engine.sc.max_len, hw=device_peaks(jax.devices()[0]),
+            paged=self.paged,
         )
 
     # ------------------------------------------------------------ segment

@@ -15,6 +15,7 @@ from repro.kernels.sonic_matmul.ops import (
     DECODE_M_THRESHOLD, make_sonic_weight, sonic_matmul, sonic_matvec,
 )
 from repro.kernels.sonic_matmul.ref import sonic_matmul_ref, sonic_matvec_ref
+from repro.kernels.sparse_matvec.kernel import row_table
 from repro.kernels.sparse_matvec.ops import sparse_matvec, topk_sparse_matmul
 from repro.kernels.sparse_matvec.ref import sparse_matvec_ref
 
@@ -22,7 +23,8 @@ _TOL = {jnp.float32: dict(rtol=2e-5, atol=2e-5), jnp.bfloat16: dict(rtol=2e-2, a
 
 
 @pytest.mark.parametrize("m,k,n,c", [(8, 128, 128, 8), (16, 256, 256, 64),
-                                     (32, 512, 128, 16), (5, 256, 384, 64)])
+                                     (32, 512, 128, 16), (5, 256, 384, 64),
+                                     (8, 256, 256, 300)])  # codebook > 1 row
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_clustered_matmul(m, k, n, c, dtype):
     w = jax.random.normal(jax.random.PRNGKey(0), (k, n))
@@ -65,6 +67,24 @@ def test_sparse_matvec(b, k, n, knz, dtype):
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32), **_TOL[dtype]
     )
+
+
+@pytest.mark.parametrize("knz", [256, 300, 600])  # one chunk, padded, three
+def test_sparse_matvec_row_table(knz):
+    """The stored (K, 1, N) row table gives what the (K, N) weight gives,
+    across whole and padded chunks of kept rows."""
+    wt = jax.random.normal(jax.random.PRNGKey(0), (640, 256))
+    idx = jnp.sort(
+        jax.random.permutation(jax.random.PRNGKey(2), 640)[:knz]
+    ).astype(jnp.int32)
+    x_nz = jax.random.normal(jax.random.PRNGKey(3), (3, knz))
+    got = sparse_matvec(x_nz, idx, row_table(wt))
+    assert got.shape == (3, 256)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(sparse_matvec(x_nz, idx, wt)))
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(sparse_matvec_ref(x_nz, idx, wt)),
+                               rtol=2e-5, atol=2e-4)
 
 
 def test_topk_sparse_matmul_exact_on_sparse_input():
@@ -388,3 +408,33 @@ def test_topk_sparse_matmul_density_extremes(frac):
         xm[0, keep] = np.asarray(x)[0, keep]
         want = xm @ np.asarray(wt)
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+
+
+def test_no_module_queries_the_backend_at_import():
+    """Importing every ``repro`` module and the HTTP client initialises no
+    JAX backend: interpret mode is chosen when a kernel's caller is lowered,
+    and a process that only imports leaves the chip to others."""
+    import os
+    import pathlib
+    import subprocess
+    import sys
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    code = (
+        "import importlib, pkgutil, sys\n"
+        "import repro\n"
+        "from jax._src import xla_bridge\n"
+        "for m in pkgutil.walk_packages(repro.__path__, 'repro.'):\n"
+        "    importlib.import_module(m.name)\n"
+        "importlib.import_module('serve_client')\n"
+        "print(xla_bridge.backends_are_initialized())\n"
+    )
+    res = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        timeout=300, cwd=root,
+        env={**os.environ,
+             "PYTHONPATH": f"{root / 'src'}:{root / 'tools'}",
+             "JAX_PLATFORMS": "cpu"},
+    )
+    assert res.returncode == 0, res.stderr[-3000:]
+    assert res.stdout.strip() == "False"

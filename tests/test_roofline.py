@@ -8,6 +8,7 @@ from repro.roofline.analysis import (
     roofline_terms,
 )
 from repro.roofline.analytic import _param_counts, analytic_cost
+from repro.roofline.hw import TPU_V5E
 
 
 def test_param_counts_match_eval_shape():
@@ -38,7 +39,7 @@ def test_decode_is_weight_bound_in_analytic_model():
     # decode arithmetic intensity ≈ 2 flop/byte ⇒ memory term dominates at
     # v5e's 240 flop/byte ridge
     terms = roofline_terms(cost.model_flops, cost.hlo_flops_est,
-                           cost.hbm_bytes, 0.0, 256)
+                           cost.hbm_bytes, 0.0, 256, TPU_V5E)
     assert terms.dominant == "memory"
 
 
@@ -46,7 +47,7 @@ def test_train_is_compute_bound_in_analytic_model():
     cfg = get_config("command-r-35b")
     cost = analytic_cost(cfg, SHAPES["train_4k"])
     terms = roofline_terms(cost.model_flops, cost.hlo_flops_est,
-                           cost.hbm_bytes, 0.0, 256)
+                           cost.hbm_bytes, 0.0, 256, TPU_V5E)
     assert terms.dominant == "compute"
 
 
@@ -82,7 +83,7 @@ def test_collective_parser_trip_counts_and_ring_costs():
 
 
 def test_roofline_dominant_selection():
-    t = roofline_terms(1e12, 2e12, 1e9, 1e6, 256)
+    t = roofline_terms(1e12, 2e12, 1e9, 1e6, 256, TPU_V5E)
     assert t.useful_fraction == 0.5
     assert t.dominant in ("compute", "memory", "collective")
     assert t.step_time_est_s == max(t.compute_s, t.memory_s, t.collective_s)
@@ -165,7 +166,6 @@ def test_spec_verify_cost_is_draft_plus_verify():
 
 def test_step_time_is_roofline_max():
     from repro.roofline.analytic import StepCost, step_time
-    from repro.roofline.hw import TPU_V5E
 
     compute_bound = StepCost(1e15, 1.0, {})
     memory_bound = StepCost(1.0, 1e12, {})
@@ -173,3 +173,34 @@ def test_step_time_is_roofline_max():
                                1e15 / TPU_V5E.peak_flops_bf16)
     np.testing.assert_allclose(step_time(memory_bound, TPU_V5E),
                                1e12 / TPU_V5E.hbm_bw)
+
+
+def test_peak_table_keyed_by_device_kind():
+    import pytest
+
+    from repro.roofline.hw import PEAKS
+
+    v5e = PEAKS["TPU v5 lite"]  # jax.devices()[0].device_kind on a v5e
+    assert v5e is TPU_V5E
+    assert (v5e.peak_flops_bf16, v5e.peak_ops_int8, v5e.hbm_bw) == (
+        197e12, 393e12, 819e9)
+    with pytest.raises(KeyError):
+        PEAKS["cpu"]
+
+
+def test_device_peaks_resolves_the_running_device():
+    """Entry points price against the device they run on: a v5e by its
+    kind, the CPU as the v5e it rehearses, any other accelerator raises."""
+    from types import SimpleNamespace
+
+    import jax
+    import pytest
+
+    from repro.roofline.hw import device_peaks
+
+    assert device_peaks(jax.devices()[0]) is TPU_V5E  # tests run on the CPU
+    v5e = SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    assert device_peaks(v5e) is TPU_V5E
+    for platform, kind in (("tpu", "TPU v4"), ("gpu", "NVIDIA H100")):
+        with pytest.raises(ValueError, match=kind):
+            device_peaks(SimpleNamespace(platform=platform, device_kind=kind))

@@ -314,9 +314,11 @@ def test_elastic_drr_preserves_relative_shares():
 def test_drain_predictor_calibration():
     from repro.configs.base import get_config
     from repro.roofline.autotune import DrainPredictor, KnobConfig
+    from repro.roofline.hw import TPU_V5E
 
     pred = DrainPredictor(get_config("tinyllama-1.1b"),
-                          KnobConfig(segment_len=8), n_slots=4, max_len=192)
+                          KnobConfig(segment_len=8), n_slots=4, max_len=192,
+                          hw=TPU_V5E)
     assert not pred.calibrated
     assert pred.drain_s([16], [32]) is None  # cold: callers fall back
     pred.observe(16, 32, measured_s=2.0)
@@ -340,9 +342,11 @@ def test_drain_predictor_calibration():
 def test_drain_predictor_memoizes_shape_buckets():
     from repro.configs.base import get_config
     from repro.roofline.autotune import DrainPredictor, KnobConfig
+    from repro.roofline.hw import TPU_V5E
 
     pred = DrainPredictor(get_config("tinyllama-1.1b"),
-                          KnobConfig(segment_len=8), n_slots=4, max_len=192)
+                          KnobConfig(segment_len=8), n_slots=4, max_len=192,
+                          hw=TPU_V5E)
     pred.observe(15, 30, 1.0)
     pred.observe(16, 31, 1.0)  # same power-of-two buckets (16, 32)
     assert len(pred._single) == 1

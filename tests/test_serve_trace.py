@@ -7,6 +7,7 @@ import pytest
 
 from repro.models.registry import get_arch
 from repro.roofline.autotune import KnobConfig, WorkloadSpec, autotune, predict
+from repro.roofline.hw import TPU_V5E
 from repro.serve import (
     ContinuousScheduler,
     ServeConfig,
@@ -128,7 +129,7 @@ def test_autotune_ranks_roundtrip_heavy_config_last():
     w = WorkloadSpec(tuple(LENS), tuple(NEWS), n_slots=2, max_len=64)
     cands = [KnobConfig(segment_len=1), KnobConfig(segment_len=8),
              KnobConfig(segment_len=16, prefill_chunk=32)]
-    res = autotune(cfg, w, candidates=cands)
+    res = autotune(cfg, w, TPU_V5E, candidates=cands)
     assert res.best.segment_len > 1  # per-token round trips rank last
     assert res.ranked[-1].knobs.segment_len == 1
     assert [p.tok_s for p in res.ranked] == sorted(
@@ -140,11 +141,11 @@ def test_autotune_ranks_roundtrip_heavy_config_last():
 def test_predict_is_deterministic_and_terminates():
     cfg = get_arch("tinyllama-1.1b", reduced=True).cfg
     w = WorkloadSpec((4, 16, 8), (30, 5, 12), n_slots=2, max_len=64)
-    a = predict(KnobConfig(segment_len=8, prefill_chunk=16), w, cfg)
-    b = predict(KnobConfig(segment_len=8, prefill_chunk=16), w, cfg)
+    a = predict(KnobConfig(segment_len=8, prefill_chunk=16), w, cfg, TPU_V5E)
+    b = predict(KnobConfig(segment_len=8, prefill_chunk=16), w, cfg, TPU_V5E)
     assert a == b
     assert a.time_s > 0 and a.tok_s > 0 and a.n_segments > 0
     # spec priced pessimistically at accept_len=1: never beats plain decode
-    plain = predict(KnobConfig(segment_len=8), w, cfg)
-    spec = predict(KnobConfig(segment_len=8, spec_k=4), w, cfg)
+    plain = predict(KnobConfig(segment_len=8), w, cfg, TPU_V5E)
+    spec = predict(KnobConfig(segment_len=8, spec_k=4), w, cfg, TPU_V5E)
     assert spec.tok_s < plain.tok_s
