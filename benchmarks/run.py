@@ -895,7 +895,9 @@ def serve_prefill():
             "ttft_p95_s": p95,
             "cold_total_s": cold_t,
             "tok_s": useful / min(warm[mode]),
-            "admit_round_ms": 1e3 * st["admit_time_s"] / st["admit_rounds"],
+            "admit_round_ms": (1e3 * (st["host_s_admit"]
+                                      + st["dispatch_s_prefill"])
+                               / st["admit_rounds"]),
             "prefill_traces": traces,
         }
         if mode == "batched":
@@ -1258,8 +1260,6 @@ def serve_energy():
         "trace": {k: tr.totals[k] for k in
                   ("prefill_tokens", "decode_tokens", "prefill_launches",
                    "decode_segments", "decode_steps", "preemptions")},
-        "trace_flops": tr.totals["flops"],
-        "trace_hbm_bytes": tr.totals["hbm_bytes"],
         "photonic": {"platform": "SONIC", **sonic},
         "electronic": {"platform": "NullHop", **nullhop},
         "electronic_gpu": {"platform": "NP100", **rep["platforms"]["NP100"]},
@@ -1267,9 +1267,7 @@ def serve_energy():
     }
     print("\n== serve_energy: energy/token from a real scheduler trace ==")
     print(f"trace: {tr.totals['prefill_tokens']} prefill + "
-          f"{tr.totals['decode_tokens']} decode tokens, "
-          f"{tr.totals['flops'] / 1e9:.1f} GFLOP executed, "
-          f"{tr.totals['hbm_bytes'] / 1e9:.2f} GB moved")
+          f"{tr.totals['decode_tokens']} decode tokens")
     print(f"{'platform':>10s} {'J/token':>12s} {'tok/s/W':>10s} {'W':>8s}")
     for name in ("SONIC", "NullHop", "NP100"):
         r = rep["platforms"][name]

@@ -405,14 +405,18 @@ def attention_apply(
     b, s, d = x.shape
     h, kh, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     kv_repeat = plan.kv_repeat if plan is not None else 1
-    q = dense_apply(p["wq"], x).reshape(b, s, h, dh)
-    k = dense_apply(p["wk"], x).reshape(b, s, kh, dh)
-    v = dense_apply(p["wv"], x).reshape(b, s, kh, dh)
+    # named scopes (attn.*, kv.*) label the device ops of each kind of work
+    # in profiler traces and HLO metadata; they change no computation
+    with jax.named_scope("attn.qkv"):
+        q = dense_apply(p["wq"], x).reshape(b, s, h, dh)
+        k = dense_apply(p["wk"], x).reshape(b, s, kh, dh)
+        v = dense_apply(p["wv"], x).reshape(b, s, kh, dh)
 
     if cfg.pos_enc in ("rope", "mrope"):
-        ang = rope_angles(cfg, positions)
-        q = apply_rope(q, ang)
-        k = apply_rope(k, ang)
+        with jax.named_scope("attn.rope"):
+            ang = rope_angles(cfg, positions)
+            q = apply_rope(q, ang)
+            k = apply_rope(k, ang)
 
     if kv_repeat > 1:  # TP-friendly KV head replication (DESIGN.md §5)
         k = jnp.repeat(k, kv_repeat, axis=2)
@@ -447,66 +451,74 @@ def attention_apply(
         )
         k_pool, v_pool = cache
         quant = cache_scales is not None
-        if quant:
-            # per-block KV scales ride the SAME block table as the values:
-            # scale pools are (n_blocks, block_len, KH) — one fp32 per
-            # cached position per head — so the write/gather helpers below
-            # (which only index leading dims) work on them unchanged
-            ks_pool, vs_pool = cache_scales
-            k_w, ks_new = quantize_kv(k)
-            v_w, vs_new = quantize_kv(v)
-        else:
-            k_w, v_w = k, v
         write = paged_cache_write if s == 1 else paged_cache_write_chunk
-        k_pool = write(k_pool, block_table, k_w, cache_pos)
-        v_pool = write(v_pool, block_table, v_w, cache_pos)
-        if quant:
-            ks_pool = write(ks_pool, block_table, ks_new, cache_pos)
-            vs_pool = write(vs_pool, block_table, vs_new, cache_pos)
-        k_virt = paged_cache_gather(k_pool, block_table)
-        v_virt = paged_cache_gather(v_pool, block_table)
-        if quant:
-            k_virt = dequantize_kv(
-                k_virt, paged_cache_gather(ks_pool, block_table), q.dtype)
-            v_virt = dequantize_kv(
-                v_virt, paged_cache_gather(vs_pool, block_table), q.dtype)
-        if s == 1 or decode_chunk:
-            # decode step / speculative-verify window: one plain-softmax
-            # row per query token over the gathered (dequantized) cache
-            out = decode_attention(q, k_virt, v_virt, cache_pos)
-        else:  # chunk-resume prefill at block-table offsets
-            kv_pos = jnp.broadcast_to(
-                jnp.arange(k_virt.shape[1], dtype=jnp.int32),
-                (b, k_virt.shape[1]),
-            )
-            pos2d = positions if positions.ndim == 2 else positions[:, 0, :]
-            out = flash_attention(q, k_virt, v_virt, pos2d, kv_pos,
-                                  causal=causal)
-        out = dense_apply(p["wo"], out.reshape(b, s, h * dh))
+        with jax.named_scope("kv.write"):
+            if quant:
+                # per-block KV scales ride the SAME block table as the
+                # values: scale pools are (n_blocks, block_len, KH) — one
+                # fp32 per cached position per head — so the write/gather
+                # helpers below (which only index leading dims) work on
+                # them unchanged
+                ks_pool, vs_pool = cache_scales
+                k_w, ks_new = quantize_kv(k)
+                v_w, vs_new = quantize_kv(v)
+            else:
+                k_w, v_w = k, v
+            k_pool = write(k_pool, block_table, k_w, cache_pos)
+            v_pool = write(v_pool, block_table, v_w, cache_pos)
+            if quant:
+                ks_pool = write(ks_pool, block_table, ks_new, cache_pos)
+                vs_pool = write(vs_pool, block_table, vs_new, cache_pos)
+        with jax.named_scope("kv.gather"):
+            k_virt = paged_cache_gather(k_pool, block_table)
+            v_virt = paged_cache_gather(v_pool, block_table)
+            if quant:
+                k_virt = dequantize_kv(
+                    k_virt, paged_cache_gather(ks_pool, block_table), q.dtype)
+                v_virt = dequantize_kv(
+                    v_virt, paged_cache_gather(vs_pool, block_table), q.dtype)
+        with jax.named_scope("attn.core"):
+            if s == 1 or decode_chunk:
+                # decode step / speculative-verify window: one plain-softmax
+                # row per query token over the gathered (dequantized) cache
+                out = decode_attention(q, k_virt, v_virt, cache_pos)
+            else:  # chunk-resume prefill at block-table offsets
+                kv_pos = jnp.broadcast_to(
+                    jnp.arange(k_virt.shape[1], dtype=jnp.int32),
+                    (b, k_virt.shape[1]),
+                )
+                pos2d = (positions if positions.ndim == 2
+                         else positions[:, 0, :])
+                out = flash_attention(q, k_virt, v_virt, pos2d, kv_pos,
+                                      causal=causal)
+        with jax.named_scope("attn.out"):
+            out = dense_apply(p["wo"], out.reshape(b, s, h * dh))
         new_cache = ((k_pool, v_pool, ks_pool, vs_pool) if quant
                      else (k_pool, v_pool))
         return out, new_cache
     if cache is None:
         pos2d = positions if positions.ndim == 2 else positions[:, 0, :]
-        out = flash_attention(q, k, v, pos2d, pos2d, causal=causal)
+        with jax.named_scope("attn.core"):
+            out = flash_attention(q, k, v, pos2d, pos2d, causal=causal)
     else:
         k_cache, v_cache = cache
         quant = cache_scales is not None
-        if quant:
-            ks_cache, vs_cache = cache_scales
-            kq, ks_new = quantize_kv(k)
-            vq, vs_new = quantize_kv(v)
         # decode and chunk-resume write at the caller's per-row offsets;
         # whole-prompt prefill writes at 0
         write_pos = (cache_pos if cache_pos is not None
                      else jnp.zeros((b,), jnp.int32))
-        if quant:
-            k_cache = _dus_batch(k_cache, kq, write_pos)
-            v_cache = _dus_batch(v_cache, vq, write_pos)
-            ks_cache = _dus_batch(ks_cache, ks_new, write_pos)
-            vs_cache = _dus_batch(vs_cache, vs_new, write_pos)
-        else:
-            k_cache, v_cache = update_kv_cache(k_cache, v_cache, k, v, write_pos)
+        with jax.named_scope("kv.write"):
+            if quant:
+                ks_cache, vs_cache = cache_scales
+                kq, ks_new = quantize_kv(k)
+                vq, vs_new = quantize_kv(v)
+                k_cache = _dus_batch(k_cache, kq, write_pos)
+                v_cache = _dus_batch(v_cache, vq, write_pos)
+                ks_cache = _dus_batch(ks_cache, ks_new, write_pos)
+                vs_cache = _dus_batch(vs_cache, vs_new, write_pos)
+            else:
+                k_cache, v_cache = update_kv_cache(k_cache, v_cache, k, v,
+                                                   write_pos)
         if plan is not None and plan.mesh is not None:
             cspec = plan.cache_spec()
             k_cache = plan.constrain(k_cache, *cspec)
@@ -514,57 +526,59 @@ def attention_apply(
             if quant:
                 ks_cache = plan.constrain(ks_cache, *cspec[:3])
                 vs_cache = plan.constrain(vs_cache, *cspec[:3])
-        if quant and s > 1 and not decode_chunk:
-            # int8-KV bit-exactness recipe (ISSUE 10, docs/serving.md):
-            # EVERY prefill — whole-prompt and chunk-resume alike — attends
-            # the dequantized cache it just wrote, never the exact fresh
-            # k/v.  Whole-prompt prefill is then literally the write_pos=0
-            # case of chunk-resume, so chunked prefill is bitwise identical
-            # to whole-prompt under quant, and the decode/verify branch
-            # below attends the same dequantized values — one value stream
-            # for all paths.  Stale rows past the causal frontier are
-            # masked to exact zeros.
-            k_att = dequantize_kv(k_cache, ks_cache, q.dtype)
-            v_att = dequantize_kv(v_cache, vs_cache, q.dtype)
-            kv_pos = jnp.broadcast_to(
-                jnp.arange(k_cache.shape[1], dtype=jnp.int32),
-                (b, k_cache.shape[1]),
-            )
-            pos2d = positions if positions.ndim == 2 else positions[:, 0, :]
-            out = flash_attention(q, k_att, v_att, pos2d, kv_pos,
-                                  causal=causal)
-        elif s == 1 or (decode_chunk and cache_pos is not None):
-            # decode step / speculative-verify window: attend over the
-            # (dequantized) cache, one plain-softmax row per query token —
-            # under quant each verify row recomputes exactly what the
-            # sequential decode step would, so greedy spec outputs stay
-            # bit-identical to non-speculative int8-KV decoding
-            assert cache_pos is not None
-            if quant:
+        with jax.named_scope("attn.core"):
+            if quant and s > 1 and not decode_chunk:
+                # int8-KV bit-exactness recipe (docs/serving.md):
+                # EVERY prefill — whole-prompt and chunk-resume alike — attends
+                # the dequantized cache it just wrote, never the exact fresh
+                # k/v.  Whole-prompt prefill is then literally the write_pos=0
+                # case of chunk-resume, so chunked prefill is bitwise identical
+                # to whole-prompt under quant, and the decode/verify branch
+                # below attends the same dequantized values — one value stream
+                # for all paths.  Stale rows past the causal frontier are
+                # masked to exact zeros.
                 k_att = dequantize_kv(k_cache, ks_cache, q.dtype)
                 v_att = dequantize_kv(v_cache, vs_cache, q.dtype)
-            else:
-                k_att, v_att = k_cache, v_cache
-            out = decode_attention(q, k_att, v_att, cache_pos)
-        elif cache_pos is not None:  # chunk-resume: attend over the cache
-            # (prefix from earlier chunks + this chunk's freshly written
-            # rows); positions past the chunk end are causally masked, so
-            # stale tenant rows contribute exact zeros
-            kv_pos = jnp.broadcast_to(
-                jnp.arange(k_cache.shape[1], dtype=jnp.int32),
-                (b, k_cache.shape[1]),
-            )
-            pos2d = positions if positions.ndim == 2 else positions[:, 0, :]
-            out = flash_attention(q, k_cache, v_cache, pos2d, kv_pos,
-                                  causal=causal)
-        else:  # whole-prompt prefill: attend over the fresh (exact) k/v
-            pos2d = positions if positions.ndim == 2 else positions[:, 0, :]
-            out = flash_attention(q, k, v, pos2d, pos2d, causal=causal)
+                kv_pos = jnp.broadcast_to(
+                    jnp.arange(k_cache.shape[1], dtype=jnp.int32),
+                    (b, k_cache.shape[1]),
+                )
+                pos2d = positions if positions.ndim == 2 else positions[:, 0, :]
+                out = flash_attention(q, k_att, v_att, pos2d, kv_pos,
+                                      causal=causal)
+            elif s == 1 or (decode_chunk and cache_pos is not None):
+                # decode step / speculative-verify window: attend over the
+                # (dequantized) cache, one plain-softmax row per query token —
+                # under quant each verify row recomputes exactly what the
+                # sequential decode step would, so greedy spec outputs stay
+                # bit-identical to non-speculative int8-KV decoding
+                assert cache_pos is not None
+                if quant:
+                    k_att = dequantize_kv(k_cache, ks_cache, q.dtype)
+                    v_att = dequantize_kv(v_cache, vs_cache, q.dtype)
+                else:
+                    k_att, v_att = k_cache, v_cache
+                out = decode_attention(q, k_att, v_att, cache_pos)
+            elif cache_pos is not None:  # chunk-resume: attend over the cache
+                # (prefix from earlier chunks + this chunk's freshly written
+                # rows); positions past the chunk end are causally masked, so
+                # stale tenant rows contribute exact zeros
+                kv_pos = jnp.broadcast_to(
+                    jnp.arange(k_cache.shape[1], dtype=jnp.int32),
+                    (b, k_cache.shape[1]),
+                )
+                pos2d = positions if positions.ndim == 2 else positions[:, 0, :]
+                out = flash_attention(q, k_cache, v_cache, pos2d, kv_pos,
+                                      causal=causal)
+            else:  # whole-prompt prefill: attend over the fresh (exact) k/v
+                pos2d = positions if positions.ndim == 2 else positions[:, 0, :]
+                out = flash_attention(q, k, v, pos2d, pos2d, causal=causal)
         new_cache = (
             (k_cache, v_cache, ks_cache, vs_cache) if quant else (k_cache, v_cache)
         )
 
-    out = dense_apply(p["wo"], out.reshape(b, s, h * dh))
+    with jax.named_scope("attn.out"):
+        out = dense_apply(p["wo"], out.reshape(b, s, h * dh))
     return out, new_cache
 
 

@@ -99,10 +99,11 @@ def layer_apply(
     x = x + h
 
     hin = L.norm_apply(p["ln2"], x)
-    if cfg.n_experts:
-        h2 = M.moe_apply(p["moe"], cfg, hin, plan)
-    else:
-        h2 = L.ffn_apply(p["ffn"], cfg, hin)
+    with jax.named_scope("ffn"):
+        if cfg.n_experts:
+            h2 = M.moe_apply(p["moe"], cfg, hin, plan)
+        else:
+            h2 = L.ffn_apply(p["ffn"], cfg, hin)
     h2 = plan.constrain(h2, plan.dp, seq, None)
     x = plan.constrain(x + h2, plan.dp, seq, None)
     return x, new_cache
@@ -223,10 +224,11 @@ def forward(
         decode_chunk,
     )
     x = L.norm_apply(params["final_norm"], x)
-    if cfg.tie_embeddings:
-        logits = x @ params["embed"]["embedding"].astype(x.dtype).T
-    else:
-        logits = L.lm_head_apply(params["lm_head"], x)
+    with jax.named_scope("lm_head"):
+        if cfg.tie_embeddings:
+            logits = x @ params["embed"]["embedding"].astype(x.dtype).T
+        else:
+            logits = L.lm_head_apply(params["lm_head"], x)
     logits = plan.constrain(logits, plan.dp, None, plan.tp)
     return logits, new_cache
 

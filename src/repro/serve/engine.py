@@ -66,6 +66,7 @@ docs/serving.md.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
@@ -158,6 +159,18 @@ class ServeConfig:
     # accounting.  False keeps the zero-overhead path — the scheduler never
     # allocates a recorder and every hook site is one ``is None`` check.
     trace: bool = False
+
+
+def _scoped(name: str, fn):
+    """``fn`` traced under ``jax.named_scope(name)``, with its own name and
+    signature."""
+
+    @functools.wraps(fn)
+    def scoped(*args, **kwargs):
+        with jax.named_scope(name):
+            return fn(*args, **kwargs)
+
+    return scoped
 
 
 _SLOT_PROGRAMS = ("prefill_slot", "prefill_slots", "slot_segment",
@@ -275,7 +288,9 @@ class ServeEngine:
         self._checked_contracts: set[str] = set()
 
         def sample(logits, key):
-            return sample_token(logits, key, sc.temperature, sc.top_k, sc.top_p)
+            with jax.named_scope("sample"):
+                return sample_token(logits, key, sc.temperature, sc.top_k,
+                                    sc.top_p)
 
         def prefill(params, tokens, key):
             self.trace_counts["prefill"] += 1
@@ -701,6 +716,11 @@ class ServeEngine:
                         dict(static_argnums=(0,), donate_argnums=(3, 4, 5, 6)),
                     )
 
+        # each slot program traces under a named scope of its own name, so
+        # its device ops say which flavour ran (the jitted function keeps
+        # its name: the XLA modules stay ``jit_segment`` / ``jit_prefill_*``)
+        slot_progs = {nm: (_scoped(nm, fn), jkw)
+                      for nm, (fn, jkw) in slot_progs.items()}
         if sc.jit:
             self._prefill = jax.jit(prefill)
             self._decode = jax.jit(decode)

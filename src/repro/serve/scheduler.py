@@ -109,6 +109,7 @@ from repro.serve.engine import ServeEngine
 from repro.serve.policy import Overloaded, RateLimited, TenantPolicy
 from repro.serve.request import (CANCELLED, EXPIRED, FINISHED, QUEUED,
                                  RUNNING, Request, SubmitRequest)
+from repro.serve.trace import Phases, TraceRecorder
 from repro.utils.logging import get_logger
 
 log = get_logger("serve.scheduler")
@@ -381,7 +382,6 @@ class ContinuousScheduler:
             "blocks_in_use_peak": 0,
             # batched/chunked admission accounting (serve_prefill bench)
             "admit_rounds": 0,
-            "admit_time_s": 0.0,
             "prefill_launches": 0,
             "chunks_prefilled": 0,
             "prefill_batch_hist": {},  # real rows per launch -> count
@@ -419,15 +419,27 @@ class ContinuousScheduler:
             # transitions observed by this scheduler
             "preemptions_by_class": {},
             "brownout_changes": 0,
+            # run_segment's phases (serve/trace.py ``Phases``): host
+            # seconds of each span's own work, and the dispatch seconds of
+            # program calls (uploads + launch, not the device's run); the
+            # device waits are spans without a counter.  Flat scalars, so
+            # a ``dict(stats)`` snapshot holds their values
+            "host_s_sweep": 0.0,
+            "host_s_admit": 0.0,
+            "host_s_grow": 0.0,
+            "host_s_retire": 0.0,
+            "dispatch_s_prefill": 0.0,
+            "dispatch_s_segment": 0.0,
+            # prefill launch shapes: real prompt tokens, and width × bucket
+            # tokens computed (dummy rows and bucket padding included)
+            "prefill_tokens_real": 0,
+            "prefill_tokens_launched": 0,
         }
+        self._phase = Phases(self.stats, clock)
 
         # opt-in per-segment trace recorder (ServeConfig.trace, ISSUE 7);
         # None keeps every hook site to a single attribute check
-        self.trace = None
-        if engine.sc.trace:
-            from repro.serve.trace import TraceRecorder
-
-            self.trace = TraceRecorder(engine)
+        self.trace = TraceRecorder(engine) if engine.sc.trace else None
 
     # -------------------------------------------------------------- paged
 
@@ -897,13 +909,11 @@ class ContinuousScheduler:
     # -------------------------------------------------------------- admit
 
     def _admit(self) -> int:
-        """One admit round (timed for the serve_prefill bench): batched/
-        chunked admission when ``prefill_chunk`` is set, else the PR 2/3
-        one-request-per-launch path."""
-        t0 = self.clock()
-        n = (self._admit_chunked() if self.chunked
-             else self._admit_per_request())
-        self.stats["admit_time_s"] += self.clock() - t0
+        """One admit round: batched/chunked admission when
+        ``prefill_chunk`` is set, else one request per launch."""
+        with self._phase("admit", "host_s_admit"):
+            n = (self._admit_chunked() if self.chunked
+                 else self._admit_per_request())
         self.stats["admit_rounds"] += 1
         return n
 
@@ -1136,29 +1146,35 @@ class ContinuousScheduler:
                     bt[i, nb_mapped:] = (pool_size + i * self.max_blocks
                                          + np.arange(nb_mapped,
                                                      self.max_blocks))
-            self.key, sub = jax.random.split(self.key)
-            args = (eng.params, self.cache, self.tok, self.pos, self.done,
-                    jnp.asarray(prompts), jnp.asarray(slots_v),
-                    jnp.asarray(starts), jnp.asarray(last_local))
-            if self.paged:
-                fn, ckey = eng._prefill_slots_paged, "prefill_slots_paged"
-                args = (*args, jnp.asarray(bt), sub)
-            else:
-                fn, ckey = eng._prefill_slots, "prefill_slots"
-                args = (*args, sub)
-            self.cache, self.tok, self.pos, self.done, firsts = fn(*args)
+            real_tokens = sum(r[2] for r in rows)
+            with self._phase("prefill_dispatch", "dispatch_s_prefill",
+                             width=width, bucket=bucket,
+                             real_tokens=real_tokens):
+                self.key, sub = jax.random.split(self.key)
+                args = (eng.params, self.cache, self.tok, self.pos, self.done,
+                        jnp.asarray(prompts), jnp.asarray(slots_v),
+                        jnp.asarray(starts), jnp.asarray(last_local))
+                if self.paged:
+                    fn, ckey = eng._prefill_slots_paged, "prefill_slots_paged"
+                    args = (*args, jnp.asarray(bt), sub)
+                else:
+                    fn, ckey = eng._prefill_slots, "prefill_slots"
+                    args = (*args, sub)
+                self.cache, self.tok, self.pos, self.done, firsts = fn(*args)
             eng.call_counts[ckey] += 1
             launched.append((rows, firsts))
             self.stats["prefill_launches"] += 1
             self.stats["chunks_prefilled"] += len(rows)
+            self.stats["prefill_tokens_real"] += real_tokens
+            self.stats["prefill_tokens_launched"] += width * bucket
             hist = self.stats["prefill_batch_hist"]
             hist[len(rows)] = hist.get(len(rows), 0) + 1
             if self.trace is not None:
-                self.trace.record_prefill(
-                    self.stats["segments"], width, bucket,
-                    sum(r[2] for r in rows), [r[1] for r in rows])
+                self.trace.record_prefill(self.stats["segments"], width,
+                                          bucket, real_tokens)
         # the ONLY admit-round download: every launch's first tokens at once
-        firsts_h = jax.device_get([f for _, f in launched])
+        with self._phase("prefill_wait"):
+            firsts_h = jax.device_get([f for _, f in launched])
         now = self.clock()
         n_live = 0
         for (rows, _), fh in zip(launched, firsts_h):
@@ -1244,29 +1260,34 @@ class ContinuousScheduler:
                     self._swap_in(slot, req)
                     continue
                 prefix = self._prefix.pop(slot)
-                self.key, sub = jax.random.split(self.key)
-                if self.paged:
-                    self.cache, self.tok, self.pos, self.done, first = (
-                        eng._prefill_slot_paged(
-                            eng.params, self.cache, self.tok, self.pos,
-                            self.done, jnp.asarray(prefix)[None, :],
-                            jnp.int32(slot),
-                            jnp.asarray(self.block_table[slot]), sub,
+                n = len(prefix)
+                with self._phase("prefill_dispatch", "dispatch_s_prefill",
+                                 width=1, bucket=n, real_tokens=n):
+                    self.key, sub = jax.random.split(self.key)
+                    if self.paged:
+                        self.cache, self.tok, self.pos, self.done, first = (
+                            eng._prefill_slot_paged(
+                                eng.params, self.cache, self.tok, self.pos,
+                                self.done, jnp.asarray(prefix)[None, :],
+                                jnp.int32(slot),
+                                jnp.asarray(self.block_table[slot]), sub,
+                            )
                         )
-                    )
-                    eng.call_counts["prefill_slot_paged"] += 1
-                else:
-                    self.cache, self.tok, self.pos, self.done, first = (
-                        eng._prefill_slot(
-                            eng.params, self.cache, self.tok, self.pos,
-                            self.done, jnp.asarray(prefix)[None, :],
-                            jnp.int32(slot), sub,
+                    else:
+                        self.cache, self.tok, self.pos, self.done, first = (
+                            eng._prefill_slot(
+                                eng.params, self.cache, self.tok, self.pos,
+                                self.done, jnp.asarray(prefix)[None, :],
+                                jnp.int32(slot), sub,
+                            )
                         )
-                    )
-                    eng.call_counts["prefill_slot"] += 1
+                eng.call_counts["prefill_slot_paged" if self.paged
+                                else "prefill_slot"] += 1
+                # one row at the prompt's own length: nothing padded
+                self.stats["prefill_tokens_real"] += n
+                self.stats["prefill_tokens_launched"] += n
                 if self.trace is not None:
-                    self.trace.record_prefill(self.stats["segments"], 1,
-                                              len(prefix), len(prefix), [0])
+                    self.trace.record_prefill(self.stats["segments"], 1, n, n)
                 resumed = bool(req.tokens)
                 pending.append((req, slot, first, resumed))
                 if resumed:
@@ -1293,7 +1314,8 @@ class ContinuousScheduler:
                 self.limit[slot] = req.prompt_len + req.max_new_tokens - 1
         if not pending:
             return 0
-        firsts = jax.device_get([f for _, _, f, _ in pending])
+        with self._phase("prefill_wait"):
+            firsts = jax.device_get([f for _, _, f, _ in pending])
         now = self.clock()
         for (req, slot, _, resumed), first in zip(pending, firsts):
             if resumed:
@@ -1404,43 +1426,65 @@ class ContinuousScheduler:
         With ``ServeConfig.debug_invariants`` the allocator/table/commitment
         invariants are checked at the end of EVERY segment, so a violation
         fails at the segment that caused it, not at retire.
+
+        Each phase runs inside a ``serve.*`` profiler span and adds its host
+        seconds to a flat counter of ``stats`` (serve/trace.py ``Phases``).
         """
-        debug = self.engine.sc.debug_invariants
-        self._inject_chaos()
-        self._sweep_terminal()
-        self._update_slo()
+        self._phase.segment = self.stats["segments"]
+        with self._phase("run_segment"):
+            n = self._segment()
+        if self.engine.sc.debug_invariants:
+            self.check_block_invariants()
+        return n
+
+    def _segment(self) -> int:
+        with self._phase("sweep", "host_s_sweep"):
+            self._inject_chaos()
+            self._sweep_terminal()
+            self._update_slo()
         self._admit()
-        self._ensure_segment_capacity()
+        with self._phase("grow", "host_s_grow"):
+            self._ensure_segment_capacity()
         if not self.active.any():
-            if debug:
-                self.check_block_invariants()
             return 0
         eng = self.engine
         seg_key = "slot_spec_segment" if self.spec is not None else "slot_segment"
         params_args = ((eng.params, eng.draft_params)
                        if self.spec is not None else (eng.params,))
-        base = (self.segment_len, *params_args, self.cache,
-                self.tok, self.pos, self.done, self.key,
-                jnp.asarray(self.active), jnp.asarray(self.limit))
-        if self.segment_mode == "while":
-            # early-exit at retirement boundaries whenever admission work
-            # is pending: queued requests, or a claimed prompt still mid-
-            # chunked-prefill (its next chunk only advances between
-            # segments, so riding out a long segment delays its TTFT)
-            pending = bool(self.queue) or bool(self._prefill_start)
-            args = (*base, jnp.bool_(pending))
-            seg_key += "_while"
-        else:
-            args = base
-        if self.paged:
-            args = (*args, jnp.asarray(self.block_table))
-            seg_key += "_paged"
-        seg_fn = getattr(eng, "_" + seg_key)
-        toks, self.cache, self.tok, self.pos, self.done, self.key = (
-            seg_fn(*args)
-        )
+        with self._phase("segment_dispatch", "dispatch_s_segment"):
+            base = (self.segment_len, *params_args, self.cache,
+                    self.tok, self.pos, self.done, self.key,
+                    jnp.asarray(self.active), jnp.asarray(self.limit))
+            if self.segment_mode == "while":
+                # early-exit at retirement boundaries whenever admission
+                # work is pending: queued requests, or a claimed prompt
+                # still mid-chunked-prefill (its next chunk only advances
+                # between segments, so riding out a long segment delays its
+                # TTFT)
+                pending = bool(self.queue) or bool(self._prefill_start)
+                args = (*base, jnp.bool_(pending))
+                seg_key += "_while"
+            else:
+                args = base
+            if self.paged:
+                args = (*args, jnp.asarray(self.block_table))
+                seg_key += "_paged"
+            seg_fn = getattr(eng, "_" + seg_key)
+            toks, self.cache, self.tok, self.pos, self.done, self.key = (
+                seg_fn(*args)
+            )
         eng.call_counts[seg_key] += 1
-        toks = np.asarray(toks)  # the only per-segment download
+        with self._phase("segment_wait"):
+            toks = np.asarray(toks)  # the only per-segment download
+        with self._phase("retire", "host_s_retire") as span:
+            n_exec, n_live = self._retire(toks)
+            span.set_metadata(steps=n_exec, live=n_live)
+        return sum(r is not None for r in self.slots)
+
+    def _retire(self, toks: np.ndarray) -> tuple[int, int]:
+        """Account one segment's emissions, stream them and retire the
+        finished requests.  Returns (steps executed, live slot-steps)."""
+        eng = self.engine
         self.stats["segments"] += 1
         if self.spec is not None:
             # (n_slots, S, k+1): per-step emission counts feed the
@@ -1515,9 +1559,7 @@ class ContinuousScheduler:
                                                 now - req.submit_t)
                 self._vacate_slot(slot)
                 self.stats["retired"] += 1
-        if debug:
-            self.check_block_invariants()
-        return sum(r is not None for r in self.slots)
+        return n_exec, int(live_counts.sum())
 
     # ---------------------------------------------------------------- run
 

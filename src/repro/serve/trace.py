@@ -1,22 +1,21 @@
-"""Per-segment serving trace: cheap host-side counters + analytic pricing.
+"""Serving observability: host spans of the serving loop, and the opt-in
+per-launch trace recorder.
 
-Opt-in via ``ServeConfig.trace=True``.  The scheduler then owns a
-:class:`TraceRecorder` and calls its ``record_*`` hooks from the launch
+Spans (``Phases``): every ``ContinuousScheduler.run_segment`` call opens
+``serve.*`` ``jax.profiler.TraceAnnotation`` spans around its phases and
+adds each phase's host seconds to one flat counter of the scheduler's
+``stats`` (``host_s_*``, ``dispatch_s_*``).  The annotations land in the
+profiler's own trace, on the device trace's clock; with no profiler running
+they cost two clock reads and a no-op annotation each.  docs/serving.md
+("Observability") lists the spans and counters.
+
+Recorder (``TraceRecorder``): opt-in via ``ServeConfig.trace=True``.  The
+scheduler then owns one and calls its ``record_*`` hooks from the launch
 sites (prefill dispatch, decode/spec segment, preemption/swap).  With
 tracing off the scheduler's ``trace`` attribute is ``None`` and every hook
-site is a single ``is not None`` check — the zero-overhead path.
-
-Conventions (shared with roofline/analytic.py's step-cost models):
-
-* ``tokens`` counts USEFUL tokens — real prompt tokens prefilled, live
-  decode emissions (replayed tokens included: the device computed them).
-* ``flops`` / ``hbm_bytes`` count EXECUTED work: a decode segment runs all
-  ``n_slots`` rows (masked ones included) attending the full ``max_len``
-  context every step, and a chunked-prefill launch is padded to its
-  power-of-two width.  The gap between the two columns is exactly the
-  masked/padding waste a knob change can claw back.
-* Preemption events record the swap payload bytes (host<->device), kept
-  out of the ``hbm_bytes`` total — they are PCIe traffic, not HBM.
+site is a single ``is not None`` check.  ``tokens`` counts USEFUL tokens:
+real prompt tokens prefilled, live decode emissions (replayed tokens
+included: the device computed them).
 
 ``trace_energy`` bridges a finished trace to the photonic energy model:
 per-token Joules from ``photonic.mapper.lm_workload`` (linear layers only —
@@ -27,16 +26,56 @@ baselines, scaled by the trace's token count.
 from __future__ import annotations
 
 import dataclasses
-from typing import Sequence
+from typing import Callable, Sequence
 
-from repro.roofline.analytic import (
-    StepCost,
-    decode_step_cost,
-    prefill_chunk_cost,
-    spec_verify_cost,
-)
+import jax
 
 PHASES = ("prefill", "decode", "spec", "preempt", "brownout")
+
+
+class Phases:
+    """Host spans of one scheduler's serving loop.
+
+    ``phases(name, key, **args)`` is a context manager: it opens the
+    profiler span ``serve.<name>`` tagged with ``segment`` (the segment the
+    current ``run_segment`` call launches, 0-based) and ``args``, and on exit
+    adds the span's SELF time — its seconds less those of the spans opened
+    inside it — to ``stats[key]``.  ``key`` None times nothing (a parent or
+    a device wait).  Span arguments must be host values: reading a device
+    value would sync."""
+
+    def __init__(self, stats: dict, clock: Callable[[], float]):
+        self.stats, self.clock = stats, clock
+        self.segment = 0
+        self._inner = [0.0]  # per open span: seconds of the spans inside it
+
+    def __call__(self, name: str, key: str | None = None, **args) -> "_Span":
+        return _Span(self, name, key, args)
+
+
+class _Span:
+    __slots__ = ("phases", "key", "ann", "t0")
+
+    def __init__(self, phases: Phases, name: str, key: str | None,
+                 args: dict):
+        self.phases, self.key = phases, key
+        self.ann = jax.profiler.TraceAnnotation(
+            "serve." + name, segment=phases.segment, **args)
+
+    def __enter__(self) -> jax.profiler.TraceAnnotation:
+        self.ann.__enter__()
+        self.phases._inner.append(0.0)
+        self.t0 = self.phases.clock()
+        return self.ann  # ``set_metadata(**host_values)`` tags it late
+
+    def __exit__(self, *exc) -> None:
+        p = self.phases
+        dt = p.clock() - self.t0
+        inner = p._inner.pop()
+        p._inner[-1] += dt
+        if self.key is not None:
+            p.stats[self.key] += dt - inner
+        self.ann.__exit__(*exc)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -46,8 +85,6 @@ class PhaseRecord:
     batch: int  # rows the launch executed (padded width / n_slots)
     steps: int  # loop steps (decode/spec) or chunk length (prefill)
     tokens: int  # useful tokens (see module docstring)
-    flops: float  # executed FLOPs (analytic)
-    hbm_bytes: float  # executed HBM traffic (analytic; swap bytes excluded)
 
 
 class TraceRecorder:
@@ -55,102 +92,47 @@ class TraceRecorder:
 
     def __init__(self, engine):
         self.cfg = engine.cfg
-        self.max_len = engine.sc.max_len
-        spec = engine.spec
-        self.spec_k = spec.k if spec is not None else 0
-        self.draft_layers = (engine.draft_cfg.n_layers
-                             if spec is not None and engine.draft_cfg is not None
-                             else None)
-        self.cache_bytes_per_elem = (
-            1.03 if engine.plan.cache_quant_int8 else 2.0)
-        # int8 block-sparse serving weights (ISSUE 10): kept blocks move as
-        # int8 + one fp32 scale + one int32 index each (~1.01 bytes/elem at
-        # the 128-tile default), and pruned blocks never leave HBM — the
-        # density folds straight into the per-element price
-        sc = engine.sc
-        self.weight_bytes_per_elem = (
-            1.01 * (1.0 - sc.weight_quant_sparsity)
-            if getattr(sc, "weight_quant", "none") == "int8" else 2.0)
         self.events: list[PhaseRecord] = []
         # per-tenant emitted-token counters (PR 8): the billing basis —
         # the scheduler calls note_tenant_tokens once per live emission
         # (replays excluded), keyed by the request's tenant label
         self.tenant_tokens: dict[str, int] = {}
-        self.totals: dict[str, float] = {
+        self.totals: dict[str, int] = {
             "prefill_tokens": 0, "prefill_launches": 0,
             "decode_tokens": 0, "decode_segments": 0, "decode_steps": 0,
             "spec_tokens": 0, "spec_segments": 0, "spec_live_steps": 0,
             "preemptions": 0, "swap_bytes": 0,
             "brownout_changes": 0, "brownout_level_peak": 0,
-            "flops": 0.0, "hbm_bytes": 0.0,
         }
-        # segments repeat the same (batch, steps) shape thousands of times;
-        # memoize the per-step analytic price
-        self._decode_memo: dict[int, StepCost] = {}
-        self._spec_memo: dict[int, StepCost] = {}
-
-    # -- pricing ----------------------------------------------------------
-    def _decode_cost(self, batch: int) -> StepCost:
-        c = self._decode_memo.get(batch)
-        if c is None:
-            c = decode_step_cost(self.cfg, batch, self.max_len,
-                                 self.cache_bytes_per_elem,
-                                 self.weight_bytes_per_elem)
-            self._decode_memo[batch] = c
-        return c
-
-    def _spec_cost(self, batch: int) -> StepCost:
-        c = self._spec_memo.get(batch)
-        if c is None:
-            c = spec_verify_cost(self.cfg, self.spec_k, batch, self.max_len,
-                                 self.draft_layers, self.cache_bytes_per_elem,
-                                 self.weight_bytes_per_elem)
-            self._spec_memo[batch] = c
-        return c
-
-    def _push(self, rec: PhaseRecord) -> None:
-        self.events.append(rec)
-        self.totals["flops"] += rec.flops
-        if rec.phase != "preempt":
-            self.totals["hbm_bytes"] += rec.hbm_bytes
 
     # -- hooks (called by ContinuousScheduler) ----------------------------
     def record_prefill(self, segment: int, width: int, chunk: int,
-                       real_tokens: int, starts: Sequence[int]) -> None:
-        """One prefill launch: ``width`` rows × ``chunk`` tokens (padded
-        rows implicit at start 0), ``real_tokens`` of which are real."""
-        ctx = sum(chunk * s + chunk * (chunk + 1) / 2.0 for s in starts)
-        ctx += (width - len(starts)) * chunk * (chunk + 1) / 2.0
-        cost = prefill_chunk_cost(self.cfg, width, chunk, ctx_sum=ctx,
-                                  cache_bytes_per_elem=self.cache_bytes_per_elem,
-                                  weight_bytes_per_elem=self.weight_bytes_per_elem)
+                       real_tokens: int) -> None:
+        """One prefill launch: ``width`` rows × ``chunk`` tokens,
+        ``real_tokens`` of which are real."""
         self.totals["prefill_tokens"] += real_tokens
         self.totals["prefill_launches"] += 1
-        self._push(PhaseRecord("prefill", segment, width, chunk, real_tokens,
-                               cost.flops, cost.hbm_bytes))
+        self.events.append(
+            PhaseRecord("prefill", segment, width, chunk, real_tokens))
 
     def record_decode(self, segment: int, batch: int, steps: int,
                       tokens: int) -> None:
         """One plain decode segment: ``steps`` executed loop steps over
         ``batch`` slot rows, ``tokens`` live emissions."""
-        c = self._decode_cost(batch)
         self.totals["decode_tokens"] += tokens
         self.totals["decode_segments"] += 1
         self.totals["decode_steps"] += steps
-        self._push(PhaseRecord("decode", segment, batch, steps, tokens,
-                               c.flops * steps, c.hbm_bytes * steps))
+        self.events.append(PhaseRecord("decode", segment, batch, steps, tokens))
 
     def record_spec(self, segment: int, batch: int, steps: int,
                     live_steps: int, tokens: int) -> None:
         """One speculative segment: ``steps`` draft-and-verify rounds,
         ``live_steps`` of them on live slots, ``tokens`` accepted+bonus
         emissions."""
-        c = self._spec_cost(batch)
         self.totals["spec_tokens"] += tokens
         self.totals["spec_segments"] += 1
         self.totals["spec_live_steps"] += live_steps
-        self._push(PhaseRecord("spec", segment, batch, steps, tokens,
-                               c.flops * steps, c.hbm_bytes * steps))
+        self.events.append(PhaseRecord("spec", segment, batch, steps, tokens))
 
     def record_preempt(self, segment: int, emitted: int,
                        swap_bytes: int = 0) -> None:
@@ -158,23 +140,21 @@ class TraceRecorder:
         device→host KV payload when the swap path was taken."""
         self.totals["preemptions"] += 1
         self.totals["swap_bytes"] += swap_bytes
-        self._push(PhaseRecord("preempt", segment, 1, 0, emitted,
-                               0.0, float(swap_bytes)))
+        self.events.append(PhaseRecord("preempt", segment, 1, 0, emitted))
 
     def record_swap_in(self, segment: int, swap_bytes: int) -> None:
         """Host→device KV re-upload at readmission of a swapped request."""
         self.totals["swap_bytes"] += swap_bytes
-        self._push(PhaseRecord("preempt", segment, 1, 0, 0,
-                               0.0, float(swap_bytes)))
+        self.events.append(PhaseRecord("preempt", segment, 1, 0, 0))
 
     def record_brownout(self, segment: int, level: int) -> None:
         """A brownout-ladder transition (PR 9): the new level rides in the
-        ``steps`` field; zero priced work — the event marks WHEN the
-        overload controller moved, for correlating energy/goodput phases."""
+        ``steps`` field — the event marks WHEN the overload controller
+        moved, for correlating energy/goodput phases."""
         self.totals["brownout_changes"] += 1
         self.totals["brownout_level_peak"] = max(
             self.totals["brownout_level_peak"], level)
-        self._push(PhaseRecord("brownout", segment, 0, level, 0, 0.0, 0.0))
+        self.events.append(PhaseRecord("brownout", segment, 0, level, 0))
 
     def note_tenant_tokens(self, tenant: str, n: int = 1) -> None:
         """One (or ``n``) live emissions billed to ``tenant``."""
