@@ -10,7 +10,7 @@ Conventions:
 from __future__ import annotations
 
 import functools
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -279,45 +279,71 @@ def update_kv_cache(
 # bit-identical to the dense slot layout; positions past ``pos`` read
 # scratch/stale values but are masked to exact zeros, exactly as the dense
 # layout's stale rows are.
+#
+# Every layer's blocks live in ONE stacked pool ``(n_layers, n_blocks, …)``
+# that the layer scan carries (``transformer.trunk_apply``); a layer writes
+# and reads its own blocks in place through a ``LayerPool`` — the stacked
+# pool plus that layer's index — so no step ever slices, updates or copies a
+# whole layer of the pool.
 
 
-def paged_cache_gather(pool: jax.Array, block_table: jax.Array) -> jax.Array:
-    """pool (n_blocks, block_len, KH, Dh), block_table (B, MB) int32 →
-    virtual per-slot cache (B, MB·block_len, KH, Dh).
+class LayerPool(NamedTuple):
+    """Layer ``layer`` of a stacked block pool ``pool`` (n_layers, n_blocks,
+    block_len, …), addressed in place by (layer, block, offset)."""
 
-    mode="clip": the dummy rows of a fixed-width batched prefill carry
-    out-of-range block ids; clamping hands them finite (masked, dropped)
-    garbage instead of NaN fill values."""
-    g = jnp.take(pool, block_table, axis=0, mode="clip")  # (B, MB, bl, …)
+    pool: jax.Array
+    layer: jax.Array  # () int32
+
+
+def paged_cache_gather(pool: LayerPool, block_table: jax.Array) -> jax.Array:
+    """Layer ``pool.layer``'s blocks, block_table (B, MB) int32 → virtual
+    per-slot cache (B, MB·block_len, KH, Dh).
+
+    The layer and block axes merge (a free reshape), so the gather reads
+    only the mapped blocks.  Ids clamp into the layer BEFORE the layer
+    offset is added: the dummy rows of a fixed-width batched prefill carry
+    out-of-range block ids, and clamping hands them finite (masked,
+    dropped) values of their own layer instead of NaN fill values or
+    another layer's blocks."""
+    stack, layer = pool
+    n_layers, n_blocks = stack.shape[:2]
+    flat = stack.reshape(n_layers * n_blocks, *stack.shape[2:])
+    ids = jnp.clip(block_table, 0, n_blocks - 1) + layer * n_blocks
+    g = jnp.take(flat, ids, axis=0, mode="clip")  # (B, MB, bl, …)
     b, mb, bl = g.shape[:3]
     return g.reshape(b, mb * bl, *g.shape[3:])
 
 
 def paged_cache_write(
-    pool: jax.Array,  # (n_blocks, block_len, KH, Dh)
+    pool: LayerPool,  # stacked (n_layers, n_blocks, block_len, KH, Dh)
     block_table: jax.Array,  # (B, MB) int32
     new: jax.Array,  # (B, 1, KH, Dh) — one decode token per slot
     pos: jax.Array,  # (B,) logical write position per slot
-) -> jax.Array:
-    """Scatter one decode token per slot into its mapped physical block.
+) -> LayerPool:
+    """Scatter one decode token per slot into its mapped physical block of
+    layer ``pool.layer``.
 
     Slots whose mapping is unset write into their own scratch block (table
     entry = the slot id, per the layout contract above), which is what makes
     ``unique_indices`` sound: no two slots ever write the same (block,
     offset) pair."""
-    bl = pool.shape[1]
+    stack, layer = pool
+    bl = stack.shape[2]
     phys = jnp.take_along_axis(block_table, (pos // bl)[:, None], axis=1)[:, 0]
-    return pool.at[phys, pos % bl].set(new[:, 0].astype(pool.dtype),
-                                       unique_indices=True)
+    stack = stack.at[layer, phys, pos % bl].set(
+        new[:, 0].astype(stack.dtype), unique_indices=True
+    )
+    return LayerPool(stack, layer)
 
 
 def paged_cache_write_chunk(
-    pool: jax.Array,  # (n_blocks, block_len, KH, Dh)
+    pool: LayerPool,  # stacked (n_layers, n_blocks, block_len, KH, Dh)
     block_table: jax.Array,  # (B, MB) int32
     new: jax.Array,  # (B, C, KH, Dh) — one prefill chunk per slot
     pos0: jax.Array,  # (B,) logical start position of the chunk per slot
-) -> jax.Array:
-    """Scatter a whole prefill chunk per slot at its block-table offsets.
+) -> LayerPool:
+    """Scatter a whole prefill chunk per slot at its block-table offsets in
+    layer ``pool.layer``.
 
     The chunk's logical positions ``pos0[b] .. pos0[b]+C-1`` may straddle
     block boundaries: each token resolves its own (physical block, in-block
@@ -329,14 +355,18 @@ def paged_cache_write_chunk(
     beyond the row's mapped blocks (bucket-padding spill) and whose masked
     dummy rows hold DISTINCT out-of-range physical ids, so those writes
     drop (``mode="drop"``) without ever aliasing an in-bounds update or
-    repeating a (block, offset) pair."""
-    bl = pool.shape[1]
+    repeating a (block, offset) pair.  The layer and block axes stay
+    separate here: bounds hold per axis, so an out-of-range id drops
+    instead of landing in the next layer's blocks."""
+    stack, layer = pool
+    bl = stack.shape[2]
     c = new.shape[1]
     logical = pos0[:, None] + jnp.arange(c, dtype=pos0.dtype)  # (B, C)
     phys = jnp.take_along_axis(block_table, logical // bl, axis=1)  # (B, C)
-    return pool.at[phys, logical % bl].set(
-        new.astype(pool.dtype), mode="drop", unique_indices=True
+    stack = stack.at[layer, phys, logical % bl].set(
+        new.astype(stack.dtype), mode="drop", unique_indices=True
     )
+    return LayerPool(stack, layer)
 
 
 # -------- int8 KV cache (SONIC C2 applied to the cache — §Perf A2/C) --------
@@ -391,8 +421,9 @@ def attention_apply(
         step it replaces, which is what makes greedy speculative outputs
         bit-identical to non-speculative decoding (docs/serving.md).
       * cache given, S == 1              → decode step at ``cache_pos``.
-      * block_table given                → paged cache: ``cache`` is a
-        (k_pool, v_pool) block pool; decode scatters one token into the
+      * block_table given                → paged cache: ``cache`` is
+        this layer's (k_pool, v_pool) ``LayerPool`` views of the stacked
+        block pools; decode scatters one token into the
         mapped block (``paged_cache_write``), chunk-resume / verify-window
         scatters the whole chunk at its block-table offsets
         (``paged_cache_write_chunk``); attention runs over the gathered
@@ -455,7 +486,7 @@ def attention_apply(
         with jax.named_scope("kv.write"):
             if quant:
                 # per-block KV scales ride the SAME block table as the
-                # values: scale pools are (n_blocks, block_len, KH) — one
+                # values: scale pools are (L, n_blocks, block_len, KH) — one
                 # fp32 per cached position per head — so the write/gather
                 # helpers below (which only index leading dims) work on
                 # them unchanged

@@ -124,8 +124,9 @@ def trunk_apply(
     """Scan the stacked layers.  Returns (hidden, new_cache).
 
     With ``block_table`` the cache leaves are block pools
-    (L, n_blocks, block_len, KH, Dh); the table is shared across layers
-    (closed over by the scan body, not scanned)."""
+    (L, n_blocks, block_len, KH, Dh), carried through the scan in place;
+    the table is shared across layers (closed over by the scan body, not
+    scanned)."""
 
     if cache is None:  # train / encoder forward
 
@@ -149,6 +150,28 @@ def trunk_apply(
         return x, None
 
     quant = "k_scale" in cache
+
+    if block_table is not None:
+        # paged: the pool leaves ride in the carry and each layer writes and
+        # reads its own blocks in place by (layer, block) index
+        # (``layers.LayerPool``).  As scanned input and output they would
+        # cost a slice, an update and a copy of the whole pool every step.
+        names = ("k", "v", "k_scale", "v_scale") if quant else ("k", "v")
+
+        def body_paged(carry, inp):
+            x, pools = carry
+            lp, layer = inp
+            views = tuple(L.LayerPool(p, layer) for p in pools)
+            x, new_c = layer_apply(lp, cfg, x, positions, plan, views,
+                                   cache_pos, block_table,
+                                   decode_chunk=decode_chunk)
+            return (x, tuple(c.pool for c in new_c)), None
+
+        (x, pools), _ = jax.lax.scan(
+            body_paged, (x, tuple(cache[n] for n in names)),
+            (params["layers"], jnp.arange(cache["k"].shape[0])),
+        )
+        return x, dict(zip(names, pools))
 
     def body_cached(x, inp):
         if quant:
