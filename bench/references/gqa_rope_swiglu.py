@@ -7,6 +7,27 @@ layout of the program's parameter tree (nested dicts, layer weights
 stacked on a leading axis), so the program receives them as input and the
 reference reads the same values back.
 
+What every reference module provides (``bench/run.py`` and ``bench/check.py``
+call nothing else, so a configuration of another architecture joins with a
+module of its own and no edit to the harness):
+
+  model_from_config(config)  the model the configuration file states
+  make_params(model, key)    the cell's weights, in the program's tree
+                             layout (run under jit, ``model`` static)
+  gaps(params, tokens, targets, model, control_bits)
+                             per position, the served token's and the
+                             control's gap below the reference's best
+  program_fields(config)     the program's ``ModelConfig`` fields, by name,
+                             with the values the file states; raises where
+                             the file states a model this module does not
+                             compute
+  PROGRAM_REQUIRES           ``ModelConfig`` fields and the values that say
+                             the program computes what this module does;
+                             the harness refuses any other before a weight
+                             is made
+  work_shapes(config)        the ``bench.work.Shapes`` that the roofline
+                             and MFU metrics count the work from
+
 Weight formats (``config["weights"]["format"]``):
 
   dense              every weight normal(0, fan_in^-1/2), in ``dtype``.
@@ -30,6 +51,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+
+from bench import work
 
 RMS_EPS = 1e-5
 Q_MAX = 127  # symmetric int8
@@ -65,6 +88,40 @@ def model_from_config(config: dict) -> Model:
         fmt=w["format"], dtype=w["dtype"], sparsity=w.get("sparsity", 0.0),
         block=tuple(w.get("block", (128, 128))),
     )
+
+
+PROGRAM_REQUIRES = {"family": "dense", "pos_enc": "rope", "norm": "rmsnorm",
+                    "ffn": "swiglu", "use_bias": False, "tie_embeddings": False}
+
+
+def program_fields(config: dict) -> dict:
+    """The program's ``ModelConfig`` fields for a configuration file: every
+    shape it states."""
+    if (config["hidden_act"], config["tie_word_embeddings"]) != ("silu", False):
+        raise ValueError(
+            f"{config['arch_id']} is not the decoder this reference computes: "
+            f"hidden_act {config['hidden_act']!r}, tie_word_embeddings "
+            f"{config['tie_word_embeddings']!r}; it computes 'silu', False")
+    m = model_from_config(config)
+    return dict(n_layers=m.layers, d_model=m.d, n_heads=m.heads,
+                n_kv_heads=m.kv_heads, head_dim=m.head_dim, d_ff=m.ffn,
+                vocab_size=m.vocab, rope_theta=m.rope_theta)
+
+
+def work_shapes(config: dict) -> work.Shapes:
+    """One group of alike layers, every projection multiplied by every
+    token; K and V of every KV head stored a token; scores and values at
+    4 x heads x head_dim FLOPs a (query, key) pair."""
+    m = model_from_config(config)
+    layer = work.LayerGroup(
+        layers=m.layers,
+        mats=tuple((k, n, 1.0) for _, k, n in projection_shapes(m).values()),
+        kv_per_token=2 * m.kv_heads * m.head_dim,
+        attn_flops_per_pair=4 * m.heads * m.head_dim)
+    return work.Shapes(
+        groups=(layer,), d=m.d, vocab=m.vocab, fmt=m.fmt,
+        bytes_per_weight=config["weights"].get("stated_bytes_per_weight", 2),
+        sparsity=m.sparsity, block=m.block)
 
 
 def projection_shapes(m: Model) -> dict[str, tuple[str, int, int]]:

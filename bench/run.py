@@ -63,25 +63,20 @@ def seed_key(seed: int):
                               (s >> 31) & 0x7FFFFFFF)
 
 
-def program_config(config: dict):
+def program_config(config: dict, ref):
     """The program's ``ModelConfig`` for a configuration file: its arch with
-    every shape the file states."""
+    every field that the configuration's reference module ``ref`` maps the
+    file to, refused unless the fields ``ref.PROGRAM_REQUIRES`` names hold
+    what it computes."""
     from repro.configs.base import get_config
 
-    d, h = config["hidden_size"], config["num_attention_heads"]
-    cfg = get_config(config["arch_id"]).replace(
-        n_layers=config["num_hidden_layers"], d_model=d, n_heads=h,
-        n_kv_heads=config["num_key_value_heads"],
-        head_dim=config.get("head_dim", d // h),
-        d_ff=config["intermediate_size"], vocab_size=config["vocab_size"],
-        rope_theta=float(config["rope_theta"]),
-    )
-    # what the plain reference computes, and the program must too
-    if ((cfg.family, cfg.pos_enc, cfg.norm, cfg.ffn, cfg.use_bias,
-         cfg.tie_embeddings, config["hidden_act"], config["tie_word_embeddings"])
-            != ("dense", "rope", "rmsnorm", "swiglu", False, False, "silu", False)):
-        raise ValueError(f"{config['arch_id']} is not the decoder "
-                         f"{config['reference']} computes")
+    cfg = get_config(config["arch_id"]).replace(**ref.program_fields(config))
+    wrong = {k: getattr(cfg, k) for k, v in ref.PROGRAM_REQUIRES.items()
+             if getattr(cfg, k) != v}
+    if wrong:
+        raise ValueError(f"{config['arch_id']} is not the model "
+                         f"{config['reference']} computes: it has {wrong}, "
+                         f"the reference needs {ref.PROGRAM_REQUIRES}")
     return cfg
 
 
@@ -134,9 +129,9 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     dev = jax.devices()[0]
     pk = peaks if peaks is not None else work.peaks(dev.device_kind)
 
-    cfg = program_config(config)
+    ref = spec.reference_module(config, cell.bench_dir)
+    cfg = program_config(config, ref)
     arch = dataclasses.replace(get_arch(config["arch_id"]), cfg=cfg)
-    ref = spec.reference_module(config)
     model = ref.model_from_config(config)
     make = jax.jit(ref.make_params, static_argnums=0)
     params = jax.block_until_ready(make(model, seed_key(seed)))
@@ -200,7 +195,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
         ctx = spec.MetricContext(
             reduced=red, stats0=tracer.stats0, stats1=tracer.stats1,
             launches=launches, records=win.recs, t0=tracer.t0, t1=tracer.t1,
-            shapes=work.Shapes.from_config(config), peaks=pk)
+            shapes=ref.work_shapes(config), peaks=pk)
         for m in cell.per_layer:
             v = spec.metric_reader(m["name"])(ctx)
             if v is not None:

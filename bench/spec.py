@@ -10,6 +10,13 @@ adding files and entries, never by editing a file that is there.
     traffic mix     bench/traffic/<name>.json
     cell's check    bench/checks/<cell>.json  (the number compared, its limit)
     metric reader   bench/metrics/<name>.py   (``read(ctx) -> float | None``)
+
+The architecture is the reference module's, not the harness's: it provides
+``model_from_config``, ``make_params`` and ``gaps`` (the weights and the
+check), ``program_fields`` and ``PROGRAM_REQUIRES`` (the program's
+``ModelConfig`` for the file, and what that config must hold), and
+``work_shapes`` (the work the roofline and MFU metrics count); the header
+of ``bench/references/gqa_rope_swiglu.py`` gives each signature.
 """
 from __future__ import annotations
 
@@ -33,6 +40,7 @@ class Cell:
     check: dict  # {"number": "max_gap" | "mean_gap", "limit": float}
     end_to_end: list[dict]  # the cell's end-to-end metric entries
     per_layer: list[dict]  # the cell's per-layer metric entries
+    bench_dir: pathlib.Path  # where its files were found
 
 
 def load_module(path: pathlib.Path) -> ModuleType:
@@ -78,6 +86,7 @@ def load_cell(name: str, root: pathlib.Path = ROOT,
         check=_json(bench_dir / "checks" / f"{name}.json"),
         end_to_end=[m for m in spec["end_to_end"] if _applies(m, name)],
         per_layer=[m for m in spec["per_layer"] if _applies(m, name)],
+        bench_dir=bench_dir,
     )
 
 
@@ -86,8 +95,8 @@ class MetricContext:
     """What a per-layer metric's ``read(ctx)`` may read, all of the traced
     window: the reduced device trace, the scheduler's counters at its start
     and end, the benchmark's log of program launches, the request records
-    (host clock, ``t0``..``t1``), the configuration's shapes and the
-    device's peaks."""
+    (host clock, ``t0``..``t1``), the work shapes of the configuration's
+    reference (``work_shapes``) and the device's peaks."""
 
     reduced: object  # trace_reduce.Reduced
     stats0: dict

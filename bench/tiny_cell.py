@@ -5,6 +5,7 @@ from __future__ import annotations
 
 import json
 import pathlib
+import shutil
 
 from bench import spec
 
@@ -41,11 +42,13 @@ def traffic(kind: str = "closed") -> dict:
 
 
 def write(root: pathlib.Path, fmt: str = "dense", kind: str = "closed") -> spec.Cell:
-    """Write a one-cell benchmark under ``root`` and load its cell."""
+    """Write a one-cell benchmark under ``root``, with a copy of the
+    reference module, and load its cell."""
     bench = root / "bench"
-    (bench / "configs").mkdir(parents=True, exist_ok=True)
-    (bench / "traffic").mkdir(parents=True, exist_ok=True)
-    (bench / "checks").mkdir(parents=True, exist_ok=True)
+    for sub in ("configs", "traffic", "checks", "references"):
+        (bench / sub).mkdir(parents=True, exist_ok=True)
+    ref = f"{config(fmt)['reference']}.py"
+    shutil.copy(spec.BENCH_DIR / "references" / ref, bench / "references" / ref)
     (bench / "checks" / "tiny.cell.json").write_text(
         json.dumps({"number": "max_gap", "limit": 0.05}))
     (bench / "configs" / "tiny.json").write_text(json.dumps(config(fmt)))

@@ -1,17 +1,30 @@
 """Work a step requires, counted from the configuration's shapes alone.
 
-Nothing here reads what the program executes, so the counts are the same
-whatever implements the work, and a roofline share built on them cannot
-pass 100% by a change of implementation:
+The configuration's reference module states the shapes
+(``work_shapes(config) -> Shapes``): one or more groups of alike layers,
+each with its matrices, the KV it stores a token and its attention FLOPs a
+(query, key) pair, and the LM head.  Nothing here reads what the program
+executes, so the counts are the same whatever implements the work, and a
+roofline share built on them cannot pass 100% by a change of
+implementation:
 
 - Weights are counted in the format the configuration states: bf16 (2 B)
   for dense weights, and for int8 block-sparse weights only the kept int8
-  blocks with one fp32 scale each; FLOPs only over kept blocks.
+  blocks with one fp32 scale each, matrix by matrix; FLOPs only over kept
+  blocks.
+- A matrix carries the share of a step's tokens that multiply it: 1 for a
+  dense one, experts per token / experts for a routed expert.  A token's
+  FLOPs count each matrix times its share.  A step of ``t`` tokens reads a
+  matrix of share ``s`` with probability ``1 - (1 - s)^t``: the expected
+  bytes if every token picks its experts uniformly and independently of the
+  others, the one assumption of these counts.  At share 1 a step reads
+  every weight once, whatever ``t``.
 - KV reads are counted at each row's actual context, not at ``max_len``.
 - Prefill counts the real tokens of each chunk, not the bucket padding, and
   LM-head logits only at each row's last real token.
-- Every product is counted at 2 FLOPs per multiply-add; attention at
-  4 x heads x head_dim FLOPs per (query, key) pair (scores and values).
+- Every product is counted at 2 FLOPs per multiply-add; attention at the
+  FLOPs per (query, key) pair the reference states for each layer (scores
+  and values: 4 x heads x the head width for grouped-query attention).
 
 A step's least time is the larger of its FLOPs over the bf16 peak and its
 bytes over HBM bandwidth (``roofline_s``).
@@ -38,31 +51,26 @@ def peaks(device_kind: str) -> dict:
 
 
 @dataclasses.dataclass(frozen=True)
-class Shapes:
+class LayerGroup:
+    """``layers`` alike layers: each layer's matrices as (k, n, share), the
+    KV elements a token stores in one layer, and one layer's attention
+    FLOPs per (query, key) pair."""
+
     layers: int
-    d: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    ffn: int
+    mats: tuple[tuple[int, int, float], ...]
+    kv_per_token: int
+    attn_flops_per_pair: int
+
+
+@dataclasses.dataclass(frozen=True)
+class Shapes:
+    groups: tuple[LayerGroup, ...]
+    d: int  # embedding row, and the LM head's input
     vocab: int
     fmt: str = "dense"  # "dense" | "int8_block_sparse"
     bytes_per_weight: int = 2  # dense format
     sparsity: float = 0.0
     block: tuple[int, int] = (128, 128)
-
-    @classmethod
-    def from_config(cls, config: dict) -> "Shapes":
-        w = config["weights"]
-        d, h = config["hidden_size"], config["num_attention_heads"]
-        return cls(
-            layers=config["num_hidden_layers"], d=d, heads=h,
-            kv_heads=config["num_key_value_heads"],
-            head_dim=config.get("head_dim", d // h),
-            ffn=config["intermediate_size"], vocab=config["vocab_size"],
-            fmt=w["format"], bytes_per_weight=w.get("stated_bytes_per_weight", 2),
-            sparsity=w.get("sparsity", 0.0), block=tuple(w.get("block", (128, 128))),
-        )
 
     def _kept(self, k: int, n: int) -> tuple[int, int]:
         """(weights, blocks) kept of a (k, n) matrix."""
@@ -73,15 +81,22 @@ class Shapes:
         r = max(int(round(kb * (1.0 - self.sparsity))), 1)
         return r * nb * bk * bn, r * nb
 
-    def _layer_mats(self) -> list[tuple[int, int]]:
-        q, kv = self.heads * self.head_dim, self.kv_heads * self.head_dim
-        return [(self.d, q), (self.d, kv), (self.d, kv), (q, self.d),
-                (self.d, self.ffn), (self.d, self.ffn), (self.ffn, self.d)]
+    def _mats(self):
+        """(layers, k, n, share) of every layer matrix."""
+        return [(g.layers, k, n, share) for g in self.groups
+                for k, n, share in g.mats]
 
     @property
     def layer_weights(self) -> int:
-        """Kept projection weights of all layers."""
-        return self.layers * sum(self._kept(k, n)[0] for k, n in self._layer_mats())
+        """Kept weights of every layer matrix (every expert's)."""
+        return sum(ls * self._kept(k, n)[0] for ls, k, n, _ in self._mats())
+
+    @property
+    def token_weights(self) -> float:
+        """Kept layer weights one token multiplies: each matrix's times its
+        share."""
+        return sum(ls * self._kept(k, n)[0] * share
+                   for ls, k, n, share in self._mats())
 
     @property
     def head_weights(self) -> int:
@@ -93,19 +108,20 @@ class Shapes:
             return w * self.bytes_per_weight
         return w + 4 * blocks  # int8 values + one fp32 scale per kept block
 
-    @property
-    def weight_bytes(self) -> int:
-        """Bytes of every projection and the LM head, read once a step."""
-        layer = sum(self._bytes(k, n) for k, n in self._layer_mats())
-        return self.layers * layer + self._bytes(self.d, self.vocab)
+    def step_weight_bytes(self, t: float) -> float:
+        """Expected bytes of the layer matrices and the LM head that a step
+        of ``t`` tokens reads, each matrix at most once (module docstring)."""
+        layers = sum(ls * self._bytes(k, n) * (1.0 - (1.0 - share) ** t)
+                     for ls, k, n, share in self._mats())
+        return layers + self._bytes(self.d, self.vocab)
 
     @property
     def kv_bytes_per_token(self) -> int:
-        return self.layers * 2 * self.kv_heads * self.head_dim * KV_BYTES
+        return sum(g.layers * g.kv_per_token for g in self.groups) * KV_BYTES
 
     @property
     def attn_flops_per_pair(self) -> int:
-        return 4 * self.layers * self.heads * self.head_dim
+        return sum(g.layers * g.attn_flops_per_pair for g in self.groups)
 
 
 def decode(s: Shapes, steps: int, contexts: list[int]) -> tuple[float, float]:
@@ -114,9 +130,10 @@ def decode(s: Shapes, steps: int, contexts: list[int]) -> tuple[float, float]:
     attended (its position + 1)."""
     n = len(contexts)
     flops = sum(token_flops(s, c) for c in contexts)
-    # weights once per step; per token its embedding row, the KV it reads
-    # (its whole context) and the one position it writes
-    nbytes = (steps * float(s.weight_bytes) + n * s.d * ACT_BYTES
+    # weights once per step, each step taken at the segment's mean tokens;
+    # per token its embedding row, the KV it reads (its whole context) and
+    # the one position it writes
+    nbytes = (steps * s.step_weight_bytes(n / steps) + n * s.d * ACT_BYTES
               + s.kv_bytes_per_token * (sum(contexts) + n))
     return flops, nbytes
 
@@ -124,10 +141,11 @@ def decode(s: Shapes, steps: int, contexts: list[int]) -> tuple[float, float]:
 def prefill(s: Shapes, rows: list[tuple[int, int]]) -> tuple[float, float]:
     """(FLOPs, bytes) of one prefill launch over ``rows`` of (start, real
     tokens): real tokens only, logits only at each row's last token."""
-    flops, nbytes = 0.0, float(s.weight_bytes)
+    flops = 0.0
+    nbytes = s.step_weight_bytes(sum(real for _, real in rows))
     for start, real in rows:
         pairs = real * start + real * (real + 1) / 2  # causal (query, key)
-        flops += (2.0 * s.layer_weights * real + 2.0 * s.head_weights
+        flops += (2.0 * s.token_weights * real + 2.0 * s.head_weights
                   + s.attn_flops_per_pair * pairs)
         # embedding rows, the prefix KV read, the chunk's KV written
         nbytes += real * s.d * ACT_BYTES + s.kv_bytes_per_token * (start + real)
@@ -140,7 +158,7 @@ def roofline_s(flops: float, nbytes: float, pk: dict) -> float:
 
 def token_flops(s: Shapes, context: int) -> float:
     """FLOPs of decoding one token that attends ``context`` keys."""
-    return 2.0 * (s.layer_weights + s.head_weights) + s.attn_flops_per_pair * context
+    return 2.0 * (s.token_weights + s.head_weights) + s.attn_flops_per_pair * context
 
 
 def window_flops(s: Shapes, records, t0: float, t1: float) -> tuple[float, float]:
