@@ -39,10 +39,32 @@ class ModelConfig:
     encoder_only: bool = False
     tie_embeddings: bool = False
 
+    # attention kind: grouped-query, or latent (MLA): keys and values come
+    # from a ``mla_kv_rank``-wide latent (plus one shared rotary key of
+    # ``mla_rope_dim``) that the cache holds in their place
+    attention: Literal["gqa", "mla"] = "gqa"
+    mla_kv_rank: int = 0
+    mla_nope_dim: int = 0  # per-head query/key width without rotation
+    mla_rope_dim: int = 0  # per-head rotary query/key width
+    mla_v_dim: int = 0  # per-head value width
+
     # MoE
-    n_experts: int = 0  # 0 ⇒ dense FFN
+    n_experts: int = 0  # 0 ⇒ dense FFN; the router's width
     experts_per_token: int = 0
     moe_capacity_factor: float = 1.25
+    # "softmax": softmax top-k over capacity buffers (``moe.moe_apply``);
+    # "sigmoid_bias": sigmoid scores, top-k of scores + a per-expert bias,
+    # weights normalized and scaled, drop-free (``moe.moe_held_apply``)
+    moe_router: Literal["softmax", "sigmoid_bias"] = "softmax"
+    moe_routed_scaling: float = 1.0
+    moe_shared_experts: int = 0  # always-on experts, merged into one FFN
+    # experts this device holds: ``experts_held`` of them from
+    # ``expert_offset`` (0 ⇒ all); the router still spans ``n_experts``
+    experts_held: int = 0
+    expert_offset: int = 0
+    # leading layers with a dense FFN of ``dense_d_ff`` before the MoE stack
+    first_dense_layers: int = 0
+    dense_d_ff: int = 0
 
     # SSM (mamba2) / hybrid
     ssm_state: int = 0
@@ -83,6 +105,14 @@ class ModelConfig:
     @property
     def rwkv_heads(self) -> int:
         return self.d_model // self.rwkv_head_size if self.rwkv_head_size else 0
+
+    @property
+    def kv_latent_dim(self) -> int:  # values the latent cache holds a token
+        return self.mla_kv_rank + self.mla_rope_dim
+
+    @property
+    def n_experts_held(self) -> int:
+        return self.experts_held or self.n_experts
 
     @property
     def is_attention_free(self) -> bool:
@@ -130,6 +160,7 @@ _MODULE_FOR: dict[str, str] = {
     "hubert-xlarge": "hubert_xlarge",
     "zamba2-7b": "zamba2_7b",
     "moonshot-v1-16b-a3b": "moonshot_v1_16b_a3b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
     "grok-1-314b": "grok_1_314b",
     "command-r-35b": "command_r_35b",
     "mistral-nemo-12b": "mistral_nemo_12b",
@@ -161,10 +192,19 @@ def reduced_config(arch_id: str) -> ModelConfig:
         vocab_size=256,
         max_cache_len=128,
     )
-    if cfg.n_experts:
+    if cfg.n_experts and cfg.moe_router == "sigmoid_bias":
+        # drop-free by construction; 8 experts in 4 shares of 2
+        kw.update(n_experts=8, experts_per_token=3, d_ff=32,
+                  experts_held=2 if cfg.experts_held else 0)
+    elif cfg.n_experts:
         # ample capacity: smoke tests must be drop-free so prefill+decode
         # continuity is exact (dropping is sequence-length-dependent)
         kw.update(n_experts=4, experts_per_token=2, moe_capacity_factor=8.0)
+    if cfg.attention == "mla":
+        kw.update(n_kv_heads=4, head_dim=24, mla_kv_rank=32, mla_nope_dim=16,
+                  mla_rope_dim=8, mla_v_dim=16)
+    if cfg.first_dense_layers:
+        kw.update(n_layers=cfg.first_dense_layers + 2, dense_d_ff=128)
     if cfg.ssm_state:
         kw.update(ssm_state=16, ssm_head_dim=16, ssm_chunk=16)
         if cfg.shared_attention_every:

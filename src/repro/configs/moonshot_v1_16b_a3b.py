@@ -1,6 +1,10 @@
-"""moonshot-v1-16b-a3b [moe] — kimi/moonlight, 64 experts top-6.
-[hf:moonshotai/Moonlight-16B-A3B; hf]  48L d_model=2048 16H (GQA kv=16)
-d_ff=1408 (per expert) vocab=163840, MoE 64e top-6.
+"""moonshot-v1-16b-a3b [moe] — a generic GQA, softmax-routed MoE at
+Moonlight-like widths, used by the CPU tests of the capacity-buffer expert
+path (``moe.moe_apply``).  It is NOT Moonlight-16B-A3B: no latent attention,
+no shared experts, no sigmoid routing, no leading dense layer, and 48
+layers; the published model is ``moonlight_16b_a3b.py``.
+48L d_model=2048 16H (GQA kv=16) d_ff=1408 (per expert) vocab=163840,
+MoE 64e top-6.
 """
 from repro.configs.base import ModelConfig
 

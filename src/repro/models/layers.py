@@ -1,5 +1,6 @@
 """Shared model layers: norms, RoPE / M-RoPE, GQA attention (chunked-flash
-prefill/train + cached decode), FFNs.
+prefill/train + cached decode), latent attention (MLA) over a paged latent
+pool, FFNs.
 
 Conventions:
   * params are nested dicts of arrays; init fns mirror apply fns.
@@ -72,14 +73,16 @@ def norm_apply(p: Params, x: jax.Array, eps: float = 1e-5) -> jax.Array:
 # ----------------------------------------------------------------- RoPE
 
 
-def rope_angles(cfg: ModelConfig, positions: jax.Array) -> jax.Array:
-    """positions (B, S) or (B, 3, S) → angles (B, S, head_dim/2) fp32.
+def rope_angles(cfg: ModelConfig, positions: jax.Array,
+                dim: int | None = None) -> jax.Array:
+    """positions (B, S) or (B, 3, S) → angles (B, S, dim/2) fp32, ``dim``
+    the rotated width (``head_dim`` unless given).
 
     Standard RoPE for (B, S); M-RoPE (qwen2-vl) for (B, 3, S): the dh/2
     frequency slots are split into ``mrope_sections`` = (t, h, w) groups, each
     driven by its own position row.
     """
-    half = cfg.head_dim // 2
+    half = (dim or cfg.head_dim) // 2
     inv_freq = cfg.rope_theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     if positions.ndim == 2:  # (B, S)
         return positions[..., None].astype(jnp.float32) * inv_freq
@@ -139,6 +142,7 @@ def flash_attention(
     causal: bool = True,
     q_chunk: int = 512,
     kv_chunk: int = 1024,
+    scale: float | None = None,
 ) -> jax.Array:
     """Memory-efficient (online-softmax) attention in pure jnp.
 
@@ -147,11 +151,14 @@ def flash_attention(
     both bounds VMEM/HBM temp and keeps the dry-run memory analysis honest.
     Masking is position-based: a kv position participates iff
     kv_pos <= q_pos (causal) and kv_pos >= 0 (padding convention: pos < 0).
+    Values may be narrower than queries and keys (latent attention); the
+    scores scale by ``scale``, Dh^-1/2 unless given.
     """
     b, sq, h, dh = q.shape
     _, skv, kh, _ = k.shape
+    dv = v.shape[-1]
     g = h // kh
-    scale = dh**-0.5
+    scale = dh**-0.5 if scale is None else scale
     q_chunk = min(q_chunk, sq)
     kv_chunk = min(kv_chunk, skv)
     nq, nkv = sq // q_chunk, skv // kv_chunk
@@ -159,7 +166,7 @@ def flash_attention(
 
     qc = q.reshape(b, nq, q_chunk, kh, g, dh)
     kc = k.reshape(b, nkv, kv_chunk, kh, dh)
-    vc = v.reshape(b, nkv, kv_chunk, kh, dh)
+    vc = v.reshape(b, nkv, kv_chunk, kh, dv)
     qp = q_positions.reshape(b, nq, q_chunk)
     kp = kv_positions.reshape(b, nkv, kv_chunk)
 
@@ -189,7 +196,7 @@ def flash_attention(
             acc_new = acc * corr[..., None].astype(acc.dtype) + pv
             return (acc_new, m_new, l_new), None
 
-        acc0 = jnp.zeros((b, kh, g, q_chunk, dh), v.dtype)
+        acc0 = jnp.zeros((b, kh, g, q_chunk, dv), v.dtype)
         m0 = jnp.full((b, kh, g, q_chunk), -jnp.inf, jnp.float32)
         l0 = jnp.zeros((b, kh, g, q_chunk), jnp.float32)
         (acc, m, l), _ = jax.lax.scan(
@@ -202,13 +209,13 @@ def flash_attention(
             ),
         )
         out = acc / jnp.maximum(l, 1e-30)[..., None].astype(acc.dtype)
-        return out  # (B, KH, G, qc, Dh)
+        return out  # (B, KH, G, qc, Dv)
 
     outs = jax.lax.map(
         per_q_chunk, (jnp.moveaxis(qc, 1, 0), jnp.moveaxis(qp, 1, 0))
-    )  # (nq, B, KH, G, qc, Dh)
-    out = jnp.moveaxis(outs, 0, 1)  # (B, nq, KH, G, qc, Dh)
-    out = out.transpose(0, 1, 4, 2, 3, 5).reshape(b, sq, h, dh)
+    )  # (nq, B, KH, G, qc, Dv)
+    out = jnp.moveaxis(outs, 0, 1)  # (B, nq, KH, G, qc, Dv)
+    out = out.transpose(0, 1, 4, 2, 3, 5).reshape(b, sq, h, dv)
     return out
 
 
@@ -217,6 +224,7 @@ def decode_attention(
     k_cache: jax.Array,  # (B, S_max, KH, Dh)
     v_cache: jax.Array,  # (B, S_max, KH, Dh)
     pos: jax.Array,  # (B,) position of the FIRST query token
+    scale: float | None = None,  # Dh^-1/2 unless given
 ) -> jax.Array:
     """Decode-style attention over the cache: query i (at absolute position
     ``pos + i``) attends cache positions ``<= pos + i``; everything beyond is
@@ -227,17 +235,18 @@ def decode_attention(
     it replaces — the greedy spec/non-spec bit-identicality contract
     (docs/serving.md) rests on that."""
     b, c, h, dh = q.shape
-    kh = k_cache.shape[2]
+    kh, dv = k_cache.shape[2], v_cache.shape[-1]
     g = h // kh
     qg = q.reshape(b, c, kh, g, dh)
-    s = _gqa_scores(qg, k_cache, dh**-0.5)  # (B,KH,G,C,S_max) fp32
+    s = _gqa_scores(qg, k_cache, dh**-0.5 if scale is None else scale)
+    # s: (B, KH, G, C, S_max) fp32
     idx = jnp.arange(k_cache.shape[1])
     qpos = pos[:, None] + jnp.arange(c, dtype=pos.dtype)[None, :]  # (B, C)
     mask = idx[None, None, :] <= qpos[:, :, None]  # (B, C, S_max)
     s = jnp.where(mask[:, None, None, :, :], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bkgqs,bskd->bkgqd", p.astype(v_cache.dtype), v_cache)
-    return out.transpose(0, 3, 1, 2, 4).reshape(b, c, h, dh)
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, c, h, dv)
 
 
 def _dus_batch(cache: jax.Array, new: jax.Array, pos: jax.Array) -> jax.Array:
@@ -477,8 +486,8 @@ def attention_apply(
     if block_table is not None:
         assert cache is not None and cache_pos is not None, (
             "paged cache needs a write offset: decode at cache_pos, or "
-            "chunk-resume prefill starting at cache_pos (batch-1 whole-"
-            "prompt prefill runs dense, then write_cache_block installs it)"
+            "chunk-resume prefill starting at cache_pos (0 for a whole "
+            "prompt)"
         )
         k_pool, v_pool = cache
         quant = cache_scales is not None
@@ -611,6 +620,107 @@ def attention_apply(
     with jax.named_scope("attn.out"):
         out = dense_apply(p["wo"], out.reshape(b, s, h * dh))
     return out, new_cache
+
+
+# ------------------------------------------------- latent attention (MLA)
+#
+# DeepSeek-V3's attention.  Per token: q = x·W_q split per head into q_nope
+# and q_pe; [c | k_pe] = x·W_kva, c = RMSNorm(c) (the kv_rank-wide latent);
+# per head [k_nope | v] = c·W_kvb; q_pe and k_pe (one rotary key shared by
+# every head) rotate (rotate-half pairing); score = (q_nope·k_nope +
+# q_pe·k_pe) / sqrt(nope + rope).  The cache holds [c | k_pe] in place of K
+# and V: kv_rank + rope values a token a layer, one pool leaf
+# (L, n_blocks, block_len, kv_rank + rope).
+#
+# Prefill and decode both attend in the ABSORBED form: W_kvb's K half folds
+# into the query (q_lat = q_nope·W_uk, so q_nope·k_nope = q_lat·c) and its V
+# half into the output (softmax·v = (softmax·c)·W_uv), so attention is
+# multi-query attention over the latent itself — keys [c | k_pe], values c —
+# and nothing of size heads × positions is expanded.  One form for every
+# mode keeps a token's numerics the same whether it was prefilled or
+# decoded.
+
+
+def mla_init(key, cfg: ModelConfig) -> Params:
+    ks = jax.random.split(key, 4)
+    d, h, r = cfg.d_model, cfg.n_heads, cfg.mla_kv_rank
+    dqk = cfg.mla_nope_dim + cfg.mla_rope_dim
+    dt = jnp.dtype(cfg.param_dtype)
+    return {
+        "wq": dense_init(ks[0], d, h * dqk, dt),
+        "wkv_a": dense_init(ks[1], d, r + cfg.mla_rope_dim, dt),
+        "kv_norm": {"scale": jnp.ones((r,), jnp.float32)},
+        "wkv_b": dense_init(ks[2], r, h * (cfg.mla_nope_dim + cfg.mla_v_dim), dt),
+        "wo": dense_init(ks[3], h * cfg.mla_v_dim, d, dt),
+    }
+
+
+MLA_KV_NORM_EPS = 1e-6  # DeepSeek-V3's kv_a_layernorm keeps its default eps
+
+
+def mla_apply(
+    p: Params,
+    cfg: ModelConfig,
+    x: jax.Array,  # (B, S, D)
+    positions: jax.Array,  # (B, S)
+    *,
+    cache: LayerPool | None = None,
+    cache_pos: jax.Array | None = None,  # (B,)
+    block_table: jax.Array | None = None,  # (B, MB) int32
+) -> tuple[jax.Array, LayerPool | None]:
+    """Latent attention block (no norm/residual).  Returns (out, pool).
+
+    Modes: ``cache`` None → full causal forward over ``x``; otherwise
+    ``cache`` is this layer's view of the latent pool and ``block_table``
+    maps each row's blocks — S == 1 decodes at ``cache_pos``, S > 1 is
+    chunk-resume prefill from ``cache_pos``; both scatter [c | k_pe] into
+    the pool and attend over the gathered latent."""
+    b, s, _ = x.shape
+    h, r = cfg.n_heads, cfg.mla_kv_rank
+    dn, dr, dv = cfg.mla_nope_dim, cfg.mla_rope_dim, cfg.mla_v_dim
+    with jax.named_scope("mla.q"):
+        q = dense_apply(p["wq"], x).reshape(b, s, h, dn + dr)
+    with jax.named_scope("mla.kv_a"):
+        kv = dense_apply(p["wkv_a"], x)  # (B, S, r + dr)
+        c = norm_apply(p["kv_norm"], kv[..., :r], MLA_KV_NORM_EPS)
+    with jax.named_scope("attn.rope"):
+        ang = rope_angles(cfg, positions, dim=dr)
+        q_pe = apply_rope(q[..., dn:], ang)
+        k_pe = apply_rope(kv[:, :, None, r:], ang)[:, :, 0]
+    latent = jnp.concatenate([c, k_pe], axis=-1)  # (B, S, r + dr)
+    w_kvb = p["wkv_b"]["kernel"].astype(x.dtype).reshape(r, h, dn + dv)
+    with jax.named_scope("mla.absorb"):
+        q_lat = jnp.einsum("bshn,rhn->bshr", q[..., :dn], w_kvb[..., :dn])
+        q_full = jnp.concatenate([q_lat, q_pe], axis=-1)  # (B, S, H, r + dr)
+
+    pool = None
+    if cache is None:
+        keys = latent
+    else:
+        assert block_table is not None and cache_pos is not None, (
+            "the latent cache is a paged pool: it needs a block table and "
+            "a write offset")
+        write = paged_cache_write if s == 1 else paged_cache_write_chunk
+        with jax.named_scope("kv.write"):
+            pool = write(cache, block_table, latent, cache_pos)
+        with jax.named_scope("kv.gather"):
+            keys = paged_cache_gather(pool, block_table)  # (B, S_max, r + dr)
+    scale = (dn + dr) ** -0.5
+    k = keys[:, :, None, :]  # one key and value head: multi-query
+    v = keys[:, :, None, :r]
+    with jax.named_scope("attn.core"):
+        if cache is not None and s == 1:
+            o_lat = decode_attention(q_full, k, v, cache_pos, scale=scale)
+        else:
+            kv_pos = jnp.broadcast_to(
+                jnp.arange(k.shape[1], dtype=jnp.int32), (b, k.shape[1]))
+            o_lat = flash_attention(q_full, k, v, positions, kv_pos,
+                                    scale=scale)  # (B, S, H, r)
+    with jax.named_scope("mla.v_up"):
+        o = jnp.einsum("bshr,rhv->bshv", o_lat, w_kvb[..., dn:])
+    with jax.named_scope("attn.out"):
+        out = dense_apply(p["wo"], o.reshape(b, s, h * dv))
+    return out, pool
 
 
 # ----------------------------------------------------------------- FFN

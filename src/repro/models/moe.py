@@ -1,4 +1,7 @@
-"""Token-choice top-k MoE with GSPMD expert parallelism.
+"""Token-choice top-k MoE: softmax routing over capacity buffers with GSPMD
+expert parallelism (``moe_apply``), and DeepSeek-V3's sigmoid-routed layer
+computing one device's held experts drop-free (``moe_held_apply``, at the
+end of this module).
 
 Design (DESIGN.md §5):
 
@@ -32,7 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.layers import Params, _normal
+from repro.models.layers import Params, _normal, ffn_apply, ffn_init
 from repro.sharding.mesh import MeshPlan
 
 
@@ -276,3 +279,101 @@ def moe_load_balance_loss(p: Params, cfg: ModelConfig, x: jax.Array) -> jax.Arra
     _, experts = jax.lax.top_k(_selection_logits(logits), cfg.experts_per_token)
     frac = jax.nn.one_hot(experts, cfg.n_experts).mean((0, 1, 2))
     return cfg.n_experts * jnp.sum(frac * probs.mean((0, 1)))
+
+
+# ------------------------------------------------ held experts, drop-free
+#
+# DeepSeek-V3's expert layer as one device of an expert-parallel group runs
+# it: the router spans all ``n_experts`` (sigmoid scores in fp32; the
+# experts chosen are the top-k of scores + a per-expert correction bias; a
+# chosen expert's weight is its score over the chosen scores' sum, times
+# ``moe_routed_scaling``), and this device computes only the part of the
+# result that its ``experts_held`` experts (from ``expert_offset``) give,
+# plus the shared experts.  What the absent experts add is left to the
+# devices that hold them; on one device there is no exchange at all.
+#
+# Nothing drops: every (token, held expert) assignment is computed.  The
+# T·k assignments are sorted by held expert (the others last) into one
+# buffer that grouped matmuls (``jax.lax.ragged_dot``) run over, and each
+# token's rows combine in a fixed order, so a token's output does not
+# depend on what else is in the launch.
+
+
+def moe_held_init(key, cfg: ModelConfig) -> Params:
+    dt = jnp.dtype(cfg.param_dtype)
+    ks = jax.random.split(key, 5)
+    e, d, f = cfg.n_experts_held, cfg.d_model, cfg.d_ff
+    return {
+        "router": {"kernel": _normal(ks[0], (d, cfg.n_experts), jnp.float32,
+                                     d**-0.5),
+                   "score_bias": jnp.zeros((cfg.n_experts,), jnp.float32)},
+        "wi": _normal(ks[1], (e, d, f), dt, d**-0.5),
+        "wg": _normal(ks[2], (e, d, f), dt, d**-0.5),
+        "wo": _normal(ks[3], (e, f, d), dt, f**-0.5),
+        "shared": ffn_init(ks[4], cfg, cfg.moe_shared_experts * f),
+    }
+
+
+def route_sigmoid_bias(p: Params, cfg: ModelConfig,
+                       x: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """x (T, d) → (weights (T, k) fp32, experts (T, k) int32) over all
+    ``n_experts``, on unsnapped fp32 scores."""
+    logits = jnp.dot(x.astype(jnp.float32),
+                     p["router"]["kernel"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, experts = jax.lax.top_k(scores + p["router"]["score_bias"],
+                               cfg.experts_per_token)
+    w = jnp.take_along_axis(scores, experts, axis=-1)
+    w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    return w * cfg.moe_routed_scaling, experts.astype(jnp.int32)
+
+
+def held_rows_launched(cfg: ModelConfig, n_tokens: int) -> int:
+    """Rows the held-expert buffers of one forward over ``n_tokens`` tokens
+    hold, over every MoE layer: each token's k assignments, padding and
+    experts held elsewhere included (what ``moe_held_apply`` launches)."""
+    return (cfg.n_layers - cfg.first_dense_layers) * n_tokens * cfg.experts_per_token
+
+
+def moe_held_apply(
+    p: Params,
+    cfg: ModelConfig,
+    x: jax.Array,  # (B, S, d)
+    count_mask: jax.Array | None = None,  # (B, S) bool: the real tokens
+) -> tuple[jax.Array, jax.Array | None]:
+    """Held experts' part + shared experts → ((B, S, d), held rows).
+
+    ``held rows`` counts the (token, held expert) assignments of the tokens
+    ``count_mask`` marks (None without a mask)."""
+    b, s, d = x.shape
+    t, k, e = b * s, cfg.experts_per_token, cfg.n_experts_held
+    xt = x.reshape(t, d)
+    with jax.named_scope("moe.route"):
+        w, experts = route_sigmoid_bias(p, cfg, xt)
+        local = experts - cfg.expert_offset
+        held = (local >= 0) & (local < e)
+        group = jnp.where(held, local, e).reshape(-1)  # elsewhere → last
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.bincount(group, length=e + 1)[:e].astype(jnp.int32)
+        rows = xt[order // k]  # (T·k, d), grouped by held expert
+    with jax.named_scope("moe.experts"):
+        dt = x.dtype
+        hi = jax.lax.ragged_dot(rows, p["wi"].astype(dt), sizes)
+        hg = jax.lax.ragged_dot(rows, p["wg"].astype(dt), sizes)
+        y = jax.lax.ragged_dot(jax.nn.silu(hi) * hg, p["wo"].astype(dt), sizes)
+        # rows past the held groups are not computed: zero them, put the
+        # rows back in (token, choice) order and sum each token's k rows
+        # in a fixed order, weighted zero where the expert is held elsewhere
+        y = jnp.where((jnp.arange(t * k) < sizes.sum())[:, None], y, 0)
+        y = jnp.zeros_like(y).at[order].set(y, unique_indices=True)
+        y = y.reshape(t, k, d).astype(jnp.float32)
+        w_held = jnp.where(held, w, 0.0)
+        routed = sum(w_held[:, j, None] * y[:, j] for j in range(k))
+    with jax.named_scope("moe.shared"):
+        shared = ffn_apply(p["shared"], cfg, xt)
+    out = (routed + shared.astype(jnp.float32)).astype(x.dtype).reshape(b, s, d)
+    n_held = None
+    if count_mask is not None:
+        n_held = jnp.sum(held & count_mask.reshape(t, 1), dtype=jnp.int32)
+    return out, n_held

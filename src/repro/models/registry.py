@@ -83,8 +83,31 @@ class Arch:
         be rolled back by truncating a cursor, hybrid mixes KV with
         recurrent leaves, encoder-only never decodes.  (The int8-quantized
         KV cache is NOT excluded: verify rows attend the same dequantized
-        values sequential decode attends — ISSUE 10.)"""
+        values sequential decode attends.)  Latent attention
+        is refused: verify windows over the latent pool are not wired."""
+        if self.cfg.attention == "mla":
+            return ("latent attention: speculative verify over the latent "
+                    "pool is not wired")
         return self.chunked_prefill_skip_reason()
+
+    # -- cache layouts a family refuses ------------------------------------
+    def dense_layout_skip_reason(self) -> str:
+        """'' when the family serves from dense per-slot cache rows
+        (``kv_layout="dense"``), else why not."""
+        if self.cfg.attention == "mla":
+            from repro.models.transformer import MLA_DENSE_LAYOUT
+
+            return MLA_DENSE_LAYOUT
+        return ""
+
+    def kv_int8_skip_reason(self) -> str:
+        """'' when the family's cache can hold int8 KV with per-position
+        scales (``MeshPlan.cache_quant_int8``), else why not."""
+        if self.cfg.attention == "mla":
+            from repro.models.transformer import MLA_INT8_KV
+
+            return MLA_INT8_KV
+        return ""
 
     # -- paged KV (serving; see check_paged_cache_contract) -----------------
     @property
@@ -371,6 +394,8 @@ def check_slots_cache_contract(
       * when the family also supports paged KV, the paged twin (same
         forward with a block table over a pool) maps the pool pytree to an
         identical pytree.
+    A family that refuses dense slot rows (``dense_layout_skip_reason``)
+    is checked on the paged twin alone.
     """
     plan = plan or MeshPlan()
     cfg = cfg or arch.cfg
@@ -378,33 +403,6 @@ def check_slots_cache_contract(
     if reason:
         raise NotImplementedError(f"{arch.arch_id}: {reason}")
     b = n_slots - 1  # a partial group, like a real admit round
-    cache = arch.abstract_cache(n_slots, max_len, plan, cfg)
-    slots = SDS((b,), jnp.int32)
-
-    def roundtrip(cache, slots):
-        small = gather_cache_slots(cache, slots)
-        return write_cache_slots(cache, small, slots), small
-
-    out, small = jax.eval_shape(roundtrip, cache, slots)
-
-    def assert_same_pytree(a, c, what):
-        la, ta = jax.tree_util.tree_flatten(a)
-        lc, tc = jax.tree_util.tree_flatten(c)
-        assert ta == tc, f"{arch.arch_id}: {what} changed the cache treedef"
-        bad = [
-            (i, x.shape, x.dtype, y.shape, y.dtype)
-            for i, (x, y) in enumerate(zip(la, lc))
-            if x.shape != y.shape or x.dtype != y.dtype
-        ]
-        assert not bad, f"{arch.arch_id}: {what} changed leaf specs: {bad}"
-
-    assert_same_pytree(cache, out, "slot gather/scatter round-trip")
-    for i, leaf in enumerate(jax.tree_util.tree_leaves(small)):
-        assert leaf.shape[CACHE_SLOT_AXIS] == b, (
-            f"{arch.arch_id}: gathered sub-cache leaf {i} batch dim is "
-            f"{leaf.shape} (want {b} on axis {CACHE_SLOT_AXIS})"
-        )
-
     params = arch.abstract_params(cfg)
     starts = SDS((b,), jnp.int32)
     if arch.input_kind == "tokens":
@@ -414,16 +412,8 @@ def check_slots_cache_contract(
         if arch.input_kind == "embeds+mrope":
             kw["positions"] = SDS((b, 3, chunk), jnp.int32)
 
-    def resume(params, small, starts, kw):
-        return arch.forward(
-            params, plan, cfg=cfg, cache=small, cache_pos=starts, **kw
-        )
-
-    logits, new_small = jax.eval_shape(resume, params, small, starts, kw)
-    assert_same_pytree(small, new_small, "chunk-resume forward")
-    assert logits.shape == (b, chunk, cfg.vocab_size), (
-        f"{arch.arch_id}: chunk-resume logits shape {logits.shape}"
-    )
+    if not arch.dense_layout_skip_reason():
+        _check_dense_slots(arch, params, kw, n_slots, b, max_len, plan, cfg)
 
     if arch.supports_paged_kv:
         block_len = max(max_len // 4, 1)
@@ -440,35 +430,56 @@ def check_slots_cache_contract(
         _, new_pool = jax.eval_shape(
             resume_paged, params, pool, starts, table, kw
         )
-        assert_same_pytree(pool, new_pool, "paged chunk-resume forward")
+        _assert_same_pytree(arch, pool, new_pool, "paged chunk-resume forward")
+
+
+def _assert_same_pytree(arch: Arch, a, c, what: str) -> None:
+    la, ta = jax.tree_util.tree_flatten(a)
+    lc, tc = jax.tree_util.tree_flatten(c)
+    assert ta == tc, f"{arch.arch_id}: {what} changed the cache treedef"
+    bad = [
+        (i, x.shape, x.dtype, y.shape, y.dtype)
+        for i, (x, y) in enumerate(zip(la, lc))
+        if x.shape != y.shape or x.dtype != y.dtype
+    ]
+    assert not bad, f"{arch.arch_id}: {what} changed leaf specs: {bad}"
+
+
+def _check_dense_slots(arch, params, kw, n_slots, b, max_len, plan,
+                       cfg) -> None:
+    """The dense slot-row half of ``check_slots_cache_contract``."""
+    cache = arch.abstract_cache(n_slots, max_len, plan, cfg)
+    slots = SDS((b,), jnp.int32)
+
+    def roundtrip(cache, slots):
+        small = gather_cache_slots(cache, slots)
+        return write_cache_slots(cache, small, slots), small
+
+    out, small = jax.eval_shape(roundtrip, cache, slots)
+    _assert_same_pytree(arch, cache, out, "slot gather/scatter round-trip")
+    for i, leaf in enumerate(jax.tree_util.tree_leaves(small)):
+        assert leaf.shape[CACHE_SLOT_AXIS] == b, (
+            f"{arch.arch_id}: gathered sub-cache leaf {i} batch dim is "
+            f"{leaf.shape} (want {b} on axis {CACHE_SLOT_AXIS})"
+        )
+
+    starts = SDS((b,), jnp.int32)
+    chunk = next(iter(kw.values())).shape[1]
+
+    def resume(params, small, starts, kw):
+        return arch.forward(
+            params, plan, cfg=cfg, cache=small, cache_pos=starts, **kw
+        )
+
+    logits, new_small = jax.eval_shape(resume, params, small, starts, kw)
+    _assert_same_pytree(arch, small, new_small, "chunk-resume forward")
+    assert logits.shape == (b, chunk, cfg.vocab_size), (
+        f"{arch.arch_id}: chunk-resume logits shape {logits.shape}"
+    )
 
 
 CACHE_BLOCK_AXIS = 1  # paged pools put the physical-block axis where the
 #                       dense slot layout puts the slot axis
-
-
-def write_cache_block(cache, sub_cache, blocks):
-    """Install a batch-1 prefill cache into physical blocks of a paged pool.
-
-    ``sub_cache`` leaves are (L, 1, nb·block_len, KH, Dh) (a dense batch-1
-    cache whose length is padded up to whole blocks); ``blocks`` is the (nb,)
-    int32 vector of physical block ids the allocator mapped for the slot
-    (may be traced — the paged prefill program jits over it; ids are
-    distinct by the allocator contract, hence ``unique_indices``).  Each
-    leaf is reshaped into blocks and scattered onto axis
-    ``CACHE_BLOCK_AXIS`` of the pool; no other block is touched
-    (``check_paged_cache_contract``).
-    """
-    nb = blocks.shape[0]
-
-    def wr(full, one):
-        bl = full.shape[CACHE_BLOCK_AXIS + 1]
-        lead = one.shape[0]  # n_layers
-        assert one.shape[2] == nb * bl, (one.shape, nb, bl)
-        o = one[:, 0].reshape(lead, nb, bl, *one.shape[3:]).astype(full.dtype)
-        return full.at[:, blocks].set(o, unique_indices=True)
-
-    return jax.tree_util.tree_map(wr, cache, sub_cache)
 
 
 def check_paged_cache_contract(

@@ -62,6 +62,12 @@ tokens/step).  Rejected tokens cost nothing to undo: rollback is cursor
 truncation, on the dense rows and on the paged block table alike.  Greedy
 speculative outputs are bit-identical to the plain scheduler.  See
 docs/serving.md.
+
+Held-expert counts: for a model whose expert layer holds a share of the
+experts (``ModelConfig.moe_router == "sigmoid_bias"``), every slot program
+also returns the (token, held expert) assignments of its real tokens,
+summed on the device (``held``; None for other models), which the
+scheduler downloads with the tokens it already fetches.
 """
 from __future__ import annotations
 
@@ -201,6 +207,19 @@ class ServeEngine:
                 "(pool sharding constraints missing — see ROADMAP)"
             )
         assert sc.weight_quant in ("none", "int8"), sc.weight_quant
+        model_cfg = cfg or arch.cfg
+        if sc.kv_layout == "dense" and arch.dense_layout_skip_reason():
+            raise NotImplementedError(
+                f"{arch.arch_id}: {arch.dense_layout_skip_reason()}")
+        if plan.cache_quant_int8 and arch.kv_int8_skip_reason():
+            raise NotImplementedError(
+                f"{arch.arch_id}: {arch.kv_int8_skip_reason()}")
+        if sc.weight_quant == "int8" and (model_cfg.attention == "mla"
+                                          or model_cfg.moe_router != "softmax"):
+            raise NotImplementedError(
+                f"{arch.arch_id}: int8 weights are not wired for latent "
+                f"attention or the held-expert layer, which read their raw "
+                f"matrices (absorption, grouped expert matmuls, router)")
         raw_params = params  # pre-quantization tree (drafter derivation)
         if sc.weight_quant == "int8":
             # one-time host-side conversion: every slot program reads
@@ -214,7 +233,7 @@ class ServeEngine:
                 block=sc.weight_quant_block,
             )
         self.arch, self.params, self.plan, self.sc = arch, params, plan, sc
-        self.cfg = cfg or arch.cfg
+        self.cfg = model_cfg
 
         # ------------------------- speculative decoding (drafter resolution)
         #
@@ -291,6 +310,17 @@ class ServeEngine:
             with jax.named_scope("sample"):
                 return sample_token(logits, key, sc.temperature, sc.top_k,
                                     sc.top_p)
+
+        counting = self.counts_moe_rows
+
+        def forward_counted(params, real, **kw):
+            """``arch.forward`` → (logits, cache, held): ``held`` counts the
+            held-expert assignments of the tokens ``real`` (B, S) marks, on
+            a model that counts them; None on any other."""
+            if not counting:
+                return (*arch.forward(params, plan, cfg=self.cfg, **kw), None)
+            return arch.forward(params, plan, cfg=self.cfg, count_mask=real,
+                                **kw)
 
         def prefill(params, tokens, key):
             self.trace_counts["prefill"] += 1
@@ -389,38 +419,36 @@ class ServeEngine:
                 P; slot and max_new are traced scalars, so neither
                 retraces).  Dense: the batch-1 cache is written into the
                 slot row (``write_cache_slot``).  Paged (extra ``bt_row``
-                arg before ``key``): the prefill cache is padded up to whole
-                blocks and scattered into the physical blocks the row maps
-                (``write_cache_block``).  The whole slot state is donated;
+                arg before ``key``): a chunk-resume forward from position 0
+                writes straight into the physical blocks the row maps.  The
+                whole slot state is donated;
                 the host only reads the first sampled token back.
                 """
                 self.trace_counts[name] += 1
                 key = rest[-1]
                 p_len = prompt.shape[1]
+                real = jnp.ones(prompt.shape, bool)
                 if paged:
-                    bt_row = rest[0]
-                    nb = -(-p_len // sc.block_len)  # ceil — static per trace
-                    small = arch.init_cache(1, nb * sc.block_len, plan,
-                                            cfg=self.cfg)
+                    # straight into the row's mapped blocks: a chunk-resume
+                    # forward from position 0 over the pool
+                    logits, cache, held = forward_counted(
+                        params, real, tokens=prompt, cache=cache,
+                        cache_pos=jnp.zeros((1,), jnp.int32),
+                        block_table=rest[0][None, :])
                 else:
                     small = arch.init_cache(1, sc.max_len, plan, cfg=self.cfg)
-                logits, small = arch.forward(
-                    params, plan, cfg=self.cfg, tokens=prompt, cache=small
-                )
-                first = sample(logits[:, -1], key)[0]
-                if paged:
-                    from repro.models.registry import write_cache_block
-
-                    cache = write_cache_block(cache, small, bt_row[:nb])
-                else:
+                    logits, small, held = forward_counted(
+                        params, real, tokens=prompt, cache=small)
                     from repro.models.registry import write_cache_slot
 
                     cache = write_cache_slot(cache, small, slot)
+                first = sample(logits[:, -1], key)[0]
                 return (
                     cache,
                     tok.at[slot].set(first),
                     pos.at[slot].set(p_len),
                     done.at[slot].set(False),
+                    held,
                     first,
                 )
 
@@ -455,10 +483,14 @@ class ServeEngine:
                 """
                 self.trace_counts[name] += 1
                 key = rest[-1]
+                # real tokens: a real row's chunk up to its last real token
+                real = ((slots < tok.shape[0])[:, None]
+                        & (jnp.arange(prompts.shape[1])[None, :]
+                           <= last_local[:, None]))
                 if paged:
                     bt_rows = rest[0]
-                    logits, cache = arch.forward(
-                        params, plan, cfg=self.cfg, tokens=prompts,
+                    logits, cache, held = forward_counted(
+                        params, real, tokens=prompts,
                         cache=cache, cache_pos=starts, block_table=bt_rows,
                     )
                 else:
@@ -467,8 +499,8 @@ class ServeEngine:
                     )
 
                     small = gather_cache_slots(cache, slots)
-                    logits, small = arch.forward(
-                        params, plan, cfg=self.cfg, tokens=prompts,
+                    logits, small, held = forward_counted(
+                        params, real, tokens=prompts,
                         cache=small, cache_pos=starts,
                     )
                     cache = write_cache_slots(cache, small, slots)
@@ -481,6 +513,7 @@ class ServeEngine:
                     tok.at[slots].set(firsts, mode="drop"),
                     pos.at[slots].set(starts + last_local + 1, mode="drop"),
                     done.at[slots].set(False, mode="drop"),
+                    held,
                     firsts,
                 )
 
@@ -504,19 +537,19 @@ class ServeEngine:
             """
             key, sub = jax.random.split(key)
             fkw = {} if block_table is None else {"block_table": block_table}
-            logits, cache = arch.forward(
-                params, plan, cfg=self.cfg, tokens=tok[:, None],
+            live = active & ~done
+            logits, cache, held = forward_counted(
+                params, live[:, None], tokens=tok[:, None],
                 cache=cache, cache_pos=pos, **fkw,
             )
             nxt = sample(logits[:, 0], sub)
-            live = active & ~done
             if sc.eos_token >= 0:
                 done = done | (live & (nxt == sc.eos_token))
             emitted = jnp.where(live, nxt, -1)
             tok = jnp.where(live, nxt, tok)
             pos = jnp.where(live, pos + 1, pos)
             done = done | (active & (pos >= limit))
-            return cache, tok, pos, done, key, emitted
+            return cache, tok, pos, done, key, emitted, held
 
         def spec_step(params, draft_params, cache, tok, pos, done, key,
                       active, limit, block_table=None):
@@ -584,23 +617,31 @@ class ServeEngine:
             if sc.eos_token >= 0:
                 stop = stop | (last == sc.eos_token)
             done = done | (live & stop)
-            return cache, tok, pos, done, key, emitted  # emitted (B, K+1)
+            # emitted (B, K+1); no held-expert family runs speculation
+            return cache, tok, pos, done, key, emitted, None
+
+        held0 = jnp.int32(0) if counting else None
+
+        def add_held(total, n):
+            return total if n is None else total + n
 
         def segment_scan_impl(n_steps, step, cache, tok, pos, done, key):
             """Shared scan-segment body (dense/paged × plain/speculative):
             one place to change segment semantics, so the four programs
             cannot drift apart.  ``step`` emits (B,) tokens per step on the
             plain path and (B, K+1) on the speculative one — the stacked
-            output comes back (n_slots, n_steps[, K+1])."""
+            output comes back (n_slots, n_steps[, K+1]) — and the steps'
+            held-expert counts add up (None where not counted)."""
 
             def body(carry, _):
-                cache, tok, pos, done, key, emitted = step(*carry)
-                return (cache, tok, pos, done, key), emitted
+                *state, held = carry
+                cache, tok, pos, done, key, emitted, n = step(*state)
+                return (cache, tok, pos, done, key, add_held(held, n)), emitted
 
-            (cache, tok, pos, done, key), toks = jax.lax.scan(
-                body, (cache, tok, pos, done, key), length=n_steps
+            (cache, tok, pos, done, key, held), toks = jax.lax.scan(
+                body, (cache, tok, pos, done, key, held0), length=n_steps
             )
-            return jnp.moveaxis(toks, 0, 1), cache, tok, pos, done, key
+            return jnp.moveaxis(toks, 0, 1), cache, tok, pos, done, key, held
 
         def segment_while_impl(n_steps, step, cache, tok, pos, done, key,
                                active, stop_on_free, emit_tail):
@@ -619,28 +660,28 @@ class ServeEngine:
             out0 = jnp.full((n_slots, n_steps) + emit_tail, -1, jnp.int32)
 
             def cond(st):
-                i, _cache, _tok, _pos, done, _key, _out = st
+                i, _cache, _tok, _pos, done, _key, _out, _held = st
                 any_running = jnp.any(active & ~done)
                 freed = jnp.any(active & done)
                 return (i < n_steps) & any_running & ~(stop_on_free & freed)
 
             def loop_body(st):
-                i, cache, tok, pos, done, key, out = st
-                cache, tok, pos, done, key, emitted = step(
+                i, cache, tok, pos, done, key, out, held = st
+                cache, tok, pos, done, key, emitted, n = step(
                     cache, tok, pos, done, key
                 )
                 upd = emitted.reshape((n_slots, 1) + emit_tail)
                 out = jax.lax.dynamic_update_slice(
                     out, upd, (0, i) + (0,) * len(emit_tail)
                 )
-                return i + 1, cache, tok, pos, done, key, out
+                return i + 1, cache, tok, pos, done, key, out, add_held(held, n)
 
             st = jax.lax.while_loop(
                 cond, loop_body,
-                (jnp.int32(0), cache, tok, pos, done, key, out0),
+                (jnp.int32(0), cache, tok, pos, done, key, out0, held0),
             )
-            _, cache, tok, pos, done, key, out = st
-            return out, cache, tok, pos, done, key
+            _, cache, tok, pos, done, key, out, held = st
+            return out, cache, tok, pos, done, key, held
 
         def _mk_segment(flavor, paged, spec):
             """Build one compiled segment program.
@@ -741,6 +782,25 @@ class ServeEngine:
                 setattr(self, "_" + nm, fn)
 
     # ------------------------------------------------------------- public
+
+    @property
+    def counts_moe_rows(self) -> bool:
+        """Whether the slot programs return held-expert counts."""
+        return self.cfg.n_experts > 0 and self.cfg.moe_router == "sigmoid_bias"
+
+    def moe_rows_launched(self, n_tokens: int) -> int:
+        """Rows the expert layers launch for a forward over ``n_tokens``
+        tokens, padding included: tokens × experts per token × MoE layers
+        for a held-expert model.  A dense transformer's FFN counts as an
+        expert layer that holds its one expert: a row per token and layer.
+        0 for the models whose layers route otherwise."""
+        if self.counts_moe_rows:
+            from repro.models.moe import held_rows_launched
+
+            return held_rows_launched(self.cfg, n_tokens)
+        if self.cfg.family == "dense":
+            return self.cfg.n_layers * n_tokens
+        return 0
 
     def init_slot_cache(self, n_slots: int):
         """Fresh slot cache (batch = n_slots, length = max_len) for the

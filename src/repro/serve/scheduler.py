@@ -98,7 +98,7 @@ from __future__ import annotations
 
 import collections
 import time
-from typing import Callable, Sequence
+from typing import Any, Callable, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -434,6 +434,13 @@ class ContinuousScheduler:
             # tokens computed (dummy rows and bucket padding included)
             "prefill_tokens_real": 0,
             "prefill_tokens_launched": 0,
+            # expert layers: the (token, held expert) assignments of real
+            # tokens (counted on the device and fetched with the tokens in
+            # a held-expert model; a dense FFN holds its one expert, so
+            # there every real token's row counts) and the rows the layers
+            # launched, padding included (``engine.moe_rows_launched``)
+            "moe_rows_held": 0,
+            "moe_rows_computed": 0,
         }
         self._phase = Phases(self.stats, clock)
 
@@ -1106,7 +1113,8 @@ class ContinuousScheduler:
                 (slot, start, real, final)
             )
         pool_size = (self.n_slots + self.n_blocks) if self.paged else 0
-        launched: list[tuple[list, jax.Array]] = []
+        launched: list[tuple[list, jax.Array, Any]] = []
+        round_tokens = [0, 0]  # launched, real
         for bucket in sorted(rows_by_bucket):
             rows = rows_by_bucket[bucket]
             # launch width is bucketed to powers of two as well (second
@@ -1160,24 +1168,29 @@ class ContinuousScheduler:
                 else:
                     fn, ckey = eng._prefill_slots, "prefill_slots"
                     args = (*args, sub)
-                self.cache, self.tok, self.pos, self.done, firsts = fn(*args)
+                (self.cache, self.tok, self.pos, self.done, held,
+                 firsts) = fn(*args)
             eng.call_counts[ckey] += 1
-            launched.append((rows, firsts))
+            launched.append((rows, firsts, held))
             self.stats["prefill_launches"] += 1
             self.stats["chunks_prefilled"] += len(rows)
             self.stats["prefill_tokens_real"] += real_tokens
             self.stats["prefill_tokens_launched"] += width * bucket
+            round_tokens[0] += width * bucket
+            round_tokens[1] += real_tokens
             hist = self.stats["prefill_batch_hist"]
             hist[len(rows)] = hist.get(len(rows), 0) + 1
             if self.trace is not None:
                 self.trace.record_prefill(self.stats["segments"], width,
                                           bucket, real_tokens)
-        # the ONLY admit-round download: every launch's first tokens at once
+        # the ONLY admit-round download: every launch's first tokens (and
+        # held-expert counts) at once
         with self._phase("prefill_wait"):
-            firsts_h = jax.device_get([f for _, f in launched])
+            got = jax.device_get([(f, h) for _, f, h in launched])
+        self._count_rows(*round_tokens, [h for _, h in got])
         now = self.clock()
         n_live = 0
-        for (rows, _), fh in zip(launched, firsts_h):
+        for (rows, _, _), (fh, _) in zip(launched, got):
             for i, (slot, start, real, final) in enumerate(rows):
                 req = self.slots[slot]
                 if not final:
@@ -1243,7 +1256,8 @@ class ContinuousScheduler:
         safe (device executes the prefills in dispatch order).
         """
         eng = self.engine
-        pending: list[tuple[Request, int, jax.Array, bool]] = []
+        pending: list[tuple[Request, int, jax.Array, bool, Any]] = []
+        n_tokens = 0
         deferred = False
         for slot in range(self.n_slots):
             if deferred:
@@ -1265,31 +1279,30 @@ class ContinuousScheduler:
                                  width=1, bucket=n, real_tokens=n):
                     self.key, sub = jax.random.split(self.key)
                     if self.paged:
-                        self.cache, self.tok, self.pos, self.done, first = (
-                            eng._prefill_slot_paged(
-                                eng.params, self.cache, self.tok, self.pos,
-                                self.done, jnp.asarray(prefix)[None, :],
-                                jnp.int32(slot),
-                                jnp.asarray(self.block_table[slot]), sub,
-                            )
+                        (self.cache, self.tok, self.pos, self.done, held,
+                         first) = eng._prefill_slot_paged(
+                            eng.params, self.cache, self.tok, self.pos,
+                            self.done, jnp.asarray(prefix)[None, :],
+                            jnp.int32(slot),
+                            jnp.asarray(self.block_table[slot]), sub,
                         )
                     else:
-                        self.cache, self.tok, self.pos, self.done, first = (
-                            eng._prefill_slot(
-                                eng.params, self.cache, self.tok, self.pos,
-                                self.done, jnp.asarray(prefix)[None, :],
-                                jnp.int32(slot), sub,
-                            )
+                        (self.cache, self.tok, self.pos, self.done, held,
+                         first) = eng._prefill_slot(
+                            eng.params, self.cache, self.tok, self.pos,
+                            self.done, jnp.asarray(prefix)[None, :],
+                            jnp.int32(slot), sub,
                         )
                 eng.call_counts["prefill_slot_paged" if self.paged
                                 else "prefill_slot"] += 1
                 # one row at the prompt's own length: nothing padded
                 self.stats["prefill_tokens_real"] += n
                 self.stats["prefill_tokens_launched"] += n
+                n_tokens += n
                 if self.trace is not None:
                     self.trace.record_prefill(self.stats["segments"], 1, n, n)
                 resumed = bool(req.tokens)
-                pending.append((req, slot, first, resumed))
+                pending.append((req, slot, first, resumed, held))
                 if resumed:
                     # recompute readmit: the prefill re-ran the ORIGINAL
                     # admission program on the prompt alone — its sample
@@ -1315,9 +1328,10 @@ class ContinuousScheduler:
         if not pending:
             return 0
         with self._phase("prefill_wait"):
-            firsts = jax.device_get([f for _, _, f, _ in pending])
+            got = jax.device_get([(f, h) for _, _, f, _, h in pending])
+        self._count_rows(n_tokens, n_tokens, [h for _, h in got])
         now = self.clock()
-        for (req, slot, _, resumed), first in zip(pending, firsts):
+        for (req, slot, _, resumed, _), (first, _) in zip(pending, got):
             if resumed:
                 replay = self._replay[slot]
                 want = replay.popleft()
@@ -1470,16 +1484,29 @@ class ContinuousScheduler:
                 args = (*args, jnp.asarray(self.block_table))
                 seg_key += "_paged"
             seg_fn = getattr(eng, "_" + seg_key)
-            toks, self.cache, self.tok, self.pos, self.done, self.key = (
-                seg_fn(*args)
-            )
+            (toks, self.cache, self.tok, self.pos, self.done, self.key,
+             held) = seg_fn(*args)
         eng.call_counts[seg_key] += 1
         with self._phase("segment_wait"):
-            toks = np.asarray(toks)  # the only per-segment download
+            # the only per-segment download
+            toks, held = jax.device_get((toks, held))
+            toks = np.asarray(toks)
         with self._phase("retire", "host_s_retire") as span:
             n_exec, n_live = self._retire(toks)
+            if self.spec is None:  # a verify step is no one-token forward
+                self._count_rows(n_exec * self.n_slots, n_live, [held])
             span.set_metadata(steps=n_exec, live=n_live)
         return sum(r is not None for r in self.slots)
+
+    def _count_rows(self, launched: int, real: int, held: list) -> None:
+        """Add the expert-layer rows of ``launched`` tokens, ``real`` of
+        them real: ``held`` holds the device's counts where the engine
+        counts held-expert rows (None otherwise)."""
+        eng = self.engine
+        self.stats["moe_rows_computed"] += eng.moe_rows_launched(launched)
+        self.stats["moe_rows_held"] += (
+            sum(int(h) for h in held) if eng.counts_moe_rows
+            else eng.moe_rows_launched(real))
 
     def _retire(self, toks: np.ndarray) -> tuple[int, int]:
         """Account one segment's emissions, stream them and retire the
