@@ -56,12 +56,13 @@ def _shared_block(
     plan: MeshPlan,
     cache: tuple | None,
     cache_pos: jax.Array | None,
+    live: jax.Array | None = None,
 ) -> tuple[jax.Array, tuple | None]:
     b, s, _ = x.shape
     seq = plan.tp if s > 1 else None
     h, new_cache = L.attention_apply(
         p["attn"], cfg, L.norm_apply(p["ln_a"], x), positions,
-        plan=plan, cache=cache, cache_pos=cache_pos, causal=True,
+        plan=plan, cache=cache, cache_pos=cache_pos, causal=True, live=live,
     )
     x = plan.constrain(x + h, plan.dp, seq, None)
     h2 = L.ffn_apply(p["ffn"], cfg, L.norm_apply(p["ln_f"], x))
@@ -80,6 +81,7 @@ def forward(
     cache: dict | None = None,  # see init_cache
     cache_pos: jax.Array | None = None,
     remat: bool = False,
+    live: jax.Array | None = None,  # (B,) rows whose decode output is used
 ) -> tuple[jax.Array, dict | None]:
     dtype = jnp.dtype(cfg.compute_dtype)
     if embeds is None:
@@ -115,7 +117,8 @@ def forward(
                 kci = jax.lax.dynamic_index_in_dim(kc, inv, 0, keepdims=False)
                 vci = jax.lax.dynamic_index_in_dim(vc, inv, 0, keepdims=False)
                 xo, nc = _shared_block(
-                    shared_p, cfg, x, positions, plan, (kci, vci), cache_pos
+                    shared_p, cfg, x, positions, plan, (kci, vci), cache_pos,
+                    live,
                 )
                 kc = jax.lax.dynamic_update_index_in_dim(kc, nc[0], inv, 0)
                 vc = jax.lax.dynamic_update_index_in_dim(vc, nc[1], inv, 0)

@@ -18,6 +18,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.kernels.paged_attention import (
+    decode_walk,
+    dense_decode_attention,
+    paged_decode_attention,
+)
 
 Params = dict[str, Any]
 
@@ -226,14 +231,13 @@ def decode_attention(
     pos: jax.Array,  # (B,) position of the FIRST query token
     scale: float | None = None,  # Dh^-1/2 unless given
 ) -> jax.Array:
-    """Decode-style attention over the cache: query i (at absolute position
-    ``pos + i``) attends cache positions ``<= pos + i``; everything beyond is
-    masked.  C == 1 is the classic single-token decode step; C > 1 is the
-    speculative-verify window, which deliberately reuses this exact
-    formulation (plain softmax, not the online-softmax flash path) so each
-    window row computes bitwise the same math as the sequential decode step
-    it replaces — the greedy spec/non-spec bit-identicality contract
-    (docs/serving.md) rests on that."""
+    """Decode-style attention over the whole gathered cache: query i (at
+    absolute position ``pos + i``) attends cache positions ``<= pos + i``;
+    everything beyond is masked.  C == 1 is the classic single-token decode
+    step; C > 1 is the speculative-verify window.  The paths that gather
+    their cache run it: int8 KV (after dequantizing) and latent attention.
+    An unquantized GQA cache is read in place, each slot up to its length
+    (``kernels.paged_attention``)."""
     b, c, h, dh = q.shape
     kh, dv = k_cache.shape[2], v_cache.shape[-1]
     g = h // kh
@@ -282,12 +286,15 @@ def update_kv_cache(
 # at a unique (block, offset) pair.  That lets the scatter below carry
 # ``unique_indices=True``, which XLA lowers markedly faster than a
 # collision-safe scatter (and faster than the dense layout's per-row
-# dynamic_update_slice).  The gather rebuilds the per-slot virtual cache
-# ``(n_slots, max_blocks·block_len, KH, Dh)`` — with ``max_blocks·block_len
-# == max_len`` the attention shapes (and therefore the greedy outputs) are
-# bit-identical to the dense slot layout; positions past ``pos`` read
-# scratch/stale values but are masked to exact zeros, exactly as the dense
-# layout's stale rows are.
+# dynamic_update_slice).  Decode-style attention over an unquantized pool
+# reads each slot's own blocks in place, up to its length
+# (``kernels.paged_attention``); the dense layout's rows run the same
+# computation as blocks of an identity table, so the greedy outputs of the
+# two layouts are bit-identical.  The gather below rebuilds the per-slot
+# virtual cache ``(n_slots, max_blocks·block_len, KH, Dh)`` for the paths
+# that still need it — prefill, int8 KV and latent attention; positions
+# past ``pos`` read scratch/stale values but are masked to exact zeros,
+# exactly as the dense layout's stale rows are.
 #
 # Every layer's blocks live in ONE stacked pool ``(n_layers, n_blocks, …)``
 # that the layer scan carries (``transformer.trunk_apply``); a layer writes
@@ -410,6 +417,7 @@ def attention_apply(
     block_table: jax.Array | None = None,  # (B, MB) int32 — paged cache mode
     causal: bool = True,
     decode_chunk: bool = False,  # speculative-verify window (serving)
+    live: jax.Array | None = None,  # (B,) bool: rows whose output is used
 ) -> tuple[jax.Array, tuple | None]:
     """Full attention block (no norm/residual).  Returns (out, new_cache).
 
@@ -424,19 +432,24 @@ def attention_apply(
         whole prompt at once (asserted in tests/test_serve_prefill.py).
       * cache given, S > 1, cache_pos, decode_chunk → speculative-verify
         window: same cache writes as chunk-resume, but attention runs
-        through ``decode_attention`` (plain softmax over the updated cache,
-        one decode-style row per window token) instead of the flash path —
-        each row is bitwise the SAME computation as the sequential decode
-        step it replaces, which is what makes greedy speculative outputs
-        bit-identical to non-speculative decoding (docs/serving.md).
+        decode-style (one row per window token over the updated cache)
+        instead of the flash path — each row is bitwise the SAME
+        computation as the sequential decode step it replaces, which is
+        what makes greedy speculative outputs bit-identical to
+        non-speculative decoding (docs/serving.md).
       * cache given, S == 1              → decode step at ``cache_pos``.
       * block_table given                → paged cache: ``cache`` is
         this layer's (k_pool, v_pool) ``LayerPool`` views of the stacked
         block pools; decode scatters one token into the
         mapped block (``paged_cache_write``), chunk-resume / verify-window
         scatters the whole chunk at its block-table offsets
-        (``paged_cache_write_chunk``); attention runs over the gathered
-        virtual cache either way.
+        (``paged_cache_write_chunk``).
+
+    Decode steps and verify windows over an unquantized cache read each
+    slot's blocks in place up to its length (``kernels.paged_attention``;
+    the dense layout's rows as blocks of an identity table); a slot not
+    ``live`` reads one block.  Prefill and int8 KV attend a gathered
+    (dequantized) cache.
 
     Sharding (when ``plan`` has a mesh): q/k/v are constrained to head-sharded
     (or head_dim-sharded) layout over the TP axis; KV heads are replicated
@@ -509,28 +522,39 @@ def attention_apply(
             if quant:
                 ks_pool = write(ks_pool, block_table, ks_new, cache_pos)
                 vs_pool = write(vs_pool, block_table, vs_new, cache_pos)
-        with jax.named_scope("kv.gather"):
-            k_virt = paged_cache_gather(k_pool, block_table)
-            v_virt = paged_cache_gather(v_pool, block_table)
-            if quant:
-                k_virt = dequantize_kv(
-                    k_virt, paged_cache_gather(ks_pool, block_table), q.dtype)
-                v_virt = dequantize_kv(
-                    v_virt, paged_cache_gather(vs_pool, block_table), q.dtype)
-        with jax.named_scope("attn.core"):
-            if s == 1 or decode_chunk:
-                # decode step / speculative-verify window: one plain-softmax
-                # row per query token over the gathered (dequantized) cache
-                out = decode_attention(q, k_virt, v_virt, cache_pos)
-            else:  # chunk-resume prefill at block-table offsets
-                kv_pos = jnp.broadcast_to(
-                    jnp.arange(k_virt.shape[1], dtype=jnp.int32),
-                    (b, k_virt.shape[1]),
-                )
-                pos2d = (positions if positions.ndim == 2
-                         else positions[:, 0, :])
-                out = flash_attention(q, k_virt, v_virt, pos2d, kv_pos,
-                                      causal=causal)
+        if (s == 1 or decode_chunk) and not quant:
+            # decode step / verify window: each slot's own blocks, read in
+            # place up to its length
+            with jax.named_scope("attn.core"):
+                out = paged_decode_attention(
+                    q, k_pool.pool, v_pool.pool, k_pool.layer, block_table,
+                    cache_pos,
+                    decode_walk(cache_pos, s, live, k_pool.pool.shape[2]))
+        else:
+            with jax.named_scope("kv.gather"):
+                k_virt = paged_cache_gather(k_pool, block_table)
+                v_virt = paged_cache_gather(v_pool, block_table)
+                if quant:
+                    k_virt = dequantize_kv(
+                        k_virt, paged_cache_gather(ks_pool, block_table),
+                        q.dtype)
+                    v_virt = dequantize_kv(
+                        v_virt, paged_cache_gather(vs_pool, block_table),
+                        q.dtype)
+            with jax.named_scope("attn.core"):
+                if s == 1 or decode_chunk:
+                    # int8 KV: one plain-softmax row per query token over
+                    # the gathered, dequantized cache
+                    out = decode_attention(q, k_virt, v_virt, cache_pos)
+                else:  # chunk-resume prefill at block-table offsets
+                    kv_pos = jnp.broadcast_to(
+                        jnp.arange(k_virt.shape[1], dtype=jnp.int32),
+                        (b, k_virt.shape[1]),
+                    )
+                    pos2d = (positions if positions.ndim == 2
+                             else positions[:, 0, :])
+                    out = flash_attention(q, k_virt, v_virt, pos2d, kv_pos,
+                                          causal=causal)
         with jax.named_scope("attn.out"):
             out = dense_apply(p["wo"], out.reshape(b, s, h * dh))
         new_cache = ((k_pool, v_pool, ks_pool, vs_pool) if quant
@@ -587,18 +611,18 @@ def attention_apply(
                 out = flash_attention(q, k_att, v_att, pos2d, kv_pos,
                                       causal=causal)
             elif s == 1 or (decode_chunk and cache_pos is not None):
-                # decode step / speculative-verify window: attend over the
-                # (dequantized) cache, one plain-softmax row per query token —
-                # under quant each verify row recomputes exactly what the
-                # sequential decode step would, so greedy spec outputs stay
-                # bit-identical to non-speculative int8-KV decoding
+                # decode step / speculative-verify window, one row per query
+                # token: each row recomputes exactly what the sequential
+                # decode step would, so greedy spec outputs stay
+                # bit-identical to non-speculative decoding
                 assert cache_pos is not None
-                if quant:
-                    k_att = dequantize_kv(k_cache, ks_cache, q.dtype)
-                    v_att = dequantize_kv(v_cache, vs_cache, q.dtype)
-                else:
-                    k_att, v_att = k_cache, v_cache
-                out = decode_attention(q, k_att, v_att, cache_pos)
+                if quant:  # over the whole dequantized cache
+                    out = decode_attention(
+                        q, dequantize_kv(k_cache, ks_cache, q.dtype),
+                        dequantize_kv(v_cache, vs_cache, q.dtype), cache_pos)
+                else:  # each row up to its length, as the paged layout does
+                    out = dense_decode_attention(q, k_cache, v_cache,
+                                                 cache_pos, live)
             elif cache_pos is not None:  # chunk-resume: attend over the cache
                 # (prefix from earlier chunks + this chunk's freshly written
                 # rows); positions past the chunk end are causally masked, so

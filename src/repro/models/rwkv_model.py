@@ -56,8 +56,9 @@ def forward(
     cache: dict | None = None,  # stacked rwkv6_init_state over layers
     cache_pos: jax.Array | None = None,  # unused
     remat: bool = False,
+    live: jax.Array | None = None,  # unused: no cache to read by length
 ) -> tuple[jax.Array, dict | None]:
-    del positions, cache_pos
+    del positions, cache_pos, live
     dtype = jnp.dtype(cfg.compute_dtype)
     if embeds is None:
         x = L.embed_apply(params["embed"], tokens, dtype)

@@ -93,10 +93,12 @@ def layer_apply(
     block_table: jax.Array | None = None,
     decode_chunk: bool = False,
     count_mask: jax.Array | None = None,
+    live: jax.Array | None = None,
 ) -> tuple[jax.Array, tuple | None, jax.Array | None]:
     """One layer → (x, new_cache, held rows).  ``held rows`` counts the
     (token, held expert) assignments of the tokens ``count_mask`` marks in a
-    held-expert layer, and is None elsewhere or without a mask."""
+    held-expert layer, and is None elsewhere or without a mask.  ``live``
+    (B,) marks the rows whose decode output is used (``attention_apply``)."""
     b, s, _ = x.shape
     seq = plan.tp if s > 1 else None  # SP only when the seq dim exists
 
@@ -122,6 +124,7 @@ def layer_apply(
             block_table=block_table,
             causal=not cfg.encoder_only,
             decode_chunk=decode_chunk,
+            live=live,
         )
     # constrain the sublayer OUTPUT (a TP partial sum) before the residual
     # add: GSPMD then lowers psum+shard to reduce-scatter instead of
@@ -174,6 +177,7 @@ def trunk_apply(
     block_table: jax.Array | None = None,  # paged: cache leaves are pools
     decode_chunk: bool = False,  # speculative-verify window (serving)
     count_mask: jax.Array | None = None,  # (B, S) real tokens to count
+    live: jax.Array | None = None,  # (B,) rows whose decode output is used
 ) -> tuple[jax.Array, dict | None, jax.Array | None]:
     """Scan the stacked layers.  Returns (hidden, new_cache, held rows).
 
@@ -227,7 +231,7 @@ def trunk_apply(
             x, new_c, n = layer_apply(lp, cfg, x, positions, plan, views,
                                       cache_pos, block_table,
                                       decode_chunk=decode_chunk,
-                                      count_mask=count_mask)
+                                      count_mask=count_mask, live=live)
             return (x, tuple(c.pool for c in new_c), _add(n_held, n)), None
 
         carry = (x, tuple(cache[n] for n in names), n_held0)
@@ -245,12 +249,12 @@ def trunk_apply(
             lp, kc, vc, ks, vs = inp
             x, new_c, _ = layer_apply(lp, cfg, x, positions, plan,
                                       (kc, vc, ks, vs), cache_pos, block_table,
-                                      decode_chunk=decode_chunk)
+                                      decode_chunk=decode_chunk, live=live)
         else:
             lp, kc, vc = inp
             x, new_c, _ = layer_apply(lp, cfg, x, positions, plan, (kc, vc),
                                       cache_pos, block_table,
-                                      decode_chunk=decode_chunk)
+                                      decode_chunk=decode_chunk, live=live)
         return x, new_c
 
     if quant:
@@ -283,6 +287,7 @@ def forward(
     block_table: jax.Array | None = None,  # paged-KV decode/resume (serving)
     decode_chunk: bool = False,  # speculative-verify window (serving)
     count_mask: jax.Array | None = None,  # (B, S) bool: real tokens
+    live: jax.Array | None = None,  # (B,) bool: rows whose output is used
 ) -> tuple[jax.Array, dict | None] | tuple[jax.Array, dict | None, jax.Array]:
     """→ (logits (B, S, V), new_cache), and with ``count_mask`` a third
     element: the (token, held expert) assignments of the tokens it marks,
@@ -295,7 +300,9 @@ def forward(
     ``registry.check_slots_cache_contract``).  ``decode_chunk=True`` (with
     ``cache_pos``, S > 1) is the speculative-verify window: same cache
     writes, but attention runs decode-style so every window row is bitwise
-    the computation sequential decode would do (``layers.decode_attention``)."""
+    the computation sequential decode would do.  In a decode step or verify
+    window a row that is not ``live`` reads one block of its cache: its
+    output is dropped (masked and retired serving slots)."""
     dtype = jnp.dtype(cfg.compute_dtype)
     if embeds is None:
         assert tokens is not None
@@ -315,7 +322,7 @@ def forward(
     x = plan.constrain(x, plan.dp, seq, None)
     x, new_cache, n_held = trunk_apply(
         params, cfg, x, positions, plan, cache, cache_pos, remat, block_table,
-        decode_chunk, count_mask,
+        decode_chunk, count_mask, live,
     )
     x = L.norm_apply(params["final_norm"], x)
     with jax.named_scope("lm_head"):

@@ -66,8 +66,16 @@ docs/serving.md.
 Held-expert counts: for a model whose expert layer holds a share of the
 experts (``ModelConfig.moe_router == "sigmoid_bias"``), every slot program
 also returns the (token, held expert) assignments of its real tokens,
-summed on the device (``held``; None for other models), which the
-scheduler downloads with the tokens it already fetches.
+summed on the device (a prefill program's ``held``, None for other models;
+a segment program's ``counts["held"]``), which the scheduler downloads with
+the tokens it already fetches.
+
+KV block counts: a paged segment program's ``counts`` also hold, summed on
+the device over its steps, the KV blocks its decode steps (and verify
+windows) read (``kv_read``: each slot's walk up to its length, summed over
+slots and layers) and what reading every slot's whole ``max_len`` would
+(``kv_span``).  Where attention gathers ``max_len`` (latent attention, int8
+KV) the two are equal.
 """
 from __future__ import annotations
 
@@ -78,6 +86,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.paged_attention import decode_walk
 from repro.sharding.mesh import MeshPlan
 from repro.serve.sampling import sample_token, spec_accept
 from repro.utils.logging import get_logger
@@ -312,6 +321,23 @@ class ServeEngine:
                                     sc.top_p)
 
         counting = self.counts_moe_rows
+        mb = self.max_blocks_per_slot
+        # decode-style attention reads each slot's blocks up to its length
+        # over an unquantized GQA cache; latent attention and int8 KV
+        # gather max_len
+        own_blocks = (self.cfg.attention != "mla"
+                      and not plan.cache_quant_int8)
+
+        def kv_counts(pos, c, live):
+            """{kv_read, kv_span}: the KV blocks one decode-style forward of
+            ``c`` queries a slot reads over the paged pool, summed over
+            slots and layers, and a whole ``max_len`` read's."""
+            span = jnp.int32(self.cfg.n_layers * pos.shape[0] * mb)
+            if not own_blocks:
+                return {"kv_read": span, "kv_span": span}
+            walk = jnp.clip(decode_walk(pos, c, live, sc.block_len), 1, mb)
+            return {"kv_read": self.cfg.n_layers * jnp.sum(walk),
+                    "kv_span": span}
 
         def forward_counted(params, real, **kw):
             """``arch.forward`` → (logits, cache, held): ``held`` counts the
@@ -540,8 +566,11 @@ class ServeEngine:
             live = active & ~done
             logits, cache, held = forward_counted(
                 params, live[:, None], tokens=tok[:, None],
-                cache=cache, cache_pos=pos, **fkw,
+                cache=cache, cache_pos=pos, live=live, **fkw,
             )
+            counts = {} if held is None else {"held": held}
+            if block_table is not None:
+                counts.update(kv_counts(pos, 1, live))
             nxt = sample(logits[:, 0], sub)
             if sc.eos_token >= 0:
                 done = done | (live & (nxt == sc.eos_token))
@@ -549,7 +578,7 @@ class ServeEngine:
             tok = jnp.where(live, nxt, tok)
             pos = jnp.where(live, pos + 1, pos)
             done = done | (active & (pos >= limit))
-            return cache, tok, pos, done, key, emitted, held
+            return cache, tok, pos, done, key, emitted, counts
 
         def spec_step(params, draft_params, cache, tok, pos, done, key,
                       active, limit, block_table=None):
@@ -595,7 +624,7 @@ class ServeEngine:
                 dlogits, d_cache = arch.forward(
                     draft_params, plan, cfg=self.draft_cfg,
                     tokens=cur[:, None], cache=d_cache, cache_pos=pos + i,
-                    **fkw,
+                    live=live, **fkw,
                 )
                 cur = jnp.argmax(dlogits[:, 0], axis=-1).astype(jnp.int32)
                 window.append(cur)
@@ -605,8 +634,11 @@ class ServeEngine:
 
             logits, cache = arch.forward(
                 params, plan, cfg=self.cfg, tokens=window, cache=cache,
-                cache_pos=pos, decode_chunk=True, **fkw,
+                cache_pos=pos, decode_chunk=True, live=live, **fkw,
             )
+            # no held-expert family runs speculation
+            counts = ({} if block_table is None
+                      else kv_counts(pos, k_spec + 1, live))
             verify = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # (B, K+1)
             emitted, n_emit, last = spec_accept(
                 window, verify, live, pos, limit, sc.eos_token
@@ -617,34 +649,42 @@ class ServeEngine:
             if sc.eos_token >= 0:
                 stop = stop | (last == sc.eos_token)
             done = done | (live & stop)
-            # emitted (B, K+1); no held-expert family runs speculation
-            return cache, tok, pos, done, key, emitted, None
+            # emitted (B, K+1)
+            return cache, tok, pos, done, key, emitted, counts
 
-        held0 = jnp.int32(0) if counting else None
+        def counts0(paged):
+            """The zero of a step's counts: held-expert rows where counted,
+            KV blocks on the paged layout."""
+            counts = {"held": jnp.int32(0)} if counting else {}
+            if paged:
+                counts.update(kv_read=jnp.int32(0), kv_span=jnp.int32(0))
+            return counts
 
-        def add_held(total, n):
-            return total if n is None else total + n
+        def add_counts(total, n):
+            return jax.tree_util.tree_map(jnp.add, total, n)
 
-        def segment_scan_impl(n_steps, step, cache, tok, pos, done, key):
+        def segment_scan_impl(n_steps, step, cache, tok, pos, done, key,
+                              paged):
             """Shared scan-segment body (dense/paged × plain/speculative):
             one place to change segment semantics, so the four programs
             cannot drift apart.  ``step`` emits (B,) tokens per step on the
             plain path and (B, K+1) on the speculative one — the stacked
             output comes back (n_slots, n_steps[, K+1]) — and the steps'
-            held-expert counts add up (None where not counted)."""
+            counts add up (``counts0``)."""
 
             def body(carry, _):
-                *state, held = carry
+                *state, total = carry
                 cache, tok, pos, done, key, emitted, n = step(*state)
-                return (cache, tok, pos, done, key, add_held(held, n)), emitted
+                return (cache, tok, pos, done, key, add_counts(total, n)), emitted
 
-            (cache, tok, pos, done, key, held), toks = jax.lax.scan(
-                body, (cache, tok, pos, done, key, held0), length=n_steps
+            (cache, tok, pos, done, key, counts), toks = jax.lax.scan(
+                body, (cache, tok, pos, done, key, counts0(paged)),
+                length=n_steps
             )
-            return jnp.moveaxis(toks, 0, 1), cache, tok, pos, done, key, held
+            return jnp.moveaxis(toks, 0, 1), cache, tok, pos, done, key, counts
 
         def segment_while_impl(n_steps, step, cache, tok, pos, done, key,
-                               active, stop_on_free, emit_tail):
+                               active, stop_on_free, emit_tail, paged):
             """Shared while-segment body (early exit).
 
             Same per-step math as the scan flavour (identical ``step``, so
@@ -660,13 +700,13 @@ class ServeEngine:
             out0 = jnp.full((n_slots, n_steps) + emit_tail, -1, jnp.int32)
 
             def cond(st):
-                i, _cache, _tok, _pos, done, _key, _out, _held = st
+                i, _cache, _tok, _pos, done, _key, _out, _counts = st
                 any_running = jnp.any(active & ~done)
                 freed = jnp.any(active & done)
                 return (i < n_steps) & any_running & ~(stop_on_free & freed)
 
             def loop_body(st):
-                i, cache, tok, pos, done, key, out, held = st
+                i, cache, tok, pos, done, key, out, total = st
                 cache, tok, pos, done, key, emitted, n = step(
                     cache, tok, pos, done, key
                 )
@@ -674,14 +714,16 @@ class ServeEngine:
                 out = jax.lax.dynamic_update_slice(
                     out, upd, (0, i) + (0,) * len(emit_tail)
                 )
-                return i + 1, cache, tok, pos, done, key, out, add_held(held, n)
+                return (i + 1, cache, tok, pos, done, key, out,
+                        add_counts(total, n))
 
             st = jax.lax.while_loop(
                 cond, loop_body,
-                (jnp.int32(0), cache, tok, pos, done, key, out0, held0),
+                (jnp.int32(0), cache, tok, pos, done, key, out0,
+                 counts0(paged)),
             )
-            _, cache, tok, pos, done, key, out, held = st
-            return out, cache, tok, pos, done, key, held
+            _, cache, tok, pos, done, key, out, counts = st
+            return out, cache, tok, pos, done, key, counts
 
         def _mk_segment(flavor, paged, spec):
             """Build one compiled segment program.
@@ -719,11 +761,11 @@ class ServeEngine:
                     emit_tail = ()
                 if scan:
                     return segment_scan_impl(n_steps, step, cache, tok, pos,
-                                             done, key)
+                                             done, key, paged)
                 stop_on_free = rest[0]
                 return segment_while_impl(n_steps, step, cache, tok, pos,
                                           done, key, active, stop_on_free,
-                                          emit_tail)
+                                          emit_tail, paged)
 
             return segment, name
 

@@ -441,6 +441,12 @@ class ContinuousScheduler:
             # launched, padding included (``engine.moe_rows_launched``)
             "moe_rows_held": 0,
             "moe_rows_computed": 0,
+            # paged decode attention: the KV blocks decode steps and verify
+            # windows read, summed over slots, steps and layers (counted on
+            # the device, fetched with the tokens), and what reading every
+            # slot's whole max_len would (slots × max blocks a step a layer)
+            "decode_kv_blocks_read": 0,
+            "decode_kv_blocks_span": 0,
         }
         self._phase = Phases(self.stats, clock)
 
@@ -1485,16 +1491,19 @@ class ContinuousScheduler:
                 seg_key += "_paged"
             seg_fn = getattr(eng, "_" + seg_key)
             (toks, self.cache, self.tok, self.pos, self.done, self.key,
-             held) = seg_fn(*args)
+             counts) = seg_fn(*args)
         eng.call_counts[seg_key] += 1
         with self._phase("segment_wait"):
             # the only per-segment download
-            toks, held = jax.device_get((toks, held))
+            toks, counts = jax.device_get((toks, counts))
             toks = np.asarray(toks)
         with self._phase("retire", "host_s_retire") as span:
             n_exec, n_live = self._retire(toks)
             if self.spec is None:  # a verify step is no one-token forward
-                self._count_rows(n_exec * self.n_slots, n_live, [held])
+                self._count_rows(n_exec * self.n_slots, n_live,
+                                 [counts.get("held")])
+            self.stats["decode_kv_blocks_read"] += int(counts.get("kv_read", 0))
+            self.stats["decode_kv_blocks_span"] += int(counts.get("kv_span", 0))
             span.set_metadata(steps=n_exec, live=n_live)
         return sum(r is not None for r in self.slots)
 

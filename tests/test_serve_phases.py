@@ -198,8 +198,10 @@ def test_named_scopes_in_compiled_slot_programs(engine):
     assert re.search(r"HloModule jit_segment\b", hlo)
     ops = set(re.findall(r'op_name="([^"]+)"', hlo))
     assert any("/slot_segment_while_paged/" in o for o in ops)
+    # decode reads each slot's blocks in place: the segment gathers nothing
     for scope in SCOPES:
-        assert any(f"/{scope}/" in o for o in ops), scope
+        assert any(f"/{scope}/" in o for o in ops) == (scope != "kv.gather"), \
+            scope
 
     pre = engine._prefill_slots_paged
     w, c = 2, 8

@@ -8,6 +8,8 @@ decode (M 4) and prefill (M 256) rows of bf16 activations.  Interpret mode
 cannot show what these show: Mosaic refuses unaligned blocks, gathers it
 cannot lower and vector loads of scalars.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -102,3 +104,43 @@ def test_kernel_entry_point_compiles_for_v5e(one_chip, name, m):
     compiled = fn.lower(*args).compile()  # raises what the chip would refuse
     assert "tpu_custom_call" in compiled.as_text()
     assert jax.eval_shape(fn, *args).shape == (m, N)
+
+
+@pytest.mark.parametrize("c", [1, 5], ids=["decode", "verify"])
+@pytest.mark.parametrize("layout", ["paged", "dense"])
+def test_paged_decode_attention_compiles_for_v5e(one_chip, layout, c):
+    """The decode attention kernel at internlm2-1.8b's widths (16 / 8 heads
+    of 128, 32 slots, max_len 2,048): over the stacked bf16 pool (24
+    layers, blocks of 16) and over the dense layout's rows.  The pool or
+    cache goes into the kernel as it is: no copy of it is made."""
+    from repro.kernels.paged_attention import (
+        decode_walk,
+        dense_decode_attention,
+        paged_decode_attention,
+    )
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    b, h, kh, dh, max_len = 32, 16, 8, 128, 2048
+    q = sds((b, c, h, dh), jnp.bfloat16)
+    pos = sds((b,), jnp.int32)
+    live = sds((b,), jnp.bool_)
+    if layout == "paged":
+        bl = 16
+        kv = sds((24, 2592, bl, kh, dh), jnp.bfloat16)
+        table = sds((b, max_len // bl), jnp.int32)
+
+        def fn(q, k, v, table, pos, live):
+            return paged_decode_attention(q, k, v, jnp.int32(3), table, pos,
+                                          decode_walk(pos, c, live, bl))
+
+        args = (q, kv, kv, table, pos, live)
+    else:
+        kv = sds((b, max_len, kh, dh), jnp.bfloat16)
+        fn, args = dense_decode_attention, (q, kv, kv, pos, live)
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    shape = ",".join(map(str, kv.shape))
+    assert not re.search(rf"= bf16\[{shape}\]\S* (copy|transpose)\(", hlo)
+    assert jax.eval_shape(fn, *args).shape == (b, c, h, dh)
