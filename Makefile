@@ -1,8 +1,8 @@
-# Tier-1 verification + CPU smoke benchmarks (mirrors .github/workflows/ci.yml)
+# Tier-1 verification (mirrors .github/workflows/ci.yml)
 
 PY ?= python
 
-.PHONY: test test-http lint bench-smoke bench perf-gate ci
+.PHONY: test test-http lint ci
 
 # tier-1: everything but the http-marked end-to-end serving shard (which
 # compiles a real engine per module and would slow the whole matrix)
@@ -17,16 +17,4 @@ lint:
 	ruff check .
 	$(PY) tools/check_links.py
 
-bench-smoke:
-	BENCH_REPEATS=1 PYTHONPATH=src $(PY) benchmarks/run.py --only kernel_traffic,serve_decode,serve_continuous,serve_paged,serve_prefill
-
-bench:
-	PYTHONPATH=src $(PY) benchmarks/run.py
-
-# regenerate the serving benches and compare against the committed baseline
-perf-gate:
-	cp BENCH_serve.json /tmp/BENCH_serve_baseline.json
-	BENCH_REPEATS=2 PYTHONPATH=src $(PY) benchmarks/run.py --only serve_decode,serve_continuous,serve_paged,serve_quant,serve_prefill,serve_energy,serve_http,serve_slo
-	$(PY) benchmarks/perf_gate.py --baseline /tmp/BENCH_serve_baseline.json --new BENCH_serve.json
-
-ci: test bench-smoke
+ci: test

@@ -11,7 +11,7 @@ Usage (CPU smoke):
         --reduced --kv-layout paged --port 8777
     # multi-tenant: weighted DRR shares + a rate-limited batch tenant
     PYTHONPATH=src python -m repro.launch.http_serve --arch tinyllama-1.1b \
-        --reduced --tenant acme:3 --tenant hobby:1:0.5:batch --trace
+        --reduced --tenant acme:3 --tenant hobby:1:0.5:batch
     # self-test: serve, drive N seeded in-process clients, print a
     # summary, drain, and exit nonzero on any mismatch
     PYTHONPATH=src python -m repro.launch.http_serve --arch tinyllama-1.1b \
@@ -153,9 +153,6 @@ def main() -> None:
     ap.add_argument("--overcommit", type=float, default=1.0)
     ap.add_argument("--preempt-mode", default="recompute",
                     choices=("recompute", "swap"))
-    ap.add_argument("--trace", action="store_true",
-                    help="per-segment trace + per-tenant tok/s and J/token "
-                         "in GET /v1/stats")
     # multi-tenant policy
     ap.add_argument("--tenant", action="append", default=[],
                     metavar="NAME[:WEIGHT[:RATE[:PRIORITY]]]",
@@ -202,7 +199,7 @@ def main() -> None:
 
     params = arch.init_params(jax.random.PRNGKey(args.seed))
     sc = ServeConfig(max_len=max_len, kv_layout=args.kv_layout,
-                     block_len=args.block_len, trace=args.trace)
+                     block_len=args.block_len)
     eng = ServeEngine(arch, params, MeshPlan(), sc)
     sched = ContinuousScheduler(
         eng, n_slots=args.slots, segment_len=args.segment_len,

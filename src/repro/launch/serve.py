@@ -23,9 +23,9 @@ Usage (CPU smoke):
     PYTHONPATH=src python -m repro.launch.serve --arch tinyllama-1.1b \
         --reduced --workload poisson --kv-layout paged --n-blocks 20 \
         --overcommit 2.0 --deadline 30 --chaos-slot-fail-prob 0.1
-    # trace the run + energy-per-token report, with autotuned knobs:
+    # autotuned knobs (every poisson run ends with its energy report):
     PYTHONPATH=src python -m repro.launch.serve --arch tinyllama-1.1b \
-        --reduced --workload poisson --trace --autotune
+        --reduced --workload poisson --autotune
     # fully quantized serving: int8 block-sparse weights + int8 KV cache
     # (chunked prefill and speculation both run first-class, ISSUE 10):
     PYTHONPATH=src python -m repro.launch.serve --arch tinyllama-1.1b \
@@ -46,6 +46,8 @@ from repro.configs.base import ALL_ARCH_IDS
 from repro.models.registry import get_arch
 from repro.roofline.hw import device_peaks
 from repro.serve import ContinuousScheduler, ServeConfig, ServeEngine, SubmitRequest
+from repro.serve.trace import (ACT_SPARSITY, WEIGHT_SPARSITY, spec_accept_len,
+                               token_prices, trace_energy)
 from repro.sharding.mesh import MeshPlan
 from repro.utils.compile_cache import enable_compile_cache
 from repro.utils.logging import get_logger
@@ -132,10 +134,8 @@ def _run_poisson(eng: ServeEngine, args, draws=None):
         if sched.has_work():
             running = sched.run_segment()
             st = sched.stats
-            spec_note = ""
-            if sched.spec is not None and st["spec_steps"]:
-                spec_note = (f" accepted={st['spec_emitted'] / st['spec_steps']:.2f}"
-                             f"tok/step")
+            acc = spec_accept_len(st)
+            spec_note = "" if acc is None else f" accepted={acc:.2f}tok/step"
             log.info("segment %-3d running=%d queued=%d admitted=%d retired=%d "
                      "steps=%d%s", st["segments"], running, len(sched.queue),
                      st["admitted"], st["retired"], st["steps_total"],
@@ -200,38 +200,24 @@ def _run_poisson(eng: ServeEngine, args, draws=None):
                  st["chaos_cancels"], st["chaos_slot_failures"])
     if sched.spec is not None:
         hist = st["accepted_hist"]
-        total_steps = sum(hist.values())
-        mean_acc = (sum(n * c for n, c in hist.items()) / total_steps
-                    if total_steps else 0.0)
         bars = " ".join(f"{n}tok:{hist[n]}" for n in sorted(hist))
         log.info("speculative decode: k=%d draft=%s — %d draft-and-verify "
                  "slot-steps, mean accepted length %.2f tok/step, "
-                 "acceptance histogram [%s]",
-                 sched.spec.k, sched.spec.draft, total_steps, mean_acc, bars)
+                 "acceptance histogram [%s]", sched.spec.k, sched.spec.draft,
+                 st["spec_steps"], spec_accept_len(st) or 0.0, bars)
     elif st["spec_skip_reason"]:
         log.info("speculative decode disabled: %s", st["spec_skip_reason"])
-    if sched.trace is not None:
-        from repro.serve.trace import trace_energy
-
-        tr = sched.trace.totals
-        log.info("trace: %d prefill + %d decode + %d spec tokens over %d "
-                 "launches", tr["prefill_tokens"], tr["decode_tokens"],
-                 tr["spec_tokens"], len(sched.trace.events))
-        rep = trace_energy(sched.trace, eng.cfg,
-                           weight_sparsity=TRACE_WEIGHT_SPARSITY,
-                           act_sparsity=TRACE_ACT_SPARSITY,
-                           platforms=("SONIC", "NullHop", "NP100"))
-        for name, r in rep["platforms"].items():
-            log.info("energy [%-7s] %.3e J/token (%.3g J over the trace), "
-                     "%.1f tok/s/W at %.2f W", name, r["j_per_token"],
-                     r["trace_energy_j"], r["tok_per_s_per_w"], r["power_w"])
+    rep = trace_energy(st, token_prices(
+        eng.cfg, WEIGHT_SPARSITY, ACT_SPARSITY,
+        platforms=("SONIC", "NullHop", "NP100")))
+    log.info("useful tokens: %d (%d prompt + %d decode + %d speculative)",
+             rep["tokens"], st["prefill_tokens_real"],
+             st["slot_steps_live"] - st["spec_steps"], st["spec_emitted"])
+    for name, r in rep["platforms"].items():
+        log.info("energy [%-7s] %.3e J/token (%.3g J over the run), "
+                 "%.1f tok/s/W at %.2f W", name, r["j_per_token"],
+                 r["trace_energy_j"], r["tok_per_s_per_w"], r["power_w"])
     return useful, total, sched
-
-
-# sparsity assumptions for the --trace energy report, matching the
-# serve_energy bench (see docs/energy_model.md for what they mean)
-TRACE_WEIGHT_SPARSITY = 0.75
-TRACE_ACT_SPARSITY = 0.5
 
 
 def main() -> None:
@@ -334,10 +320,6 @@ def main() -> None:
                     help="block-prune the served weights to this sparsity "
                          "before int8 quantization (pruned blocks are "
                          "skipped entirely; requires --weight-quant int8)")
-    ap.add_argument("--trace", action="store_true",
-                    help="record per-segment phase traces (host-side "
-                         "counters priced through the analytic roofline) "
-                         "and print an energy-per-token report at the end")
     ap.add_argument("--autotune", action="store_true",
                     help="pick segment_len/prefill_chunk/block_len/spec_k "
                          "from the analytic autotuner before serving "
@@ -381,9 +363,6 @@ def main() -> None:
     if args.spec_k and args.temperature > 0:
         raise SystemExit("speculative decoding is greedy-only: --spec-k "
                          "needs --temperature 0")
-    if args.trace and args.workload != "poisson":
-        raise SystemExit("--trace only applies to the slot scheduler: pass "
-                         "--workload poisson")
     if args.autotune and args.workload != "poisson":
         raise SystemExit("--autotune only applies to the slot scheduler: "
                          "pass --workload poisson")
@@ -425,15 +404,12 @@ def main() -> None:
         if args.kv_layout == "paged":
             args.block_len = best.block_len
         if args.spec_k and best.spec_k == 0:
-            if args.trace:
-                # at the assumed acceptance of 1.0 speculation never pays;
-                # keep it on so the trace measures the real acceptance and
-                # the post-run re-rank can judge it on real numbers
-                log.info("autotune ranked spec_k=0 at assumed acceptance "
-                         "1.0 — keeping --spec-k %d under --trace to "
-                         "measure the real acceptance", args.spec_k)
-            else:
-                args.spec_k = 0  # the model says speculation doesn't pay
+            # at the assumed acceptance of 1.0 speculation never pays; keep
+            # it on so the run measures the real acceptance and the
+            # post-run re-rank can judge it on real numbers
+            log.info("autotune ranked spec_k=0 at assumed acceptance 1.0 — "
+                     "keeping --spec-k %d to measure the real acceptance",
+                     args.spec_k)
         log.info("autotune pick: %s (segment_len=%d prefill_chunk=%d "
                  "prefill_buckets=%d block_len=%d spec_k=%d) — predicted "
                  "%.1f tok/s in model units", best.label(), best.segment_len,
@@ -465,7 +441,6 @@ def main() -> None:
         kv_layout=args.kv_layout,
         block_len=args.block_len,
         spec=spec,
-        trace=args.trace,
         weight_quant=args.weight_quant,
         weight_quant_sparsity=args.weight_quant_sparsity,
     )
@@ -478,9 +453,8 @@ def main() -> None:
                      useful / total if total > 0 else 0.0)
         # close the PR 7 loop: re-rank with the acceptance length this run
         # actually measured, so speculation competes on real numbers
-        if (args.autotune and sched.trace is not None
-                and sched.spec is not None):
-            acc = sched.trace.spec_accept_len()
+        if args.autotune and sched.spec is not None:
+            acc = spec_accept_len(sched.stats)
             if acc is not None:
                 from repro.roofline.autotune import WorkloadSpec, autotune
 

@@ -2,9 +2,10 @@
 
 The paper gives layer counts and total parameters but not exact layer dims;
 channel/hidden sizes below are chosen to land close to Table 1's parameter
-counts (reported side-by-side by ``benchmarks/paper_tables.py``).  All convs
-are 3×3/same with ReLU + 2×2 maxpool per stage (ReLU matters: it is what
-creates the activation sparsity SONIC's dataflow compression exploits).
+counts (reported side-by-side by ``examples/photonic_paper_repro.py``).
+All convs are 3×3/same with ReLU + 2×2 maxpool per stage (ReLU matters: it
+is what creates the activation sparsity SONIC's dataflow compression
+exploits).
 
 These models exercise the CONV dataflow path (im2col + column compression,
 paper §III.C) and are the workloads priced by the photonic simulator.

@@ -20,7 +20,6 @@ from __future__ import annotations
 
 import dataclasses
 
-import numpy as np
 
 from repro.configs.base import ModelConfig, ShapeSpec
 from repro.roofline.hw import HWTarget
@@ -110,7 +109,7 @@ def analytic_cost(
     for bf16 weights, ~1.01·(1 − sparsity) for the int8 block-sparse serving
     format (ISSUE 10) — int8 values + one fp32 scale and one int32 index per
     kept block, with pruned blocks never leaving HBM (fold the density in at
-    the caller; ``serve/trace.py`` does)."""
+    the caller; ``launch/serve.py`` does)."""
     n_active, n_total = _param_counts(cfg)
     b, s = shape.global_batch, shape.seq_len
     kind = shape.kind
@@ -181,9 +180,8 @@ def analytic_cost(
 # These price what the serving programs in serve/engine.py EXECUTE, not what
 # is useful: a decode segment attends the full max_len row every step and
 # runs all n_slots rows (masked ones included), a chunked-prefill launch is
-# padded to a power-of-two width.  The trace recorder (serve/trace.py) and
-# the knob autotuner (roofline/autotune.py) both price work through these,
-# so their flops/bytes columns are directly comparable.
+# padded to a power-of-two width.  The knob autotuner (roofline/autotune.py)
+# prices work through these.
 # ---------------------------------------------------------------------------
 
 

@@ -11,18 +11,18 @@ by predicted useful tok/s.
 
 The prediction's absolute scale is in model units (its device times are
 the ``hw`` target's, not the machine you measure on); only the RANKING is
-claimed, and the ``serve_energy`` bench gates it: the autotuner's pick
-must achieve >= 0.9x of the best measured candidate's tok/s.
+claimed, and nothing measures it yet: no run holds the pick against the
+measured candidates.
 
 Speculative decoding note: with ``spec_k > 0`` the model prices every step
 as a full draft-and-verify round but credits only ``spec_accept_len``
 emissions per step, defaulting to 1.0 — the acceptance rate is a property
 of the model/workload the analytic layer cannot know, so speculation is
 never recommended unless the caller feeds a measured acceptance length.
-The serving trace measures exactly that: pass
-``TraceRecorder.spec_accept_len()`` from a traced run (ISSUE 10 closed the
-PR 7 loop — ``launch/serve.py --autotune`` with ``--trace`` and a spec run
-re-ranks with the measured value).
+The scheduler's ``stats`` measure exactly that: pass
+``serve.trace.spec_accept_len(sched.stats)`` from a speculative run
+(``launch/serve.py --autotune`` with ``--spec-k`` re-ranks with the
+measured value after the run).
 """
 from __future__ import annotations
 

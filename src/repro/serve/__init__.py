@@ -7,8 +7,8 @@ from repro.serve.policy import (Overloaded, PriorityClass, RateLimited,
 from repro.serve.request import Request, SubmitRequest
 from repro.serve.sampling import sample_token, spec_accept
 from repro.serve.scheduler import BlockAllocator, ContinuousScheduler
-from repro.serve.trace import (PhaseRecord, TraceRecorder, tenant_report,
-                               trace_energy)
+from repro.serve.trace import (spec_accept_len, tenant_report, token_prices,
+                               trace_energy, useful_tokens)
 
 __all__ = [
     "BlockAllocator",
@@ -17,7 +17,6 @@ __all__ = [
     "FrontDoor",
     "HttpConfig",
     "Overloaded",
-    "PhaseRecord",
     "PriorityClass",
     "RateLimited",
     "Request",
@@ -29,9 +28,11 @@ __all__ = [
     "SubmitRequest",
     "TenantPolicy",
     "TenantSpec",
-    "TraceRecorder",
     "sample_token",
     "spec_accept",
+    "spec_accept_len",
     "tenant_report",
+    "token_prices",
     "trace_energy",
+    "useful_tokens",
 ]

@@ -16,9 +16,8 @@ Execution paths (``ServeConfig.loop``):
             equivalent to "scan"; pays a dynamic trip count for the early
             exit.
   "python"  the legacy host loop (one jitted decode step per token,
-            host-side sampling / key splits).  Kept as the baseline the
-            ``serve_decode`` benchmark and the equivalence tests compare
-            against.
+            host-side sampling / key splits).  Kept only as the oracle the
+            equivalence tests hold the compiled loops to.
 
 Decode kernel dispatch: when serving SONIC-converted weights
 (``core.sonic_layers`` mode "sonic"), ``sonic_matmul`` routes activations
@@ -169,11 +168,6 @@ class ServeConfig:
     # the end of every segment (PR 6) — on by default in the stress suites,
     # off in production paths (it walks host dicts, never the device)
     debug_invariants: bool = False
-    # per-segment trace recorder (serve/trace.py): opt-in host-side counters
-    # priced through roofline/analytic.py for the energy/perf-per-watt
-    # accounting.  False keeps the zero-overhead path — the scheduler never
-    # allocates a recorder and every hook site is one ``is None`` check.
-    trace: bool = False
 
 
 def _scoped(name: str, fn):
@@ -312,7 +306,7 @@ class ServeEngine:
         }
         # cache-contract checks run once per engine, not per scheduler: the
         # paged check eval_shape-traces a full forward, which would otherwise
-        # tax every scheduler construction (visible in serve_paged timings)
+        # tax every scheduler construction
         self._checked_contracts: set[str] = set()
 
         def sample(logits, key):

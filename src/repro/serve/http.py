@@ -26,8 +26,8 @@ Endpoints:
 ``GET /v1/stats``
     Scheduler stats, per-tenant policy counters, the SLO controller's
     state when one is configured (per-class observed TTFT p50/p99,
-    brownout level, shed + preemption counters per class), and (when
-    tracing) the per-tenant priced tok/s + J/token report.
+    brownout level, shed + preemption counters per class), and the
+    per-tenant priced tok/s + J/token report (``tenant_pricing``).
 
 Threading model: the scheduler (JAX programs, host bookkeeping) runs in ONE
 dedicated worker thread (:class:`SchedulerWorker`); the event loop never
@@ -57,8 +57,8 @@ streams complete), then join the thread; past ``drain_timeout_s`` the
 remaining requests are cancelled instead.
 
 The module also ships the minimal asyncio client (``open_generate`` /
-``read_sse_event`` / ``generate``) used by the tests, the load-generator
-bench, and ``tools/serve_client.py``.
+``read_sse_event`` / ``generate``) used by the tests and
+``tools/serve_client.py``.
 """
 from __future__ import annotations
 
@@ -72,6 +72,8 @@ import time
 
 from repro.serve.request import Request, SubmitRequest
 from repro.serve.policy import Overloaded, RateLimited
+from repro.serve.trace import (ACT_SPARSITY, WEIGHT_SPARSITY, tenant_report,
+                               token_prices, trace_energy)
 from repro.utils.logging import get_logger
 
 log = get_logger("serve.http")
@@ -278,12 +280,16 @@ class SchedulerWorker:
 class FrontDoor:
     """The asyncio HTTP server bridging connections to the scheduler
     worker.  Duck-typed over the scheduler: anything exposing ``submit`` /
-    ``run_segment`` / ``has_work`` / ``queue`` / ``stats`` works (the test
-    suite drives it with a JAX-free stub)."""
+    ``run_segment`` / ``has_work`` / ``queue`` / ``stats`` / ``engine.cfg``
+    works (the test suite drives it with a JAX-free stub)."""
 
     def __init__(self, sched, cfg: HttpConfig | None = None):
         self.sched = sched
         self.cfg = cfg or HttpConfig()
+        # J/token depends only on the model and the assumed sparsities, so
+        # it is priced once; /v1/stats scales it by the scheduler's tokens
+        self._prices = token_prices(sched.engine.cfg, WEIGHT_SPARSITY,
+                                    ACT_SPARSITY, platforms=("SONIC",))
         self.worker: SchedulerWorker | None = None
         self.draining = False
         self.port: int | None = None
@@ -414,14 +420,10 @@ class FrontDoor:
                 slo["preemptions_by_class"] = dict(
                     self.sched.stats.get("preemptions_by_class", {}))
                 out["slo"] = slo
-        trace = getattr(self.sched, "trace", None)
-        if trace is not None:
-            from repro.serve.trace import tenant_report, trace_energy
-
-            wall = time.perf_counter() - self._t0
-            energy = trace_energy(trace, weight_sparsity=0.75,
-                                  act_sparsity=0.5, platforms=("SONIC",))
-            out["tenant_pricing"] = tenant_report(trace, energy, wall_s=wall)
+        st = self.sched.stats
+        out["tenant_pricing"] = tenant_report(
+            st, trace_energy(st, self._prices),
+            wall_s=time.perf_counter() - self._t0)
         return out
 
     # ------------------------------------------------------------- generate

@@ -360,7 +360,7 @@ class TenantPolicy:
         self._visit: dict[int, str] = {}
         # token buckets: tenant -> [tokens, last_refill_t]
         self._bucket: dict[str, list[float]] = {}
-        # per-tenant counters (surfaced through stats + TraceRecorder)
+        # per-tenant counters (surfaced through snapshot() in /v1/stats)
         self.submitted: dict[str, int] = {}
         self.admitted: dict[str, int] = {}
         self.served_tokens: dict[str, int] = {}

@@ -109,7 +109,7 @@ from repro.serve.engine import ServeEngine
 from repro.serve.policy import Overloaded, RateLimited, TenantPolicy
 from repro.serve.request import (CANCELLED, EXPIRED, FINISHED, QUEUED,
                                  RUNNING, Request, SubmitRequest)
-from repro.serve.trace import Phases, TraceRecorder
+from repro.serve.trace import Phases
 from repro.utils.logging import get_logger
 
 log = get_logger("serve.scheduler")
@@ -380,7 +380,7 @@ class ContinuousScheduler:
             "admissions_per_slot": [0] * n_slots,
             "admit_deferred": 0,
             "blocks_in_use_peak": 0,
-            # batched/chunked admission accounting (serve_prefill bench)
+            # batched/chunked admission accounting
             "admit_rounds": 0,
             "prefill_launches": 0,
             "chunks_prefilled": 0,
@@ -411,8 +411,8 @@ class ContinuousScheduler:
             "chaos_cancels": 0,
             "chaos_slot_failures": 0,
             # multi-tenant accounting (PR 8): emitted tokens per tenant
-            # label ("default" without a policy) — the billing basis the
-            # trace layer prices into per-tenant J/token
+            # label ("default" without a policy) — the billing basis
+            # serve/trace.py ``tenant_report`` prices into per-tenant J/token
             "tenant_tokens": {},
             # SLO feedback (PR 9): evictions per priority class (the
             # batch-first victim policy's audit trail) and brownout ladder
@@ -449,10 +449,6 @@ class ContinuousScheduler:
             "decode_kv_blocks_span": 0,
         }
         self._phase = Phases(self.stats, clock)
-
-        # opt-in per-segment trace recorder (ServeConfig.trace, ISSUE 7);
-        # None keeps every hook site to a single attribute check
-        self.trace = TraceRecorder(engine) if engine.sc.trace else None
 
     # -------------------------------------------------------------- paged
 
@@ -580,13 +576,6 @@ class ContinuousScheduler:
                 and slot not in self._prefill_start
                 and slot not in self._replay):
             self._swap_out(slot, req)
-        if self.trace is not None:
-            swapped = 0
-            if req._swap is not None:
-                swapped = sum(x.nbytes for x in
-                              jax.tree_util.tree_leaves(req._swap))
-            self.trace.record_preempt(self.stats["segments"],
-                                      len(req.tokens), swapped)
         self._vacate_slot(slot)
         req.state = QUEUED
         req.preempts += 1
@@ -704,10 +693,6 @@ class ContinuousScheduler:
         self.done = self.done.at[slot].set(False)
         self.active[slot] = True
         self.limit[slot] = req.prompt_len + req.max_new_tokens - 1
-        if self.trace is not None:
-            self.trace.record_swap_in(
-                self.stats["segments"],
-                sum(x.nbytes for x in jax.tree_util.tree_leaves(req._swap)))
         req._swap = None
         req._swap_nb = 0
         self.stats["swap_ins"] += 1
@@ -808,8 +793,6 @@ class ContinuousScheduler:
         tt[req.tenant] = tt.get(req.tenant, 0) + 1
         if self.policy is not None:
             self.policy.note_tokens(req.tenant)
-        if self.trace is not None:
-            self.trace.note_tenant_tokens(req.tenant)
 
     def _note_emission_after_readmit(self, req: Request, now: float) -> None:
         """First emission after a readmission closes the preemption gap —
@@ -1186,9 +1169,6 @@ class ContinuousScheduler:
             round_tokens[1] += real_tokens
             hist = self.stats["prefill_batch_hist"]
             hist[len(rows)] = hist.get(len(rows), 0) + 1
-            if self.trace is not None:
-                self.trace.record_prefill(self.stats["segments"], width,
-                                          bucket, real_tokens)
         # the ONLY admit-round download: every launch's first tokens (and
         # held-expert counts) at once
         with self._phase("prefill_wait"):
@@ -1305,8 +1285,6 @@ class ContinuousScheduler:
                 self.stats["prefill_tokens_real"] += n
                 self.stats["prefill_tokens_launched"] += n
                 n_tokens += n
-                if self.trace is not None:
-                    self.trace.record_prefill(self.stats["segments"], 1, n, n)
                 resumed = bool(req.tokens)
                 pending.append((req, slot, first, resumed, held))
                 if resumed:
@@ -1370,7 +1348,7 @@ class ContinuousScheduler:
         """One brownout-controller step per segment: feed the monitor the
         target class's CURRENT waiting ages (queued or claimed, no first
         token yet) so the ladder reacts to a building queue before the
-        damage shows up in completed TTFTs, and trace the transition."""
+        damage shows up in completed TTFTs, and count the transition."""
         if self.policy is None or self.policy.slo is None:
             return
         now = self.clock()
@@ -1387,8 +1365,6 @@ class ContinuousScheduler:
             log.debug("brownout level -> %d (ttft q=%.3fs deadline=%.3fs)",
                       new_level, self.policy.slo.last_quantile or 0.0,
                       self.policy.slo.deadline)
-            if self.trace is not None:
-                self.trace.record_brownout(self.stats["segments"], new_level)
 
     def queue_composition(self) -> tuple[list[int], list[int]]:
         """Remaining work as (prompt_lens, new_tokens) pairs for the drain
@@ -1536,10 +1512,6 @@ class ContinuousScheduler:
             for n, c in zip(*np.unique(per_step[live_step], return_counts=True)):
                 hist[int(n)] = hist.get(int(n), 0) + int(c)
             live_counts = live_step.sum(axis=1)  # live steps per slot
-            if self.trace is not None:
-                self.trace.record_spec(
-                    self.stats["segments"], self.n_slots, n_exec,
-                    int(live_step.sum()), int(per_step[live_step].sum()))
             toks = toks.reshape(toks.shape[0], -1)
         else:
             # every executed step has ≥1 live emission (while-mode exits
@@ -1547,9 +1519,6 @@ class ContinuousScheduler:
             n_exec = (int((toks >= 0).any(axis=0).sum())
                       if self.segment_mode == "while" else self.segment_len)
             live_counts = (toks >= 0).sum(axis=1)
-            if self.trace is not None:
-                self.trace.record_decode(self.stats["segments"], self.n_slots,
-                                         n_exec, int(live_counts.sum()))
         self.stats["steps_total"] += n_exec
         eos = eng.sc.eos_token
         now = self.clock()

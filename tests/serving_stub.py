@@ -1,12 +1,13 @@
 """A JAX-free stand-in for ``ContinuousScheduler`` driving the HTTP tests.
 
 The front door is duck-typed over the scheduler (``submit`` /
-``run_segment`` / ``has_work`` / ``queue`` / ``slots`` / ``stats``), so the
-HTTP conformance suite runs against this stub with no model compile: real
-``Request`` handles, a real ``BlockAllocator`` (so block-reclaim assertions
-are exact), real ``TenantPolicy`` integration, deterministic token
-emission (token *i* of a request is a pure function of its prompt), and a
-tunable per-segment delay to make heartbeat/backpressure timing testable.
+``run_segment`` / ``has_work`` / ``queue`` / ``slots`` / ``stats`` /
+``engine.cfg``), so the HTTP conformance suite runs against this stub with
+no model compile: real ``Request`` handles, a real ``BlockAllocator`` (so
+block-reclaim assertions are exact), real ``TenantPolicy`` integration,
+deterministic token emission (token *i* of a request is a pure function of
+its prompt), and a tunable per-segment delay to make heartbeat/backpressure
+timing testable.
 
 Not collected by pytest (no ``test_`` prefix) — imported by
 ``test_serve_http.py``.
@@ -15,9 +16,11 @@ from __future__ import annotations
 
 import collections
 import time
+from types import SimpleNamespace
 
 import numpy as np
 
+from repro.configs.base import reduced_config
 from repro.serve.policy import Overloaded, RateLimited
 from repro.serve.request import (CANCELLED, EXPIRED, FINISHED, RUNNING,
                                  Request, SubmitRequest)
@@ -49,7 +52,8 @@ class StubScheduler:
         self.eos_id = eos_id
         self.policy = policy
         self.clock = clock
-        self.trace = None
+        # the model the front door prices energy for (nothing runs it)
+        self.engine = SimpleNamespace(cfg=reduced_config("tinyllama-1.1b"))
         self.spec_k = 0
         self.allocator = BlockAllocator(n_blocks)
         self.queue: collections.deque[Request] = collections.deque()
@@ -63,6 +67,9 @@ class StubScheduler:
             "cancelled": 0, "expired": 0,
             "blocks_reclaimed_cancel": 0,
             "tenant_tokens": {},
+            # the useful-token counters the energy report reads
+            "prefill_tokens_real": 0, "slot_steps_live": 0,
+            "spec_steps": 0, "spec_emitted": 0,
         }
 
     # -- submit ------------------------------------------------------------
@@ -181,6 +188,7 @@ class StubScheduler:
             req.state = RUNNING
             self.slots[slot] = req
             self.stats["admitted"] += 1
+            self.stats["prefill_tokens_real"] += req.prompt_len
             self._emit(req)  # the prefill-sampled first token
 
     def run_segment(self) -> int:
@@ -205,6 +213,7 @@ class StubScheduler:
                 if len(req.tokens) >= req.max_new_tokens:
                     break
                 self._emit(req)
+                self.stats["slot_steps_live"] += 1
                 emitted += 1
                 if self.eos_id is not None and req.tokens[-1] == self.eos_id:
                     break

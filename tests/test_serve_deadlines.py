@@ -11,7 +11,9 @@ overcommit machinery:
   segment (forced pool exhaustion);
 * priority-aware victim selection — with a ``TenantPolicy`` installed,
   pool exhaustion evicts batch before interactive even when interactive
-  has less progress.
+  has less progress;
+* SLO control — an interactive request waiting past its class deadline
+  raises the brownout ladder, which sheds the next batch submission.
 
 Every case asserts blocks are reclaimed and the allocator invariants hold
 (``debug_invariants`` also checks them after every segment), and that
@@ -23,8 +25,9 @@ import numpy as np
 import pytest
 
 from repro.models.registry import get_arch
-from repro.serve import (ChaosConfig, ContinuousScheduler, PriorityClass,
-                         ServeConfig, ServeEngine, TenantPolicy, TenantSpec)
+from repro.serve import (ChaosConfig, ContinuousScheduler, Overloaded,
+                         PriorityClass, ServeConfig, ServeEngine, SloConfig,
+                         TenantPolicy, TenantSpec)
 from repro.sharding.mesh import MeshPlan
 
 PLAN = MeshPlan()
@@ -236,3 +239,36 @@ def test_pool_exhaustion_evicts_batch_before_interactive(engines):
     for h, w in zip((ha, hc, hb), want):
         assert h.done and h.tokens == w, h.rid
     assert sched.allocator.n_free == sched.allocator.capacity
+
+
+def test_brownout_sheds_batch_while_interactive_waits(engines):
+    """SLO control disrupts batch work: an interactive request queued for
+    twice its class's TTFT deadline steps the brownout ladder to its top
+    level at the next segment, and the next batch submission is shed; the
+    requests already in stay bit-identical to the oracle."""
+    t = {"now": 0.0}
+    policy = TenantPolicy(
+        tenants={"it": TenantSpec(default_priority="interactive"),
+                 "bt": TenantSpec(default_priority="batch")},
+        classes=(PriorityClass("interactive", level=2, ttft_deadline_s=1.0),
+                 PriorityClass("standard", level=1),
+                 PriorityClass("batch", level=0)),
+        slo=SloConfig(min_obs=1))
+    sched = ContinuousScheduler(engines["paged"], n_slots=1, segment_len=4,
+                                n_blocks=16, policy=policy,
+                                clock=lambda: t["now"])
+    prompts, news = [_prompt(900, 6), _prompt(901, 6)], [16, 8]
+    want = _oracle(engines, prompts, news)
+    hb = sched.submit(prompts[0], news[0], tenant="bt")
+    sched.run_segment()  # the batch request takes the only slot
+    # its own deadline is far off: it waits, it does not expire
+    hi = sched.submit(prompts[1], news[1], tenant="it", ttft_deadline_s=100.0)
+    t["now"] = 2.0
+    sched.run_segment()
+    assert policy.brownout_level == 3
+    assert sched.stats["brownout_changes"] >= 1
+    with pytest.raises(Overloaded):
+        sched.submit(_prompt(902, 6), 8, tenant="bt")
+    assert policy.slo.shed == {"batch": 1}
+    _drain(sched)
+    assert hb.tokens == want[0] and hi.tokens == want[1]

@@ -325,6 +325,10 @@ def test_health_and_stats_endpoints():
     assert body["front_door"]["accepted"] == 1
     assert body["scheduler"]["tenant_tokens"]["a"] == 4
     assert body["tenants"]["a"]["served_tokens"] == 4
+    # priced from the scheduler's counters on every call
+    pricing = body["tenant_pricing"]["a"]
+    assert pricing["tokens"] == 4 and pricing["share"] == 1.0
+    assert pricing["j_per_token"] > 0 and pricing["tok_s"] > 0
     assert body["tenants"]["a"]["weight"] == 2.0
 
 

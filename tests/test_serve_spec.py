@@ -16,6 +16,7 @@ import pytest
 from repro.models.registry import get_arch
 from repro.serve import (
     ContinuousScheduler, ServeConfig, ServeEngine, SpecConfig, spec_accept,
+    spec_accept_len,
 )
 from repro.sharding.mesh import MeshPlan
 
@@ -104,6 +105,8 @@ def test_spec_exact_drafter_accepts_everything(arch_params, workload, baseline):
     # full-window emissions dominate; every sub-window step must be
     # explained by a budget edge (one per request at most) — not rejection
     assert hist.get(3, 0) >= sum(c for n, c in hist.items() if n < 3)
+    # the mean accepted length the autotuner prices speculation with
+    assert spec_accept_len(sched.stats) >= 1.5
 
 
 def test_spec_sparse_self_drafter_bit_identical(arch_params, workload, baseline):
