@@ -292,11 +292,31 @@ def moe_load_balance_loss(p: Params, cfg: ModelConfig, x: jax.Array) -> jax.Arra
 # plus the shared experts.  What the absent experts add is left to the
 # devices that hold them; on one device there is no exchange at all.
 #
-# Nothing drops: every (token, held expert) assignment is computed.  The
-# T·k assignments are sorted by held expert (the others last) into one
-# buffer that grouped matmuls (``jax.lax.ragged_dot``) run over, and each
-# token's rows combine in a fixed order, so a token's output does not
-# depend on what else is in the launch.
+# Nothing drops: every (token, held expert) assignment is computed, in one
+# of two forms chosen from the launch's token count T = B·S, which is
+# static.  At or below ``ALL_HELD_MAX_TOKENS`` every token goes through
+# every held expert in one batched contraction (``_held_rows_all``): each
+# layer's stacked ``wi``/``wg``/``wo`` slice is read once, where it lies,
+# and the choices are picked out of the result.  Above it the T·k
+# assignments are sorted by held expert (the others last) into one buffer
+# that grouped matmuls (``jax.lax.ragged_dot``) run over
+# (``_held_rows_grouped``); those take their weights whole, so a layer scan
+# first copies each slice out of the stack.  Either way each token's rows
+# combine in a fixed choice order, so within one form a token's output
+# does not depend on what else is in the launch; across the bound the two
+# forms may differ by rounding.
+
+# The all-held form does 2·T FLOPs per bf16 weight (2 bytes): T FLOPs per
+# weight byte.  Up to the chip's ridge (v5e: 197 TFLOP/s / 819 GB/s, about
+# 240) it is bound by the weight read, which the grouped form pays too;
+# 128 leaves a margin.
+ALL_HELD_MAX_TOKENS = 128
+
+
+def takes_all_held(n_tokens: int) -> bool:
+    """Whether a launch of ``n_tokens`` tokens (B·S) computes its held
+    experts in the all-held form."""
+    return n_tokens <= ALL_HELD_MAX_TOKENS
 
 
 def moe_held_init(key, cfg: ModelConfig) -> Params:
@@ -330,9 +350,10 @@ def route_sigmoid_bias(p: Params, cfg: ModelConfig,
 
 
 def held_rows_launched(cfg: ModelConfig, n_tokens: int) -> int:
-    """Rows the held-expert buffers of one forward over ``n_tokens`` tokens
-    hold, over every MoE layer: each token's k assignments, padding and
-    experts held elsewhere included (what ``moe_held_apply`` launches)."""
+    """Assignment rows of one forward over ``n_tokens`` tokens, over every
+    MoE layer: each token's k assignments, padding and experts held
+    elsewhere included (the grouped form's buffer; the all-held form
+    computes every held expert's row of each token instead)."""
     return (cfg.n_layers - cfg.first_dense_layers) * n_tokens * cfg.experts_per_token
 
 
@@ -347,29 +368,21 @@ def moe_held_apply(
     ``held rows`` counts the (token, held expert) assignments of the tokens
     ``count_mask`` marks (None without a mask)."""
     b, s, d = x.shape
-    t, k, e = b * s, cfg.experts_per_token, cfg.n_experts_held
+    t = b * s
     xt = x.reshape(t, d)
     with jax.named_scope("moe.route"):
         w, experts = route_sigmoid_bias(p, cfg, xt)
         local = experts - cfg.expert_offset
-        held = (local >= 0) & (local < e)
-        group = jnp.where(held, local, e).reshape(-1)  # elsewhere → last
-        order = jnp.argsort(group, stable=True)
-        sizes = jnp.bincount(group, length=e + 1)[:e].astype(jnp.int32)
-        rows = xt[order // k]  # (T·k, d), grouped by held expert
+        held = (local >= 0) & (local < cfg.n_experts_held)
+    form = _held_rows_all if takes_all_held(t) else _held_rows_grouped
+    y = form(p, xt, local, held)
     with jax.named_scope("moe.experts"):
-        dt = x.dtype
-        hi = jax.lax.ragged_dot(rows, p["wi"].astype(dt), sizes)
-        hg = jax.lax.ragged_dot(rows, p["wg"].astype(dt), sizes)
-        y = jax.lax.ragged_dot(jax.nn.silu(hi) * hg, p["wo"].astype(dt), sizes)
-        # rows past the held groups are not computed: zero them, put the
-        # rows back in (token, choice) order and sum each token's k rows
-        # in a fixed order, weighted zero where the expert is held elsewhere
-        y = jnp.where((jnp.arange(t * k) < sizes.sum())[:, None], y, 0)
-        y = jnp.zeros_like(y).at[order].set(y, unique_indices=True)
-        y = y.reshape(t, k, d).astype(jnp.float32)
+        # each token's k rows summed in a fixed order, weighted zero where
+        # the expert is held elsewhere
+        y = y.astype(jnp.float32)
         w_held = jnp.where(held, w, 0.0)
-        routed = sum(w_held[:, j, None] * y[:, j] for j in range(k))
+        routed = sum(w_held[:, j, None] * y[:, j]
+                     for j in range(cfg.experts_per_token))
     with jax.named_scope("moe.shared"):
         shared = ffn_apply(p["shared"], cfg, xt)
     out = (routed + shared.astype(jnp.float32)).astype(x.dtype).reshape(b, s, d)
@@ -377,3 +390,41 @@ def moe_held_apply(
     if count_mask is not None:
         n_held = jnp.sum(held & count_mask.reshape(t, 1), dtype=jnp.int32)
     return out, n_held
+
+
+def _held_rows_all(p: Params, xt: jax.Array, local: jax.Array,
+                   held: jax.Array) -> jax.Array:
+    """(T, k, d) rows of each token's choices: every token through every
+    held expert, then each token's k picked out (zero where held
+    elsewhere).  No sort and no copy of the weights."""
+    dt = xt.dtype
+    with jax.named_scope("moe.experts"):
+        hi = jnp.einsum("td,edf->etf", xt, p["wi"].astype(dt))
+        hg = jnp.einsum("td,edf->etf", xt, p["wg"].astype(dt))
+        y = jnp.einsum("etf,efd->etd", jax.nn.silu(hi) * hg,
+                       p["wo"].astype(dt))  # (E_held, T, d)
+        pick = jnp.clip(local, 0, y.shape[0] - 1).T[..., None]  # (k, T, 1)
+        y = jnp.take_along_axis(y, pick, axis=0)
+        return jnp.where(held.T[..., None], y, 0).swapaxes(0, 1)
+
+
+def _held_rows_grouped(p: Params, xt: jax.Array, local: jax.Array,
+                       held: jax.Array) -> jax.Array:
+    """(T, k, d) rows of each token's choices by grouped matmuls over the
+    assignments sorted by held expert (zero where held elsewhere)."""
+    (t, k), e = local.shape, p["wi"].shape[0]
+    with jax.named_scope("moe.route"):
+        group = jnp.where(held, local, e).reshape(-1)  # elsewhere → last
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.bincount(group, length=e + 1)[:e].astype(jnp.int32)
+        rows = xt[order // k]  # (T·k, d), grouped by held expert
+    with jax.named_scope("moe.experts"):
+        dt = xt.dtype
+        hi = jax.lax.ragged_dot(rows, p["wi"].astype(dt), sizes)
+        hg = jax.lax.ragged_dot(rows, p["wg"].astype(dt), sizes)
+        y = jax.lax.ragged_dot(jax.nn.silu(hi) * hg, p["wo"].astype(dt), sizes)
+        # rows past the held groups are not computed: zero them and put
+        # the rows back in (token, choice) order
+        y = jnp.where((jnp.arange(t * k) < sizes.sum())[:, None], y, 0)
+        y = jnp.zeros_like(y).at[order].set(y, unique_indices=True)
+        return y.reshape(t, k, -1)

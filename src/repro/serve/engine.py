@@ -838,6 +838,14 @@ class ServeEngine:
             return self.cfg.n_layers * n_tokens
         return 0
 
+    def moe_tokens_all_held(self, n_tokens: int) -> int:
+        """``n_tokens`` where one forward over that many tokens computes
+        its held experts in the all-held form (``moe.takes_all_held``),
+        else 0; 0 for the models without held experts."""
+        from repro.models.moe import takes_all_held
+
+        return n_tokens if self.counts_moe_rows and takes_all_held(n_tokens) else 0
+
     def init_slot_cache(self, n_slots: int):
         """Fresh slot cache (batch = n_slots, length = max_len) for the
         continuous-batching scheduler.  Verifies the per-slot write contract

@@ -441,6 +441,9 @@ class ContinuousScheduler:
             # launched, padding included (``engine.moe_rows_launched``)
             "moe_rows_held": 0,
             "moe_rows_computed": 0,
+            # launched tokens (padding included) whose expert layers took
+            # the all-held form (``engine.moe_tokens_all_held``, per launch)
+            "moe_tokens_all_held": 0,
             # paged decode attention: the KV blocks decode steps and verify
             # windows read, summed over slots, steps and layers (counted on
             # the device, fetched with the tokens), and what reading every
@@ -1103,7 +1106,8 @@ class ContinuousScheduler:
             )
         pool_size = (self.n_slots + self.n_blocks) if self.paged else 0
         launched: list[tuple[list, jax.Array, Any]] = []
-        round_tokens = [0, 0]  # launched, real
+        launch_tokens: list[int] = []  # each launch's width × bucket
+        real_round = 0
         for bucket in sorted(rows_by_bucket):
             rows = rows_by_bucket[bucket]
             # launch width is bucketed to powers of two as well (second
@@ -1165,15 +1169,15 @@ class ContinuousScheduler:
             self.stats["chunks_prefilled"] += len(rows)
             self.stats["prefill_tokens_real"] += real_tokens
             self.stats["prefill_tokens_launched"] += width * bucket
-            round_tokens[0] += width * bucket
-            round_tokens[1] += real_tokens
+            launch_tokens.append(width * bucket)
+            real_round += real_tokens
             hist = self.stats["prefill_batch_hist"]
             hist[len(rows)] = hist.get(len(rows), 0) + 1
         # the ONLY admit-round download: every launch's first tokens (and
         # held-expert counts) at once
         with self._phase("prefill_wait"):
             got = jax.device_get([(f, h) for _, f, h in launched])
-        self._count_rows(*round_tokens, [h for _, h in got])
+        self._count_rows(launch_tokens, real_round, [h for _, h in got])
         now = self.clock()
         n_live = 0
         for (rows, _, _), (fh, _) in zip(launched, got):
@@ -1243,7 +1247,7 @@ class ContinuousScheduler:
         """
         eng = self.engine
         pending: list[tuple[Request, int, jax.Array, bool, Any]] = []
-        n_tokens = 0
+        launch_tokens: list[int] = []
         deferred = False
         for slot in range(self.n_slots):
             if deferred:
@@ -1284,7 +1288,7 @@ class ContinuousScheduler:
                 # one row at the prompt's own length: nothing padded
                 self.stats["prefill_tokens_real"] += n
                 self.stats["prefill_tokens_launched"] += n
-                n_tokens += n
+                launch_tokens.append(n)
                 resumed = bool(req.tokens)
                 pending.append((req, slot, first, resumed, held))
                 if resumed:
@@ -1313,7 +1317,8 @@ class ContinuousScheduler:
             return 0
         with self._phase("prefill_wait"):
             got = jax.device_get([(f, h) for _, _, f, _, h in pending])
-        self._count_rows(n_tokens, n_tokens, [h for _, h in got])
+        self._count_rows(launch_tokens, sum(launch_tokens),
+                         [h for _, h in got])
         now = self.clock()
         for (req, slot, _, resumed, _), (first, _) in zip(pending, got):
             if resumed:
@@ -1476,19 +1481,21 @@ class ContinuousScheduler:
         with self._phase("retire", "host_s_retire") as span:
             n_exec, n_live = self._retire(toks)
             if self.spec is None:  # a verify step is no one-token forward
-                self._count_rows(n_exec * self.n_slots, n_live,
+                self._count_rows([self.n_slots] * n_exec, n_live,
                                  [counts.get("held")])
             self.stats["decode_kv_blocks_read"] += int(counts.get("kv_read", 0))
             self.stats["decode_kv_blocks_span"] += int(counts.get("kv_span", 0))
             span.set_metadata(steps=n_exec, live=n_live)
         return sum(r is not None for r in self.slots)
 
-    def _count_rows(self, launched: int, real: int, held: list) -> None:
-        """Add the expert-layer rows of ``launched`` tokens, ``real`` of
-        them real: ``held`` holds the device's counts where the engine
-        counts held-expert rows (None otherwise)."""
+    def _count_rows(self, launches: list[int], real: int, held: list) -> None:
+        """Add the expert-layer rows of forwards over ``launches`` tokens
+        each, ``real`` of them real in all: ``held`` holds the device's
+        counts where the engine counts held-expert rows (None otherwise)."""
         eng = self.engine
-        self.stats["moe_rows_computed"] += eng.moe_rows_launched(launched)
+        self.stats["moe_rows_computed"] += eng.moe_rows_launched(sum(launches))
+        self.stats["moe_tokens_all_held"] += sum(
+            eng.moe_tokens_all_held(n) for n in launches)
         self.stats["moe_rows_held"] += (
             sum(int(h) for h in held) if eng.counts_moe_rows
             else eng.moe_rows_launched(real))

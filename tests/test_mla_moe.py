@@ -2,8 +2,9 @@
 held-expert layer, at tiny widths on the CPU: absorbed attention equals the
 expanded form, decode over the pool equals the full forward, the expert
 shares add up to the uncut layer, a token's output does not depend on its
-launch, pool writes touch only their own positions, the held-row counters,
-and the layouts and paths the family refuses."""
+launch, the all-held and grouped forms agree and the launch's size picks
+between them, pool writes touch only their own positions, the held-row
+counters, and the layouts and paths the family refuses."""
 import dataclasses
 
 import jax
@@ -120,10 +121,20 @@ def _moe_cfg(**kw):
     return _arch(compute_dtype="float32", **kw).cfg
 
 
-def test_expert_shares_add_up_to_the_uncut_layer(f32_model):
+@pytest.fixture(params=["all_held", "grouped"])
+def form(request, monkeypatch):
+    """Each form of the held experts, forced through the bound on the
+    launch's token count (the tests' launches all lie below it)."""
+    if request.param == "grouped":
+        monkeypatch.setattr(M, "ALL_HELD_MAX_TOKENS", 0)
+    return request.param
+
+
+def test_expert_shares_add_up_to_the_uncut_layer(f32_model, form):
     """Four devices' shares of 2 experts each: their outputs, with the
     shared expert (which every device computes) counted once, add up to the
     layer that holds all 8."""
+    assert M.takes_all_held(14) == (form == "all_held")
     _, params = f32_model
     p = _layer(params)["moe"]
     full = _moe_cfg(experts_held=0)
@@ -140,9 +151,10 @@ def test_expert_shares_add_up_to_the_uncut_layer(f32_model):
                                np.asarray(whole), rtol=1e-4, atol=1e-5)
 
 
-def test_a_tokens_output_does_not_depend_on_its_launch():
-    """Drop-free: each token's output is bitwise the same launched alone
-    as launched with 63 others (which crowd its experts)."""
+def test_a_tokens_output_does_not_depend_on_its_launch(form):
+    """Drop-free: within one form, each token's output is bitwise the same
+    launched alone as launched with 63 others (which crowd its experts)."""
+    assert M.takes_all_held(64) == (form == "all_held")
     arch = _arch()
     p = _layer(arch.init_params(jax.random.PRNGKey(4)))["moe"]
     x = jax.random.normal(jax.random.PRNGKey(5), (1, 64, arch.cfg.d_model),
@@ -152,6 +164,44 @@ def test_a_tokens_output_does_not_depend_on_its_launch():
         alone, _ = M.moe_held_apply(p, arch.cfg, x[:, t:t + 1])
         np.testing.assert_array_equal(np.asarray(alone[0, 0], np.float32),
                                       np.asarray(together[0, t], np.float32))
+
+
+@pytest.mark.parametrize("t", [1, 5, 33])
+def test_the_all_held_form_equals_the_grouped_form(f32_model, t):
+    """At fp32 and ``highest`` the two forms give the same rows: tokens
+    whose choices are all held elsewhere get zeros, and experts that no
+    token chose change nothing."""
+    _, params = f32_model
+    p = _layer(params)["moe"]
+    e, k = p["wi"].shape[0], 3
+    x = jax.random.normal(jax.random.PRNGKey(10), (t, p["wi"].shape[1]))
+    # experts 2 and 5 get no rows; -2, -1, e and e + 1 are held elsewhere
+    pool = np.array([0, 1, 3, 4, 6, 7, -2, -1, e, e + 1])
+    rng = np.random.default_rng(t)
+    local = np.stack([rng.permutation(pool)[:k] for _ in range(t)])
+    local[0] = (-1, e, e + 1)
+    local = jnp.asarray(local, jnp.int32)
+    held = (local >= 0) & (local < e)
+    with jax.default_matmul_precision("highest"):
+        every = M._held_rows_all(p, x, local, held)
+        grouped = M._held_rows_grouped(p, x, local, held)
+    assert every.shape == grouped.shape == (t, k, x.shape[1])
+    np.testing.assert_array_equal(np.asarray(every[0]), 0)
+    # rows near zero are held to 1e-5 of the rows' scale
+    grouped = np.asarray(grouped)
+    np.testing.assert_allclose(np.asarray(every), grouped, rtol=1e-5,
+                               atol=1e-5 * np.abs(grouped).max())
+
+
+def test_the_form_is_chosen_by_the_launchs_token_count():
+    bound = M.ALL_HELD_MAX_TOKENS
+    assert bound == 128
+    assert M.takes_all_held(1) and M.takes_all_held(bound)
+    assert not M.takes_all_held(bound + 1)
+    arch = _arch()
+    eng = _engine(arch, arch.init_params(jax.random.PRNGKey(11)))
+    assert eng.moe_tokens_all_held(bound) == bound
+    assert eng.moe_tokens_all_held(bound + 1) == 0
 
 
 def test_held_count_is_the_real_tokens_assignments_here():
@@ -201,6 +251,11 @@ def test_scheduler_counts_held_and_launched_rows(arch_id):
         st["prefill_tokens_real"] + st["slot_steps_live"])
     assert st["moe_rows_computed"] == per_token * (
         st["prefill_tokens_launched"] + N_SLOTS * st["steps_total"])
+    # 8-token chunks (at most 4 wide) and 3-slot segments: every launch
+    # lies below the bound, so a held-expert model counts every token
+    assert st["moe_tokens_all_held"] == (
+        st["prefill_tokens_launched"] + N_SLOTS * st["steps_total"]
+        if arch.cfg.n_experts else 0)
 
 
 def test_per_request_admission_prefills_into_the_pool(f32_model):

@@ -6,8 +6,11 @@ installed; no chip is attached), at tinyllama-1.1b's FFN projection
 for a codebook wider than one 128-lane row), with
 decode (M 4) and prefill (M 256) rows of bf16 activations.  Interpret mode
 cannot show what these show: Mosaic refuses unaligned blocks, gathers it
-cannot lower and vector loads of scalars.
+cannot lower and vector loads of scalars.  Beside the kernels, the
+held-expert layer at Moonlight's widths is compiled to show which of its
+forms copies each layer's expert weights out of the stack.
 """
+import dataclasses
 import re
 
 import jax
@@ -15,6 +18,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs.moonlight_16b_a3b import CONFIG as MOONLIGHT
 from repro.core.sonic_layers import BlockSparseWeight, BlockSparseWeightInt8
 from repro.kernels.block_sparse_matmul.ops import (
     block_sparse_matmul,
@@ -29,6 +33,7 @@ from repro.kernels.sonic_matmul.ops import (
     sonic_matvec_int8,
 )
 from repro.kernels.sparse_matvec.ops import sparse_matvec
+from repro.models import moe as M
 
 K, N, BLOCK, CLUSTERS = 2048, 5632, 128, 64
 KB, NB, R = K // BLOCK, N // BLOCK, K // BLOCK // 4  # 75% of K-blocks pruned
@@ -144,3 +149,54 @@ def test_paged_decode_attention_compiles_for_v5e(one_chip, layout, c):
     shape = ",".join(map(str, kv.shape))
     assert not re.search(rf"= bf16\[{shape}\]\S* (copy|transpose)\(", hlo)
     assert jax.eval_shape(fn, *args).shape == (b, c, h, dh)
+
+
+def _top_level_ops(hlo: str) -> list[str]:
+    """The instructions of a compiled module's computations that are not
+    fused into another: the ops the chip runs as ops of their own."""
+    fused = set(re.findall(r"\bcalls=(%[\w.\-]+)", hlo))
+    out, keep = [], False
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.\-]+) \(", line)
+        if head:
+            keep = head.group(1) not in fused
+        elif keep and re.match(r"\s+(ROOT )?%\S+ = ", line):
+            out.append(line)
+    return out
+
+
+@pytest.mark.parametrize("form", ["all_held", "grouped"])
+def test_held_experts_read_their_stacked_weights_in_place(one_chip, form,
+                                                          monkeypatch):
+    """Moonlight's held-expert layer (16 of 64 experts held, d 2,048, f
+    1,408, top-6) in a scan over 3 stacked layers, at a decode launch of 32
+    tokens: the all-held form reads each layer's ``wi``/``wg``/``wo`` slice
+    where it lies, so no op of its own writes a slice-shaped array.  The
+    grouped form, which ``jax.lax.ragged_dot`` takes whole, shows the
+    copies this looks for."""
+    if form == "grouped":
+        monkeypatch.setattr(M, "ALL_HELD_MAX_TOKENS", 0)
+    cfg = dataclasses.replace(MOONLIGHT, param_dtype="bfloat16")
+    n_layers, t, e, d, f = 3, 32, cfg.n_experts_held, cfg.d_model, cfg.d_ff
+    assert (e, d, f, cfg.experts_per_token) == (16, 2048, 1408, 6)
+    shapes = jax.eval_shape(lambda k: M.moe_held_init(k, cfg),
+                            jax.random.PRNGKey(0))
+    stacked = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct((n_layers, *a.shape), a.dtype,
+                                       sharding=one_chip), shapes)
+    x = jax.ShapeDtypeStruct((t, 1, d), jnp.bfloat16, sharding=one_chip)
+
+    def layers(p, x):
+        def body(x, lp):
+            y, _ = M.moe_held_apply(lp, cfg, x)
+            return x + y, None
+
+        return jax.lax.scan(body, x, p)[0]
+
+    hlo = jax.jit(layers).lower(stacked, x).compile().as_text()
+    slice_shaped = [op for op in _top_level_ops(hlo)
+                    if re.search(rf"= bf16\[({e},{d},{f}|{e},{f},{d})\]", op)]
+    if form == "all_held":
+        assert not slice_shaped, slice_shaped
+    else:
+        assert len(slice_shaped) == 3
