@@ -1,8 +1,11 @@
 """A configuration of another architecture joins the benchmark as files
-only: its reference module states the program's config and the work, and
-the harness reads both without an edit to any file of ``bench/``."""
+only: its reference module states the program's config and the work (each
+layer group's matrices, KV, attention FLOPs a pair and, where its layers
+attend a sliding window, that window), and the harness reads both without
+an edit to any file of ``bench/``."""
 import json
 import textwrap
+from types import SimpleNamespace
 
 import pytest
 
@@ -72,19 +75,79 @@ TOY_CONFIG = {
 }
 
 
-def write_toy(root, arch_id="moonshot-v1-16b-a3b"):
-    """Write the toy cell's files under ``root`` and load the cell."""
+# four GQA layers at DENSE's widths in bench/test_bench_work.py (576
+# weights, 8 KV elements a token, 32 FLOPs a pair), three attending a
+# window of 4 keys to each one that attends every earlier key
+TOY_WINDOW_REFERENCE = '''
+"""Toy reference: grouped-query attention, sliding-window layers and
+full-attention layers as ``layer_types`` lists them."""
+from bench import work
+
+PROGRAM_REQUIRES = {"family": "dense"}
+
+
+def model_from_config(config):
+    return config
+
+
+def make_params(model, key):
+    raise AssertionError("weights made")
+
+
+def gaps(params, tokens, targets, model, control_bits=0):
+    raise AssertionError("not checked here")
+
+
+def program_fields(config):
+    raise AssertionError("no program computes a window")
+
+
+def work_shapes(config):
+    d, f = config["hidden_size"], config["intermediate_size"]
+    h, kv = config["num_attention_heads"], config["num_key_value_heads"]
+    hd = config["head_dim"]
+    mats = ((d, h * hd, 1.0), (d, kv * hd, 1.0), (d, kv * hd, 1.0),
+            (h * hd, d, 1.0), (d, f, 1.0), (d, f, 1.0), (f, d, 1.0))
+
+    def group(kind, window):
+        return work.LayerGroup(
+            layers=config["layer_types"].count(kind), mats=mats,
+            kv_per_token=2 * kv * hd, attn_flops_per_pair=4 * h * hd,
+            window=window)
+
+    return work.Shapes(groups=(group("full_attention", 0),
+                               group("sliding_attention",
+                                     config["sliding_window"])),
+                       d=d, vocab=config["vocab_size"])
+'''
+
+TOY_WINDOW_CONFIG = {
+    "source": "test", "num_hidden_layers": 4, "hidden_size": 8,
+    "num_attention_heads": 2, "num_key_value_heads": 1, "head_dim": 4,
+    "intermediate_size": 16, "vocab_size": 32, "sliding_window": 4,
+    "layer_types": ["sliding_attention"] * 3 + ["full_attention"],
+    "reduced": [], "arch_id": "internlm2-1.8b", "reference": "toy_window",
+    "weights": {"format": "dense", "dtype": "float32"},
+    "control": {"kind": "reference", "bits": 8},
+}
+
+
+def write_toy(root, arch_id="moonshot-v1-16b-a3b", source=TOY_REFERENCE,
+              config=TOY_CONFIG):
+    """Write the toy cell's files under ``root`` (the reference module
+    ``source`` under the name ``config["reference"]``) and load the cell."""
     bench = root / "bench"
     for sub in ("configs", "traffic", "checks", "references"):
         (bench / sub).mkdir(parents=True, exist_ok=True)
-    (bench / "references" / "toy_moe.py").write_text(textwrap.dedent(TOY_REFERENCE))
-    (bench / "configs" / "toy-moe.json").write_text(
-        json.dumps({**TOY_CONFIG, "arch_id": arch_id}))
+    (bench / "references" / f"{config['reference']}.py").write_text(
+        textwrap.dedent(source))
+    (bench / "configs" / "toy.json").write_text(
+        json.dumps({**config, "arch_id": arch_id}))
     (bench / "traffic" / "toy_mix.json").write_text(json.dumps(tiny_cell.traffic()))
     (bench / "checks" / "toy.cell.json").write_text(
         json.dumps({"number": "max_gap", "limit": 0.05}))
     (root / "BENCHMARK.json").write_text(json.dumps({
-        "workloads": [{"name": "toy.cell", "config": "toy-moe",
+        "workloads": [{"name": "toy.cell", "config": "toy",
                        "traffic": "toy_mix", "chips": 1}],
         "end_to_end": [{"name": "setup_s", "unit": "s"}],
         "per_layer": [{"name": "step_mfu", "unit": "%"}],
@@ -116,6 +179,35 @@ def test_another_architecture_joins_as_files(tmp_path):
     flops, nbytes = work.decode(s, 1, [10, 20])
     assert flops == 2 * (2 * (832 + 256)) + 60 * 30
     assert nbytes == 2368 + 2 * 8 * 2 + 36 * (30 + 2)
+
+
+def test_a_windowed_architecture_joins_as_files(tmp_path):
+    cell = write_toy(tmp_path, "internlm2-1.8b", TOY_WINDOW_REFERENCE,
+                     TOY_WINDOW_CONFIG)
+    assert not (spec.BENCH_DIR / "references" / "toy_window.py").exists()
+    s = spec.reference_module(cell.config, cell.bench_dir).work_shapes(cell.config)
+    assert [(g.layers, g.window) for g in s.groups] == [(1, 0), (3, 4)]
+    assert s.layer_weights == 4 * 576 and s.head_weights == 256
+    assert s.kv_bytes_per_token == 4 * 16 and s.attn_flops_per_pair == 4 * 32
+    # one step, contexts 3 and 10: the full layer attends 3 + 10 keys, each
+    # window layer 3 + 4; KV 16 B a token a layer, read and one written
+    flops, nbytes = work.decode(s, 1, [3, 10])
+    assert flops == 2 * 2 * (2304 + 256) + 32 * 13 + 3 * 32 * 7 == 11328
+    assert nbytes == 2 * 2560 + 2 * 16 + 16 * (13 + 2) + 3 * 16 * (7 + 2) == 5824
+    # a chunk of 4 after 6 cached: queries 6 ... 9 attend 7 ... 10 keys in
+    # the full layer and 4 each in a window layer, which reads 3 of the
+    # prefix's 6 positions
+    flops, nbytes = work.prefill(s, [(6, 4)])
+    assert flops == 2 * 2304 * 4 + 2 * 256 + 32 * 34 + 3 * 32 * 16 == 21568
+    assert nbytes == 2 * 2560 + 4 * 16 + 16 * (6 + 4) + 3 * 16 * (3 + 4) == 5680
+    # the committed decode reader counts the window: each bound 1 s at
+    # these peaks, over 2 s of device time
+    ctx = spec.MetricContext(
+        reduced=SimpleNamespace(module_s={"jit_segment": 2.0}), stats0={},
+        stats1={}, launches=SimpleNamespace(segments=[(1, [3, 10])]),
+        records=[], t0=0.0, t1=1.0, shapes=s,
+        peaks={"bf16_flop_s": 11328.0, "hbm_byte_s": 5824.0})
+    assert spec.metric_reader("decode_roofline")(ctx) == 50.0
 
 
 def test_a_reference_the_arch_does_not_match_is_refused_before_weights(tmp_path):

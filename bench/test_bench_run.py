@@ -22,6 +22,23 @@ def test_sound_run_is_correct(tmp_path, fmt, kind):
     assert {"max_gap", "compiles_in_window", "stalled_requests"} <= set(out["checks"])
 
 
+def test_tiny_cell_reports_the_metrics_of_its_own_architecture(tmp_path):
+    """The tiny GQA cell reports every per-layer metric of the committed
+    GQA cells, and none that only a cell of another architecture reads."""
+    import json
+
+    from bench import spec
+
+    cell = tiny_cell.write(tmp_path)
+    names = {m["name"] for m in cell.per_layer}
+    for m in json.loads((spec.ROOT / "BENCHMARK.json").read_text())["per_layer"]:
+        if "internlm2.decode" in m.get("workloads", ["internlm2.decode"]):
+            assert m["name"] in names
+    assert "moe.useful_rows_pct" not in names and "decode_roofline" in names
+    assert all(m.get("workloads", ["tiny.cell"]) == ["tiny.cell"]
+               for m in cell.per_layer)
+
+
 def test_traced_run_reads_every_per_layer_metric(tmp_path):
     cell = tiny_cell.write(tmp_path, "dense", "closed")
     out = run.run_cell(cell, 7, 2.5, True, trace_dir=tmp_path / "trace",

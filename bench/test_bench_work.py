@@ -1,5 +1,6 @@
 """Required work, against hand counts at tiny configurations, and pinned
 counts of the committed configurations."""
+import dataclasses
 import json
 
 import pytest
@@ -153,3 +154,81 @@ def test_reference_states_the_hand_built_shapes():
     config["weights"] = {"format": "int8_block_sparse", "dtype": "bfloat16",
                          "sparsity": 0.5, "block": [4, 4]}
     assert ref.work_shapes(config) == SPARSE
+
+
+# windowed counts against an enumeration over the queries: a query at
+# position p attends keys p - W + 1 ... p, itself included
+def attended(p: int, window: int) -> set[int]:
+    return {k for k in range(p + 1) if not window or k > p - window}
+
+
+@pytest.mark.parametrize("window", [0, 1, 4, 7])
+@pytest.mark.parametrize("start,real", [(0, 1), (0, 3), (0, 9), (2, 2),
+                                        (3, 6), (5, 1), (6, 7), (9, 4)])
+def test_window_pairs_and_prefix_enumerated(start, real, window):
+    g = work.LayerGroup(layers=1, mats=(), kv_per_token=1,
+                        attn_flops_per_pair=1, window=window)
+    queries = range(start, start + real)
+    assert g.pairs(start, real) == sum(len(attended(p, window)) for p in queries)
+    assert g.prefix_read(start) == len(
+        {k for p in queries for k in attended(p, window) if k < start})
+    for p in queries:
+        assert g.keys(p + 1) == len(attended(p, window))
+    if not window or start + real <= window:
+        assert g.pairs(start, real) == real * start + real * (real + 1) / 2
+
+
+# TWO_GROUPS with its second group, two layers, attending a window of 4
+WINDOWED = work.Shapes(groups=(
+    TWO_GROUPS.groups[0], dataclasses.replace(TWO_GROUPS.groups[1], window=4),
+), d=8, vocab=32)
+
+
+@pytest.mark.parametrize("contexts", [[1, 3], [4, 4], [5, 9], [2, 4, 6, 11]])
+def test_windowed_decode(contexts):
+    # full group: 32 FLOPs a pair, 16 B of KV a token; windowed group:
+    # 2 x 16 FLOPs a pair and 2 x 4 x 2 B a token, over min(c, 4) keys
+    n = len(contexts)
+    keys = [min(c, 4) for c in contexts]
+    flops, nbytes = work.decode(WINDOWED, 1, contexts)
+    assert flops == sum(2 * (448 + 256) + 32 * c + 32 * k
+                        for c, k in zip(contexts, keys))
+    assert nbytes == (WINDOWED.step_weight_bytes(n) + n * 16
+                      + 16 * (sum(contexts) + n) + 16 * (sum(keys) + n))
+    for c, k in zip(contexts, keys):
+        assert work.token_flops(WINDOWED, c) == 2 * (448 + 256) + 32 * c + 32 * k
+
+
+@pytest.mark.parametrize("rows", [[(0, 3)], [(0, 9)], [(2, 5), (6, 1)],
+                                  [(7, 6), (0, 2)]])
+def test_windowed_prefill_enumerated(rows):
+    flops, nbytes = work.prefill(WINDOWED, rows)
+    want_f = want_b = 0
+    for start, real in rows:
+        queries = range(start, start + real)
+        full = sum(len(attended(p, 0)) for p in queries)
+        win = sum(len(attended(p, 4)) for p in queries)
+        prefix = len({k for p in queries for k in attended(p, 4) if k < start})
+        want_f += 2 * 448 * real + 2 * 256 + 32 * full + 32 * win
+        want_b += real * 16 + 16 * (start + real) + 16 * (prefix + real)
+    assert flops == want_f
+    assert nbytes == WINDOWED.step_weight_bytes(sum(r for _, r in rows)) + want_b
+
+
+@pytest.mark.parametrize("window", [12, 13, 100])
+def test_a_window_past_every_context_counts_as_none(window):
+    wide = work.Shapes(groups=(
+        TWO_GROUPS.groups[0],
+        dataclasses.replace(TWO_GROUPS.groups[1], window=window),
+    ), d=8, vocab=32)
+    contexts, rows = [5, 12, 1, 9], [(4, 8), (0, 12), (11, 1)]
+    assert work.decode(wide, 2, contexts) == work.decode(TWO_GROUPS, 2, contexts)
+    assert work.prefill(wide, rows) == work.prefill(TWO_GROUPS, rows)
+    assert all(work.token_flops(wide, c) == work.token_flops(TWO_GROUPS, c)
+               for c in contexts)
+    from bench.window import Record
+
+    recs = [Record(due=0.0, prompt_len=7, max_new=6, emit_t=[0.5, 1.0, 2.0,
+                                                             3.0, 4.0, 5.0])]
+    assert (work.window_flops(wide, recs, 0.0, 9.0)
+            == work.window_flops(TWO_GROUPS, recs, 0.0, 9.0))

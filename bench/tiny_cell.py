@@ -41,9 +41,24 @@ def traffic(kind: str = "closed") -> dict:
     return t
 
 
+def own_per_layer(reference: str) -> list[dict]:
+    """The per-layer metrics of ``BENCHMARK.json`` that a cell of
+    ``reference``'s architecture reports: those of every cell, and those
+    whose ``workloads`` name a committed cell whose configuration has that
+    reference."""
+    bench = json.loads((spec.ROOT / "BENCHMARK.json").read_text())
+    own = {w["name"] for w in bench["workloads"]
+           if json.loads((spec.BENCH_DIR / "configs" / f"{w['config']}.json")
+                         .read_text())["reference"] == reference}
+    return [m for m in bench["per_layer"]
+            if "workloads" not in m or own & set(m["workloads"])]
+
+
 def write(root: pathlib.Path, fmt: str = "dense", kind: str = "closed") -> spec.Cell:
     """Write a one-cell benchmark under ``root``, with a copy of the
-    reference module, and load its cell."""
+    reference module, and load its cell.  The cell stands in for the
+    committed cells of its architecture: it reports their per-layer
+    metrics (``own_per_layer``), with ``workloads`` naming it."""
     bench = root / "bench"
     for sub in ("configs", "traffic", "checks", "references"):
         (bench / sub).mkdir(parents=True, exist_ok=True)
@@ -56,13 +71,13 @@ def write(root: pathlib.Path, fmt: str = "dense", kind: str = "closed") -> spec.
     e2e = [{"name": n, "unit": u} for n, u in (
         ("output_tok_s", "tokens/s"), ("ttft_p95_ms", "ms"),
         ("tpot_p95_ms", "ms"), ("setup_s", "s"))]
-    per_layer = json.loads((spec.ROOT / "BENCHMARK.json").read_text())["per_layer"]
+    per_layer = [{**m, "workloads": ["tiny.cell"]} if "workloads" in m else m
+                 for m in own_per_layer(config(fmt)["reference"])]
     (root / "BENCHMARK.json").write_text(json.dumps({
         "workloads": [{"name": "tiny.cell", "config": "tiny",
                        "traffic": "tiny_mix", "chips": 1}],
         "end_to_end": e2e,
-        "per_layer": [{k: v for k, v in m.items() if k != "workloads"}
-                      for m in per_layer],
+        "per_layer": per_layer,
     }))
     return spec.load_cell("tiny.cell", root=root, bench_dir=bench)
 
